@@ -1,7 +1,9 @@
 """Query-engine benchmarks: sharded scan GB/s + SLA attainment vs load.
 
-Shards a synthetic table across every available device (CI forces 8 host
-devices via XLA_FLAGS), times the sharded scan+aggregate path, compares
+Shards a synthetic table across every available device (a CPU run that
+wants 8 virtual devices sets
+XLA_FLAGS=--xla_force_host_platform_device_count=8 on its own command
+line), times the sharded scan+aggregate path, compares
 attained throughput against the analytical model's roofline
 (QueryEngine.model_check), then sweeps offered load: batches of deadline-
 carrying queries at 0.5x/1x/2x the engine's measured capacity, recording
@@ -13,14 +15,8 @@ sharded-vs-oracle parity and the attainment-vs-load shape are.
 """
 from __future__ import annotations
 
-import os
-import sys
 import time
 from pathlib import Path
-
-if "jax" not in sys.modules:          # must precede the first jax import
-    os.environ.setdefault("XLA_FLAGS",
-                          "--xla_force_host_platform_device_count=8")
 
 import jax
 import numpy as np
@@ -138,12 +134,6 @@ def _rle_vs_fallback() -> tuple[dict, object]:
 def rows():
     out = []
     n_dev = len(jax.devices())
-    if n_dev == 1:
-        # a prior module already imported jax, so the 8-device override
-        # could not apply; shard counts in this record are not comparable
-        # with CI's 8-shard rows
-        print("queries_bench: jax already initialized, running 1-shard",
-              file=sys.stderr)
     mesh = make_mesh((n_dev,), ("data",))
     table = Table.synthetic("bench", 1 << 21, {"a": 8, "b": 8, "c": 16},
                             seed=0)

@@ -12,6 +12,7 @@ import sys
 import traceback
 
 from benchmarks.common import emit
+from repro.launch import compile_cache
 
 MODULES = (
     "benchmarks.fig1_bandwidth_capacity",
@@ -38,6 +39,7 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default="",
                     help="run only modules whose name contains this")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     modules = [m for m in MODULES if args.only in m]
     records = []

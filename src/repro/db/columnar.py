@@ -9,7 +9,6 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.scan_filter import ref as packref
@@ -17,13 +16,16 @@ from repro.kernels.scan_filter import ref as packref
 
 @dataclass
 class BitPackedColumn:
+    """One column's packed words, kept on the host: the device copy is
+    whatever an execution binds (a ShardedTable's slices, or the words a
+    flat-table query ships), so a table is never resident twice."""
     name: str
     code_bits: int
     num_rows: int
-    words: jnp.ndarray                 # (n_words,) uint32
+    words: np.ndarray                  # (n_words,) uint32
     dictionary: np.ndarray | None = None   # code -> value (optional)
-    _valid: jnp.ndarray | None = field(default=None, repr=False,
-                                       compare=False)
+    _valid: np.ndarray | None = field(default=None, repr=False,
+                                      compare=False)
 
     @classmethod
     def from_values(cls, name: str, values, code_bits: int,
@@ -46,18 +48,17 @@ class BitPackedColumn:
                 f"MSB must stay 0); widen code_bits or re-encode the "
                 f"dictionary")
         words = packref.pack(values, code_bits)
-        return cls(name, code_bits, len(values), jnp.asarray(words),
+        return cls(name, code_bits, len(values), words,
                    None if dictionary is None else np.asarray(dictionary))
 
     @property
-    def valid_words(self) -> jnp.ndarray:
+    def valid_words(self) -> np.ndarray:
         """Packed delimiter-bit mask set only for real rows: cancels the
         pack()-to-a-word-multiple tail padding during query evaluation
         (cached — reused by every query touching this column)."""
         if self._valid is None:
-            total = int(self.words.size) * self.codes_per_word
-            self._valid = jnp.asarray(packref.pack_mask(
-                np.arange(total) < self.num_rows, self.code_bits))
+            self._valid = packref.valid_mask(int(self.words.size),
+                                             self.num_rows, self.code_bits)
         return self._valid
 
     @property

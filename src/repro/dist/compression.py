@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -41,8 +40,8 @@ def compressed_psum_pod(tree, mesh, axis: str = "pod"):
         return jax.tree.map(one, t)
 
     specs = jax.tree.map(lambda _: P(), tree)
-    return shard_map(local, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                     check_rep=False)(tree)
+    return jax.shard_map(local, mesh=mesh, in_specs=(specs,),
+                         out_specs=specs, check_vma=False)(tree)
 
 
 def error_feedback_compress(grads, residual=None):

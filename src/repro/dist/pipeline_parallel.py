@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -58,6 +57,6 @@ def gpipe(stage, weights, xs, *, mesh, axis: str):
         # only the last device holds real outputs; broadcast to the ring
         return jax.lax.psum(jnp.where(idx == s - 1, out, 0.0), axis)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axis), P()), out_specs=P(),
-                     check_rep=False)(weights, xs)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axis), P()), out_specs=P(),
+                         check_vma=False)(weights, xs)

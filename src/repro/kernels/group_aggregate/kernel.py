@@ -5,10 +5,10 @@ Two kernels, both writing int32 `(n_groups, 3)` accumulator planes of
 5-scalar row):
 
 - `_dense_*`: one pass over (rows, LANES) int32 key/value/select code
-  planes. Per grid step a (group_block, block_rows, LANES) compare plane
-  matches a block of group keys against the tile in VREGs and reduces
-  into VMEM scratch — a dense accumulator plane instead of a hash table,
-  viable because the store's FOR frames bound the key range.
+  planes. Per grid step each key of a block of group keys is compared
+  against the tile in VREGs and reduced into SMEM scalar accumulators —
+  a dense accumulator plane instead of a hash table, viable because the
+  store's FOR frames bound the key range.
 - `_rle_*`: the fused pre-grouped path over RLE run planes: a run
   (value v, length n) contributes n to group v's count and n*v to its
   sum as ONE register accumulation — no scatter, no per-row traffic. An
@@ -16,9 +16,16 @@ Two kernels, both writing int32 `(n_groups, 3)` accumulator planes of
 
 Exactness mirrors the aggregate family: ops.py bounds block_rows so each
 tile partial stays < 2^31, every tile partial is split 16/16 into two
-running planes, and the final grid step writes the normalized pair. Group
+running planes renormalized per tile, and the final grid step writes the
+normalized pair. Group
 key blocks are padded with -1 (codes are unsigned, so the sentinel never
 matches); padded rows/runs carry zero select/length.
+
+TPU layout: group keys are scalar-prefetched into SMEM, accumulators are
+SMEM scalars, and each (chunk, group block) writes one lane-dense (8, 128)
+output tile (rows = [sum_lo, sum_hi, count], lanes = groups of the block);
+the jitted entry points reshape the tiles to the (n_chunks, G, 3)
+contract.
 """
 from __future__ import annotations
 
@@ -29,60 +36,66 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.aggregate.kernel import OUT_TILE, pad_rows
 from repro.kernels.scan_filter.kernel import LANES
 
 DEFAULT_BLOCK_ROWS = 256
 DEFAULT_GROUP_BLOCK = 8
 
 
-def _accumulate(acc, ids, match, vals, weights=None):
-    """Reduce one (block_rows, LANES) tile into the (group_block, 3)
-    scratch: per group-id row, a masked (weighted) sum split 16/16 plus a
-    (weighted) count."""
-    m = match & (ids[:, None, None] >= 0)
+def _accumulate(acc, gk_ref, base, gb: int, keys, vals, live, weights=None):
+    """Reduce one (block_rows, LANES) tile into the (3 * gb,) SMEM scratch:
+    per group of the block, a masked (weighted) sum split 16/16 plus a
+    (weighted) count. Group keys are SMEM scalars read one at a time."""
     w = weights if weights is not None else jnp.int32(1)
-    s = jnp.sum(jnp.where(m, vals[None] * w, 0), axis=(1, 2))
-    c = jnp.sum(jnp.where(m, w, 0), axis=(1, 2))
-    acc[:, 0] += s & 0xFFFF
-    acc[:, 1] += s >> 16
-    acc[:, 2] += c
+    wv = vals * w
+    for j in range(gb):                   # static unroll over the block
+        match = live & (keys == gk_ref[base + j])
+        s = jnp.sum(jnp.where(match, wv, 0))
+        lo = acc[3 * j] + (s & 0xFFFF)       # renormalized every tile
+        acc[3 * j] = lo & 0xFFFF
+        acc[3 * j + 1] += (s >> 16) + (lo >> 16)
+        acc[3 * j + 2] += jnp.sum(jnp.where(match, w, 0))
 
 
-def _writeback(o_ref, acc):
-    lo = acc[:, 0]
-    o_ref[0, :, 0] = lo & 0xFFFF          # normalized planes
-    o_ref[0, :, 1] = acc[:, 1] + (lo >> 16)
-    o_ref[0, :, 2] = acc[:, 2]
+def _writeback(o_ref, acc, gb: int):
+    """Normalized [sum_lo, sum_hi, count] of group j of the block land in
+    rows 0..2 of lane j of the block's lane-dense (8, 128) output tile."""
+    row = jax.lax.broadcasted_iota(jnp.int32, OUT_TILE, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, OUT_TILE, 1)
+    tile = jnp.zeros(OUT_TILE, jnp.int32)
+    for j in range(gb):
+        at = lane == j
+        for f in range(3):
+            tile = jnp.where(at & (row == f), acc[3 * j + f], tile)
+    o_ref[0, 0] = tile
 
 
-def _dense_batched_kernel(gk_ref, k_ref, v_ref, s_ref, o_ref, acc):
+def _step(acc, o_ref, gb: int, body):
+    """Grid (n_chunks, n_group_blocks, inner): reset the scratch at inner
+    step 0, accumulate, write the block back at the last inner step."""
     i = pl.program_id(2)
-    ni = pl.num_programs(2)
 
     @pl.when(i == 0)
     def _():
-        acc[...] = jnp.zeros(acc.shape, jnp.int32)
+        for k in range(3 * gb):
+            acc[k] = jnp.int32(0)
 
-    ids = gk_ref[0]                       # (group_block,)
-    k = k_ref[0]
-    sel = s_ref[0] > 0
-    match = (k[None] == ids[:, None, None]) & sel[None]
-    _accumulate(acc, ids, match, v_ref[0])
+    body(pl.program_id(1) * gb)
 
-    @pl.when(i == ni - 1)
+    @pl.when(i == pl.num_programs(2) - 1)
     def _():
-        _writeback(o_ref, acc)
+        _writeback(o_ref, acc, gb)
 
 
-def _rle_batched_kernel(gk_ref, v_ref, l_ref, o_ref, acc, *, pred):
-    i = pl.program_id(2)
-    ni = pl.num_programs(2)
+def _dense_batched_kernel(gk_ref, k_ref, v_ref, s_ref, o_ref, acc, *,
+                          gb: int):
+    _step(acc, o_ref, gb, lambda base: _accumulate(
+        acc, gk_ref, base, gb, k_ref[0], v_ref[0], s_ref[0] > 0))
 
-    @pl.when(i == 0)
-    def _():
-        acc[...] = jnp.zeros(acc.shape, jnp.int32)
 
-    ids = gk_ref[0]
+def _rle_batched_kernel(gk_ref, v_ref, l_ref, o_ref, acc, *, gb: int,
+                        pred):
     v = v_ref[0]
     l = l_ref[0]
     live = l > 0
@@ -90,48 +103,46 @@ def _rle_batched_kernel(gk_ref, v_ref, l_ref, o_ref, acc, *, pred):
         prim, const, invert = pred
         cmp = (v >= const) if prim == "ge" else (v == const)
         live = live & (cmp ^ invert)
-    match = (v[None] == ids[:, None, None]) & live[None]
-    _accumulate(acc, ids, match, v, weights=l[None])
-
-    @pl.when(i == ni - 1)
-    def _():
-        _writeback(o_ref, acc)
-
-
-def _pad_planes(planes, block_rows):
-    rows = planes[0].shape[-2]
-    block_rows = min(block_rows, rows)
-    pad = (-rows) % block_rows
-    if pad:
-        planes = [jnp.pad(p, ((0, 0), (0, pad), (0, 0))) for p in planes]
-        rows += pad
-    return planes, rows, block_rows
+    _step(acc, o_ref, gb, lambda base: _accumulate(
+        acc, gk_ref, base, gb, v, v, live, weights=l))
 
 
 def _pad_groups(group_keys, group_block):
+    """(G,) keys -> (G padded to the block multiple,) keys; pads with -1,
+    which no unsigned code matches."""
     g = group_keys.shape[0]
-    group_block = min(group_block, max(g, 1))
+    group_block = min(group_block, max(g, 1), LANES)
     pad = (-g) % group_block
     gk = jnp.pad(jnp.asarray(group_keys, jnp.int32), (0, pad),
                  constant_values=-1)
-    return gk.reshape(-1, group_block), g
+    return gk, group_block, g
 
 
-def _launch(kernel, gk2, planes, rows, block_rows, interpret):
+def _launch(kernel, gk, gb, planes, rows, block_rows, interpret):
+    """One launch over every chunk and group block -> int32[n_chunks,
+    G_padded, 3]. Group keys ride in as scalar-prefetched SMEM data."""
     n_chunks = planes[0].shape[0]
-    gb = gk2.shape[1]
-    spec = pl.BlockSpec((1, block_rows, LANES), lambda c, g, i: (c, i, 0))
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_chunks, gk2.shape[0], rows // block_rows),
-        in_specs=[pl.BlockSpec((1, gb), lambda c, g, i: (g, 0))]
-        + [spec] * len(planes),
-        out_specs=pl.BlockSpec((1, gb, 3), lambda c, g, i: (c, g, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_chunks, gk2.size, 3), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((gb, 3), jnp.int32)],
+    n_gblocks = gk.shape[0] // gb
+    spec = pl.BlockSpec((1, block_rows, LANES),
+                        lambda c, g, i, *_: (c, i, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_chunks, n_gblocks, rows // block_rows),
+        in_specs=[spec] * len(planes),
+        out_specs=pl.BlockSpec((1, 1) + OUT_TILE,
+                               lambda c, g, i, *_: (c, g, 0, 0)),
+        scratch_shapes=[pltpu.SMEM((3 * gb,), jnp.int32)],
+    )
+    tiles = pl.pallas_call(
+        functools.partial(kernel, gb=gb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_chunks, n_gblocks) + OUT_TILE,
+                                       jnp.int32),
         interpret=interpret,
-    )(gk2, *planes)
-    return out
+    )(gk, *planes)
+    # (n_chunks, n_gblocks, 3, gb) -> (n_chunks, n_gblocks * gb, 3)
+    planes3 = jnp.swapaxes(tiles[:, :, :3, :gb], 2, 3)
+    return planes3.reshape(n_chunks, n_gblocks * gb, 3)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "group_block",
@@ -144,9 +155,9 @@ def group_sum_count_batched_planes(keys3, vals3, sel3, group_keys, *,
     keys -> int32[n_chunks, G, 3] accumulator planes, all chunks and all
     group blocks in ONE kernel launch."""
     planes = [jnp.asarray(p, jnp.int32) for p in (keys3, vals3, sel3)]
-    planes, rows, block_rows = _pad_planes(planes, block_rows)
-    gk2, g = _pad_groups(group_keys, group_block)
-    out = _launch(_dense_batched_kernel, gk2, planes, rows, block_rows,
+    planes, rows, block_rows = pad_rows(planes, block_rows)
+    gk, gb, g = _pad_groups(group_keys, group_block)
+    out = _launch(_dense_batched_kernel, gk, gb, planes, rows, block_rows,
                   interpret)
     return out[:, :g]
 
@@ -162,8 +173,8 @@ def rle_group_accumulate_batched_planes(vals3, lens3, group_keys, *,
     -> int32[n_chunks, G, 3]: the fused pre-grouped accumulation, one
     register update per (run, group block) with zero scatter traffic."""
     planes = [jnp.asarray(p, jnp.int32) for p in (vals3, lens3)]
-    planes, runs, block_rows = _pad_planes(planes, block_rows)
-    gk2, g = _pad_groups(group_keys, group_block)
+    planes, runs, block_rows = pad_rows(planes, block_rows)
+    gk, gb, g = _pad_groups(group_keys, group_block)
     kernel = functools.partial(_rle_batched_kernel, pred=pred)
-    out = _launch(kernel, gk2, planes, runs, block_rows, interpret)
+    out = _launch(kernel, gk, gb, planes, runs, block_rows, interpret)
     return out[:, :g]

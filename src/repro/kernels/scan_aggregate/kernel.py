@@ -6,7 +6,8 @@ packed predicate mask never round-trips through HBM. Per grid step a
 constant with the scan kernel's VPU bit-tricks (GE/EQ primitives, optional
 complement for the composed lt/le/ne forms), ANDed with the validity mask
 (tail/shard padding rows carry zero delimiter bits), and immediately
-reduced against the aggregate column's tile into VMEM scratch accumulators.
+reduced against the aggregate column's tile into SMEM scalar accumulators
+(the layout helpers are shared with aggregate/kernel.py).
 
 Streams 3 inputs and writes 4 scalars, vs 4 streamed tiles + a full mask
 write for the scan->aggregate pipeline — at the paper's ~1 B/instr scan
@@ -21,93 +22,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.aggregate.kernel import (acc_scratch, field_reduce,
+                                            fold_acc, init_acc, out_shape,
+                                            out_spec, pad_rows, rows_of,
+                                            write_row)
 from repro.kernels.scan_filter.kernel import DEFAULT_BLOCK_ROWS, LANES
 from repro.kernels.scan_filter.ref import field_masks
 
 
-def _fused_kernel(p_ref, a_ref, v_ref, o_ref, acc, *, op: str,
-                  const_packed, delim, low, invert: bool, code_bits: int,
-                  vmax: int):
-    i = pl.program_id(0)
-    n = pl.num_programs(0)
-
-    @pl.when(i == 0)
-    def _():
-        acc[0, 0] = jnp.int32(0)      # sum_lo (16-bit plane, denormalized)
-        acc[0, 1] = jnp.int32(0)      # sum_hi
-        acc[0, 2] = jnp.int32(0)      # count
-        acc[0, 3] = jnp.int32(vmax)   # min
-        acc[0, 4] = jnp.int32(0)      # max
-
-    x = p_ref[...]
-    h = jnp.uint32(delim)
-    if op == "ge":
-        m = ((x | h) - jnp.uint32(const_packed)) & h
-    elif op == "eq":
-        z = x ^ jnp.uint32(const_packed)
-        m = (~((z | h) - jnp.uint32(low))) & h
-    else:
-        raise ValueError(op)
-    if invert:
-        m = ~m & h
-    m = m & v_ref[...]
-
-    a = a_ref[...]
-    c = 32 // code_bits
-    value_mask = jnp.uint32((1 << (code_bits - 1)) - 1)
-    s = jnp.int32(0)
-    cnt = jnp.int32(0)
-    mn = jnp.int32(vmax)
-    mx = jnp.int32(0)
-    for f in range(c):                       # static unroll over fields
-        vals = ((a >> jnp.uint32(f * code_bits)) & value_mask).astype(
-            jnp.int32)
-        bit = ((m >> jnp.uint32(f * code_bits + code_bits - 1))
-               & jnp.uint32(1)).astype(jnp.int32)
-        sel = bit == 1
-        s += jnp.sum(vals * bit)
-        cnt += jnp.sum(bit)
-        mn = jnp.minimum(mn, jnp.min(jnp.where(sel, vals, vmax)))
-        mx = jnp.maximum(mx, jnp.max(jnp.where(sel, vals, 0)))
-
-    # s is exact (ops.py bounds block_rows); split so the running sum
-    # never wraps int32 (see aggregate/kernel.py)
-    acc[0, 0] += s & 0xFFFF
-    acc[0, 1] += s >> 16
-    acc[0, 2] += cnt
-    acc[0, 3] = jnp.minimum(acc[0, 3], mn)
-    acc[0, 4] = jnp.maximum(acc[0, 4], mx)
-
-    @pl.when(i == n - 1)
-    def _():
-        lo = acc[0, 0]
-        o_ref[0, 0] = lo & 0xFFFF             # normalized planes
-        o_ref[0, 1] = acc[0, 1] + (lo >> 16)
-        o_ref[0, 2] = acc[0, 2]
-        o_ref[0, 3] = acc[0, 3]
-        o_ref[0, 4] = acc[0, 4]
-
-
 def _fused_batched_kernel(const_ref, flag_ref, p_ref, a_ref, v_ref, o_ref,
                           acc, *, delim, low, code_bits: int, vmax: int):
-    """Batched variant: grid (n_chunks, inner), one (1, 5) partial row per
-    chunk. The per-chunk predicate rides in as data — scalar-prefetched
-    planes of packed constants and flag words (bit0 = eq primitive,
-    bit1 = invert) indexed by the chunk grid coordinate — so chunks whose
-    FOR frames translated the constant differently still share one launch.
-    Inner steps iterate fastest: reset at inner 0, normalized writeback at
-    the last inner step, bit-identical per chunk to `_fused_kernel`."""
+    """Grid (n_chunks, inner), one output row per chunk. The per-chunk
+    predicate rides in as data — scalar-prefetched planes of packed
+    constants and flag words (bit0 = eq primitive, bit1 = invert) indexed
+    by the chunk grid coordinate — so chunks whose FOR frames translated
+    the constant differently still share one launch. Inner steps iterate
+    fastest: reset at inner 0, normalized writeback at the last inner
+    step."""
     c_id = pl.program_id(0)
     i = pl.program_id(1)
-    ni = pl.num_programs(1)
 
     @pl.when(i == 0)
     def _():
-        acc[0, 0] = jnp.int32(0)      # sum_lo (16-bit plane, denormalized)
-        acc[0, 1] = jnp.int32(0)      # sum_hi
-        acc[0, 2] = jnp.int32(0)      # count
-        acc[0, 3] = jnp.int32(vmax)   # min
-        acc[0, 4] = jnp.int32(0)      # max
+        init_acc(acc, vmax)
 
     x = p_ref[0]
     h = jnp.uint32(delim)
@@ -120,38 +57,12 @@ def _fused_batched_kernel(const_ref, flag_ref, p_ref, a_ref, v_ref, o_ref,
     m = jnp.where((flags & 2) == 2, m ^ h, m)   # m subset-of h: ^h == ~m&h
     m = m & v_ref[0]
 
-    a = a_ref[0]
-    c = 32 // code_bits
-    value_mask = jnp.uint32((1 << (code_bits - 1)) - 1)
-    s = jnp.int32(0)
-    cnt = jnp.int32(0)
-    mn = jnp.int32(vmax)
-    mx = jnp.int32(0)
-    for f in range(c):                       # static unroll over fields
-        vals = ((a >> jnp.uint32(f * code_bits)) & value_mask).astype(
-            jnp.int32)
-        bit = ((m >> jnp.uint32(f * code_bits + code_bits - 1))
-               & jnp.uint32(1)).astype(jnp.int32)
-        sel = bit == 1
-        s += jnp.sum(vals * bit)
-        cnt += jnp.sum(bit)
-        mn = jnp.minimum(mn, jnp.min(jnp.where(sel, vals, vmax)))
-        mx = jnp.maximum(mx, jnp.max(jnp.where(sel, vals, 0)))
+    fold_acc(acc, *field_reduce(a_ref[0], m, code_bits=code_bits,
+                                vmax=vmax))
 
-    acc[0, 0] += s & 0xFFFF
-    acc[0, 1] += s >> 16
-    acc[0, 2] += cnt
-    acc[0, 3] = jnp.minimum(acc[0, 3], mn)
-    acc[0, 4] = jnp.maximum(acc[0, 4], mx)
-
-    @pl.when(i == ni - 1)
+    @pl.when(i == pl.num_programs(1) - 1)
     def _():
-        lo = acc[0, 0]
-        o_ref[0, 0] = lo & 0xFFFF             # normalized planes
-        o_ref[0, 1] = acc[0, 1] + (lo >> 16)
-        o_ref[0, 2] = acc[0, 2]
-        o_ref[0, 3] = acc[0, 3]
-        o_ref[0, 4] = acc[0, 4]
+        write_row(o_ref, acc)
 
 
 @functools.partial(jax.jit,
@@ -167,16 +78,12 @@ def scan_aggregate_batched_packed(consts, flags, pred3d, agg3d, valid3d, *,
     flags), scalar-prefetched so the grid's chunk coordinate selects each
     tile's predicate without re-specializing the kernel.
     pred3d/agg3d/valid3d: (n_chunks, rows, 128) packed word planes.
-    Returns int32[n_chunks, 5]; each row is bit-identical to the per-chunk
-    `scan_aggregate_packed` at that chunk's (constant, op, invert)."""
-    n_chunks, rows = pred3d.shape[0], pred3d.shape[1]
-    block_rows = min(block_rows, rows)
-    pad = (-rows) % block_rows
-    if pad:
-        pred3d = jnp.pad(pred3d, ((0, 0), (0, pad), (0, 0)))
-        agg3d = jnp.pad(agg3d, ((0, 0), (0, pad), (0, 0)))
-        valid3d = jnp.pad(valid3d, ((0, 0), (0, pad), (0, 0)))
-        rows += pad
+    Returns int32[n_chunks, 5] of [sum_lo, sum_hi, count, min, max] rows.
+    Rows are zero-padded to the block multiple; padded validity words
+    carry zero delimiter bits so padding contributes to no accumulator."""
+    (pred3d, agg3d, valid3d), rows, block_rows = pad_rows(
+        [pred3d, agg3d, valid3d], block_rows)
+    n_chunks = pred3d.shape[0]
     delim, low, value = field_masks(code_bits)
     kernel = functools.partial(_fused_batched_kernel, delim=int(delim),
                                low=int(low), code_bits=code_bits,
@@ -186,15 +93,15 @@ def scan_aggregate_batched_packed(consts, flags, pred3d, agg3d, valid3d, *,
         num_scalar_prefetch=2,
         grid=(n_chunks, rows // block_rows),
         in_specs=[spec, spec, spec],
-        out_specs=pl.BlockSpec((1, 5), lambda c, i, *_: (c, 0)),
-        scratch_shapes=[pltpu.VMEM((1, 5), jnp.int32)],
+        out_specs=out_spec(lambda c, i, *_: (c, 0, 0)),
+        scratch_shapes=[acc_scratch()],
     )
-    return pl.pallas_call(
+    return rows_of(pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_chunks, 5), jnp.int32),
+        out_shape=out_shape(n_chunks),
         interpret=interpret,
-    )(consts, flags, pred3d, agg3d, valid3d)
+    )(consts, flags, pred3d, agg3d, valid3d))
 
 
 @functools.partial(jax.jit,
@@ -205,37 +112,19 @@ def scan_aggregate_packed(pred2d, agg2d, valid2d, *, constant: int, op: str,
                           block_rows: int = DEFAULT_BLOCK_ROWS,
                           interpret: bool = True):
     """(rows, 128) packed predicate/aggregate/validity words -> int32[1, 5]
-    = [sum_lo, sum_hi, count, min, max] (sum = sum_hi * 65536 + sum_lo).
-    `op` is a kernel primitive (ge | eq); the six public predicates are
-    composed in ops.py via (op, constant, invert).
-
-    Rows are zero-padded to the block multiple; padded validity words carry
-    zero delimiter bits so padding contributes to no accumulator."""
-    rows = pred2d.shape[0]
-    block_rows = min(block_rows, rows)
-    pad = (-rows) % block_rows
-    if pad:
-        pred2d = jnp.pad(pred2d, ((0, pad), (0, 0)))
-        agg2d = jnp.pad(agg2d, ((0, pad), (0, 0)))
-        valid2d = jnp.pad(valid2d, ((0, pad), (0, 0)))
-        rows += pad
-    delim, low, value = field_masks(code_bits)
-    vmax = int(value)
-    c = 32 // code_bits
+    = [sum_lo, sum_hi, count, min, max] (sum = sum_hi * 65536 + sum_lo):
+    the batched kernel over one chunk. `op` is a kernel primitive
+    (ge | eq); the six public predicates are composed in ops.py via
+    (op, constant, invert)."""
+    if op not in ("ge", "eq"):
+        raise ValueError(op)
+    vmax = (1 << (code_bits - 1)) - 1
     const_packed = 0
-    for i in range(c):
-        const_packed |= (int(constant) & vmax) << (i * code_bits)
-    kernel = functools.partial(_fused_kernel, op=op,
-                               const_packed=const_packed, delim=int(delim),
-                               low=int(low), invert=invert,
-                               code_bits=code_bits, vmax=vmax)
-    spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(rows // block_rows,),
-        in_specs=[spec, spec, spec],
-        out_specs=pl.BlockSpec((1, 5), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 5), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, 5), jnp.int32)],
-        interpret=interpret,
-    )(pred2d, agg2d, valid2d)
+    for f in range(32 // code_bits):
+        const_packed |= (int(constant) & vmax) << (f * code_bits)
+    flags = (1 if op == "eq" else 0) | (2 if invert else 0)
+    return scan_aggregate_batched_packed(
+        jnp.full((1,), const_packed, jnp.int32),
+        jnp.full((1,), flags, jnp.int32),
+        pred2d[None], agg2d[None], valid2d[None], code_bits=code_bits,
+        block_rows=block_rows, interpret=interpret)

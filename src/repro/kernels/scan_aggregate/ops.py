@@ -141,13 +141,11 @@ def _fused_batched_ref(p3, a3, v3, consts, flags, code_bits: int):
 
 
 def _example(rng):
-    import numpy as np
-
     from repro.kernels.scan_filter import ref as scan_ref
     n = 5001                                  # exercises the tail validity
     pw = scan_ref.pack(rng.integers(0, 128, n), 8)
     aw = scan_ref.pack(rng.integers(0, 128, n), 8)
-    valid = scan_ref.pack_mask(np.arange(pw.size * 4) < n, 8)
+    valid = scan_ref.valid_mask(pw.size, n, 8)
     return (jnp.asarray(pw), jnp.asarray(aw), jnp.asarray(valid),
             64, "lt", 8), {}
 

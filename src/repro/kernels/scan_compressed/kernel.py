@@ -7,15 +7,17 @@ mask round-trip; this one avoids touching *rows* at all. Per grid step a
 the VPU (runs hold decoded codes, so all six predicates are plain int32
 compares — no BitWeaving masks needed) and reduced against the matching
 run-length tile: a run of length n contributes n to the count and n*value
-to the sum, entirely in registers/VMEM. A chunk of r rows in k runs
-streams 8k bytes instead of 4*ceil(r/cpw) — on sorted or low-cardinality
-columns that is a 10-100x traffic cut at identical answers.
+to the sum, entirely in registers and SMEM scalars. A chunk of r rows in
+k runs streams 8k bytes instead of 4*ceil(r/cpw) — on sorted or
+low-cardinality columns that is a 10-100x traffic cut at identical
+answers.
 
 Exactness: the store bounds chunks at 65536 rows with payloads < 2^15, so
 every partial (value*length summed over a chunk) stays below 2^31 and the
-int32 accumulator is exact; the sum leaves as the normalized 16-bit
-planes all aggregate paths share. Zero-length runs (pow2 padding) are
-cancelled by the `lengths > 0` term of the selection.
+int32 tile partial is exact; it folds into the 16/16 sum planes all
+aggregate paths share (aggregate/kernel.py holds the layout helpers).
+Zero-length runs (pow2 padding) are cancelled by the `lengths > 0` term
+of the selection.
 """
 from __future__ import annotations
 
@@ -24,63 +26,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.aggregate.kernel import (acc_scratch, fold_acc, init_acc,
+                                            out_shape, out_spec, pad_rows,
+                                            rows_of, write_row)
 from repro.kernels.scan_filter.kernel import DEFAULT_BLOCK_ROWS, LANES
-
-
-def _rle_kernel(v_ref, l_ref, o_ref, acc, *, op: str, constant: int,
-                vmax: int):
-    i = pl.program_id(0)
-    n = pl.num_programs(0)
-
-    @pl.when(i == 0)
-    def _():
-        acc[0, 0] = jnp.int32(0)      # raw sum (chunk-bounded, exact)
-        acc[0, 1] = jnp.int32(0)      # unused until the final normalize
-        acc[0, 2] = jnp.int32(0)      # count
-        acc[0, 3] = jnp.int32(vmax)   # min
-        acc[0, 4] = jnp.int32(0)      # max
-
-    v = v_ref[...]
-    l = l_ref[...]
-    c = jnp.int32(constant)
-    cmp = {"lt": v < c, "le": v <= c, "gt": v > c, "ge": v >= c,
-           "eq": v == c, "ne": v != c}[op]
-    sel = cmp & (l > 0)
-
-    acc[0, 0] += jnp.sum(jnp.where(sel, v * l, 0))
-    acc[0, 2] += jnp.sum(jnp.where(sel, l, 0))
-    acc[0, 3] = jnp.minimum(acc[0, 3], jnp.min(jnp.where(sel, v, vmax)))
-    acc[0, 4] = jnp.maximum(acc[0, 4], jnp.max(jnp.where(sel, v, 0)))
-
-    @pl.when(i == n - 1)
-    def _():
-        s = acc[0, 0]
-        o_ref[0, 0] = s & 0xFFFF              # normalized sum planes
-        o_ref[0, 1] = s >> 16
-        o_ref[0, 2] = acc[0, 2]
-        o_ref[0, 3] = acc[0, 3]
-        o_ref[0, 4] = acc[0, 4]
 
 
 def _rle_batched_kernel(v_ref, l_ref, o_ref, acc, *, op: str, constant: int,
                         vmax: int):
-    """Batched variant: grid (n_chunks, inner); one (1, 5) partial row per
-    chunk. The inner dimension iterates fastest (TPU grid order), so the
-    per-chunk accumulator resets at inner step 0 and writes back normalized
-    at the last inner step — chunk c's partial never sees chunk c±1's
-    tiles, keeping every row bit-identical to the per-chunk kernel."""
+    """Grid (n_chunks, inner); one output row per chunk. The inner
+    dimension iterates fastest (TPU grid order), so the per-chunk
+    accumulator resets at inner step 0 and writes back normalized at the
+    last inner step — chunk c's partial never sees chunk c±1's tiles."""
     i = pl.program_id(1)
-    ni = pl.num_programs(1)
 
     @pl.when(i == 0)
     def _():
-        acc[0, 0] = jnp.int32(0)      # raw sum (chunk-bounded, exact)
-        acc[0, 1] = jnp.int32(0)      # unused until the final normalize
-        acc[0, 2] = jnp.int32(0)      # count
-        acc[0, 3] = jnp.int32(vmax)   # min
-        acc[0, 4] = jnp.int32(0)      # max
+        init_acc(acc, vmax)
 
     v = v_ref[0]
     l = l_ref[0]
@@ -88,20 +51,14 @@ def _rle_batched_kernel(v_ref, l_ref, o_ref, acc, *, op: str, constant: int,
     cmp = {"lt": v < c, "le": v <= c, "gt": v > c, "ge": v >= c,
            "eq": v == c, "ne": v != c}[op]
     sel = cmp & (l > 0)
+    fold_acc(acc, jnp.sum(jnp.where(sel, v * l, 0)),
+             jnp.sum(jnp.where(sel, l, 0)),
+             jnp.min(jnp.where(sel, v, vmax)),
+             jnp.max(jnp.where(sel, v, 0)))
 
-    acc[0, 0] += jnp.sum(jnp.where(sel, v * l, 0))
-    acc[0, 2] += jnp.sum(jnp.where(sel, l, 0))
-    acc[0, 3] = jnp.minimum(acc[0, 3], jnp.min(jnp.where(sel, v, vmax)))
-    acc[0, 4] = jnp.maximum(acc[0, 4], jnp.max(jnp.where(sel, v, 0)))
-
-    @pl.when(i == ni - 1)
+    @pl.when(i == pl.num_programs(1) - 1)
     def _():
-        s = acc[0, 0]
-        o_ref[0, 0] = s & 0xFFFF              # normalized sum planes
-        o_ref[0, 1] = s >> 16
-        o_ref[0, 2] = acc[0, 2]
-        o_ref[0, 3] = acc[0, 3]
-        o_ref[0, 4] = acc[0, 4]
+        write_row(o_ref, acc)
 
 
 @functools.partial(jax.jit,
@@ -115,28 +72,23 @@ def rle_scan_aggregate_batched_packed(values3d, lengths3d, *, constant: int,
     [sum_lo, sum_hi, count, min, max] row per chunk, all chunks in ONE
     kernel launch. Rows are zero-padded per chunk to the block multiple
     and across chunks to the widest chunk; padded runs carry length 0 and
-    contribute to no accumulator, so each output row equals the per-chunk
-    `rle_scan_aggregate_packed` bit-for-bit."""
-    n_chunks, rows = values3d.shape[0], values3d.shape[1]
-    block_rows = min(block_rows, rows)
-    pad = (-rows) % block_rows
-    if pad:
-        values3d = jnp.pad(values3d, ((0, 0), (0, pad), (0, 0)))
-        lengths3d = jnp.pad(lengths3d, ((0, 0), (0, pad), (0, 0)))
-        rows += pad
+    contribute to no accumulator."""
+    (values3d, lengths3d), rows, block_rows = pad_rows(
+        [values3d, lengths3d], block_rows)
+    n_chunks = values3d.shape[0]
     vmax = (1 << (code_bits - 1)) - 1
     kernel = functools.partial(_rle_batched_kernel, op=op,
                                constant=int(constant), vmax=vmax)
     spec = pl.BlockSpec((1, block_rows, LANES), lambda c, i: (c, i, 0))
-    return pl.pallas_call(
+    return rows_of(pl.pallas_call(
         kernel,
         grid=(n_chunks, rows // block_rows),
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1, 5), lambda c, i: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_chunks, 5), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, 5), jnp.int32)],
+        out_specs=out_spec(lambda c, i: (c, 0, 0)),
+        out_shape=out_shape(n_chunks),
+        scratch_shapes=[acc_scratch()],
         interpret=interpret,
-    )(values3d, lengths3d)
+    )(values3d, lengths3d))
 
 
 @functools.partial(jax.jit,
@@ -147,27 +99,8 @@ def rle_scan_aggregate_packed(values2d, lengths2d, *, constant: int,
                               block_rows: int = DEFAULT_BLOCK_ROWS,
                               interpret: bool = True):
     """(rows, 128) int32 run-value/run-length planes -> int32[1, 5]
-    = [sum_lo, sum_hi, count, min, max] over the rows the runs encode.
-
-    Rows are zero-padded to the block multiple; padded (and pow2-pad)
-    runs carry length 0 and contribute to no accumulator."""
-    rows = values2d.shape[0]
-    block_rows = min(block_rows, rows)
-    pad = (-rows) % block_rows
-    if pad:
-        values2d = jnp.pad(values2d, ((0, pad), (0, 0)))
-        lengths2d = jnp.pad(lengths2d, ((0, pad), (0, 0)))
-        rows += pad
-    vmax = (1 << (code_bits - 1)) - 1
-    kernel = functools.partial(_rle_kernel, op=op, constant=int(constant),
-                               vmax=vmax)
-    spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(rows // block_rows,),
-        in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1, 5), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 5), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, 5), jnp.int32)],
-        interpret=interpret,
-    )(values2d, lengths2d)
+    = [sum_lo, sum_hi, count, min, max] over the rows the runs encode:
+    the batched kernel over one chunk."""
+    return rle_scan_aggregate_batched_packed(
+        values2d[None], lengths2d[None], constant=constant, op=op,
+        code_bits=code_bits, block_rows=block_rows, interpret=interpret)

@@ -31,16 +31,15 @@ def field_masks(code_bits: int):
 
 def pack(codes, code_bits: int):
     """codes: (N,) ints in [0, 2^(bits-1)) -> packed uint32 words
-    (N padded to a multiple of codes_per_word)."""
-    codes = np.asarray(codes, np.uint32)
+    (N padded to a multiple of codes_per_word). Works field by field over
+    strided views, so the only temporaries are word-sized."""
+    codes = np.asarray(codes)
     c = codes_per_word(code_bits)
-    n = len(codes)
-    pad = (-n) % c
-    codes = np.pad(codes, (0, pad))
-    codes = codes.reshape(-1, c)
-    out = np.zeros(len(codes), np.uint32)
+    out = np.zeros(-(-len(codes) // c), np.uint32)
     for i in range(c):
-        out |= codes[:, i] << np.uint32(i * code_bits)
+        f = codes[i::c].astype(np.uint32)
+        f <<= np.uint32(i * code_bits)
+        out[:len(f)] |= f
     return out
 
 
@@ -53,18 +52,33 @@ def unpack(words, code_bits: int):
     return vals.reshape(-1)
 
 
-def pack_mask(sel, code_bits: int):
-    """Boolean per-code selection -> packed delimiter-bit mask words
-    (inverse of unpack_mask; selection padded to a word multiple with
-    False). Used to build validity masks that cancel tail/shard padding."""
-    sel = np.asarray(sel, bool)
+def code_range(words, code_bits: int, n_rows: int) -> tuple[int, int]:
+    """(min, max) of the first `n_rows` codes of packed host words, field
+    by field (no per-row temporaries); (0, -1) when there are none."""
     c = codes_per_word(code_bits)
-    pad = (-len(sel)) % c
-    sel = np.pad(sel, (0, pad)).reshape(-1, c)
-    out = np.zeros(len(sel), np.uint32)
-    for i in range(c):
-        out |= sel[:, i].astype(np.uint32) << np.uint32(
-            i * code_bits + code_bits - 1)
+    words = np.asarray(words, np.uint32)
+    lo, hi = None, None
+    for i in range(min(c, max(n_rows, 0))):
+        f = (words[:-(-(n_rows - i) // c)] >> np.uint32(i * code_bits)) \
+            & np.uint32((1 << code_bits) - 1)
+        lo = int(f.min()) if lo is None else min(lo, int(f.min()))
+        hi = int(f.max()) if hi is None else max(hi, int(f.max()))
+    return (0, -1) if lo is None else (lo, hi)
+
+
+def valid_mask(n_words: int, n_rows: int, code_bits: int):
+    """Packed delimiter-bit mask over `n_words` words with a bit set for
+    exactly the first `n_rows` codes: the validity plane that cancels
+    tail-of-word and shard padding. Built word-wise (no per-row
+    temporaries), so it stays cheap at billions of rows."""
+    c = codes_per_word(code_bits)
+    n_rows = max(n_rows, 0)
+    out = np.zeros(n_words, np.uint32)
+    full = min(n_rows // c, n_words)
+    out[:full] = field_masks(code_bits)[0]
+    if full < n_words:
+        for i in range(n_rows - full * c):
+            out[full] |= np.uint32(1 << (i * code_bits + code_bits - 1))
     return out
 
 

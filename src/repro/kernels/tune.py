@@ -129,7 +129,9 @@ def autotune(op: str, skey: str, candidates: dict, bench,
     """Timed sweep over the candidate grid; persists + returns the entry.
 
     bench(params) runs the op once with those block sizes (it should
-    block_until_ready). Candidates that raise are skipped. A cache hit
+    block_until_ready). A candidate that raises — a tile invalid for this
+    shape, or one the TPU compiler refuses — stays in the sweep as
+    {"params", "refused": error text} and never wins. A cache hit
     returns immediately without timing anything.
     """
     cache = get_cache()
@@ -145,12 +147,16 @@ def autotune(op: str, skey: str, candidates: dict, bench,
             for _ in range(repeat):
                 bench(params)
             us = (time.perf_counter() - t0) / repeat * 1e6
-        except Exception:                       # invalid tile for this shape
+        except Exception as e:
+            sweep.append({"params": params,
+                          "refused": f"{type(e).__name__}: {e}"[:2000]})
             continue
         sweep.append({"params": params, "us": round(us, 1)})
-    if not sweep:
-        raise ValueError(f"no viable candidates for {op}|{skey}")
-    best = min(sweep, key=lambda r: r["us"])
+    timed = [r for r in sweep if "us" in r]
+    if not timed:
+        raise ValueError(f"no viable candidates for {op}|{skey}: "
+                         f"{[r['refused'] for r in sweep]}")
+    best = min(timed, key=lambda r: r["us"])
     entry = {"params": best["params"], "us": best["us"], "sweep": sweep}
     cache.store(op, skey, entry)
     return entry

@@ -3,33 +3,18 @@ touches jax device state)."""
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after jax 0.4.x; plain meshes behave identically
-    from jax.sharding import AxisType
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}
-except ImportError:
-    _AXIS_KW = lambda n: {}
+from jax.sharding import AxisType
 
 
-def _make(shape, axes):
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
-    import math
-
-    import numpy as np
-    from jax.sharding import Mesh
-    n = math.prod(shape)
-    return Mesh(np.asarray(jax.devices()[:n]).reshape(shape), axes)
+def make_mesh(shape, axes):
+    """A mesh over the first prod(shape) devices, every axis Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; the multi-pod mesh stacks 2 pods on a
     leading "pod" axis (512 chips)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/examples (host devices or real)."""
-    return _make(tuple(shape), tuple(axes))
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))

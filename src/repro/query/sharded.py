@@ -20,13 +20,28 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.scan_filter import ref as packref
 from repro.query import physical
 from repro.query.physical import ColumnSlice
 from repro.query.plan import columns_of
+
+
+# rows a shard unpacks per grouped-kernel launch (int32 planes of 64 MiB)
+GROUP_SLAB_ROWS = 1 << 24
+
+
+def _merge_planes(a: dict, b: dict) -> dict:
+    """Add normalized [sum_lo, sum_hi, count] planes, renormalized —
+    exact in int32 while the shard's hi plane and count stay < 2^31."""
+    out = {}
+    for name, p in a.items():
+        q = b[name]
+        lo = p[:, 0] + q[:, 0]
+        out[name] = jnp.stack([lo & 0xFFFF, p[:, 1] + q[:, 1] + (lo >> 16),
+                               p[:, 2] + q[:, 2]], axis=1)
+    return out
 
 
 @dataclass
@@ -71,15 +86,18 @@ class ShardedTable:
         sharding = NamedSharding(mesh, P(axis))
         slices = {}
         for name, col in table.columns.items():
-            cpw = 32 // col.code_bits
-            w = np.zeros(total_rows // cpw, np.uint32)
-            w[:col.words.size] = np.asarray(col.words)
-            valid = packref.pack_mask(
-                np.arange(total_rows) < table.num_rows, col.code_bits)
-            slices[name] = ColumnSlice(
-                jax.device_put(jnp.asarray(w), sharding),
-                jax.device_put(jnp.asarray(valid), sharding),
-                col.code_bits)
+            # host numpy straight into the sharded layout: each device
+            # receives only its own rows, and nothing else stays resident
+            n_words = total_rows * col.code_bits // 32
+            w = np.asarray(col.words, np.uint32)
+            if w.size != n_words:
+                w = np.zeros(n_words, np.uint32)
+                w[:col.words.size] = col.words
+            valid = packref.valid_mask(n_words, table.num_rows,
+                                       col.code_bits)
+            slices[name] = ColumnSlice(jax.device_put(w, sharding),
+                                       jax.device_put(valid, sharding),
+                                       col.code_bits)
         return cls(table, mesh, axis, rps, slices)
 
     # --- tier accounting --------------------------------------------------
@@ -154,9 +172,9 @@ class ShardedTable:
             # stacked per-shard output instead of a combined scalar
             return jax.tree.map(lambda x: jnp.reshape(x, (1,)), out)
 
-        return jax.jit(shard_map(per_shard, mesh=self.mesh,
-                                 in_specs=(P(axis),) * (2 * len(names)),
-                                 out_specs=P(axis), check_rep=False))
+        return jax.jit(jax.shard_map(per_shard, mesh=self.mesh,
+                                     in_specs=(P(axis),) * (2 * len(names)),
+                                     out_specs=P(axis), check_vma=False))
 
     # --- degraded-mode recovery source ------------------------------------
     def shard_row_range(self, shard: int) -> tuple[int, int]:
@@ -183,8 +201,7 @@ class ShardedTable:
             w0 = lo // cpw
             w1 = min(w0 + self.rows_per_shard // cpw, int(col.words.size))
             words = np.asarray(col.words)[w0:w1]
-            valid = packref.pack_mask(
-                np.arange(words.size * cpw) < (hi - lo), col.code_bits)
+            valid = packref.valid_mask(words.size, hi - lo, col.code_bits)
             out[name] = ColumnSlice(jnp.asarray(words), jnp.asarray(valid),
                                     col.code_bits)
         return out
@@ -200,11 +217,11 @@ class ShardedTable:
             return physical.execute(plan, aggregates, slices, mode=mode,
                                     axis=axis)
 
-        # check_rep=False: pallas_call has no replication rule; the outputs
+        # check_vma=False: pallas_call has no replication rule; the outputs
         # are psum-combined and genuinely replicated
-        return jax.jit(shard_map(per_shard, mesh=self.mesh,
-                                 in_specs=(P(axis),) * (2 * len(names)),
-                                 out_specs=P(), check_rep=False))
+        return jax.jit(jax.shard_map(per_shard, mesh=self.mesh,
+                                     in_specs=(P(axis),) * (2 * len(names)),
+                                     out_specs=P(), check_vma=False))
 
     # --- grouped execution (GroupBy / HashJoin) ---------------------------
     def key_code_range(self, key: str) -> tuple[int, int]:
@@ -214,11 +231,8 @@ class ShardedTable:
         cached = self._jitted.get(("range", key))
         if cached is None:
             col = self.table.columns[key]
-            codes = np.asarray(packref.unpack(
-                col.words, col.code_bits))[: col.num_rows]
-            cached = self._jitted[("range", key)] = (
-                (int(codes.min()), int(codes.max())) if codes.size
-                else (0, -1))
+            cached = self._jitted[("range", key)] = packref.code_range(
+                col.words, col.code_bits, col.num_rows)
         return cached
 
     def execute_grouped_planes(self, plan, key: str, aggs: tuple, domain,
@@ -253,29 +267,50 @@ class ShardedTable:
         names = self._referenced(plan, aggs + (key,))
         bits = {n: self.slices[n].code_bits for n in names}
         axis = self.axis
+        value_cols = aggs if aggs else ("",)
+        # the kernel reads int32 code planes, 4 B per row per column: a
+        # shard unpacks one slab of rows at a time, so the planes stay
+        # small next to the packed table at any table size
+        slab = min(GROUP_SLAB_ROWS, self.rows_per_shard)
+        n_slabs, tail = divmod(self.rows_per_shard, slab)
 
-        def per_shard(gk, *flat):
+        def slab_planes(gk, flat, row0, n_rows):
             cols, valid = {}, None
             for i, n in enumerate(names):
-                cols[n] = jnp.asarray(
-                    packref.unpack(flat[2 * i], bits[n]), jnp.int32)
+                cpw = 32 // bits[n]
+                words = jax.lax.dynamic_slice_in_dim(
+                    flat[2 * i], row0 // cpw, n_rows // cpw)
+                cols[n] = jnp.asarray(packref.unpack(words, bits[n]),
+                                      jnp.int32)
                 if n == key:
-                    valid = packref.unpack_mask(flat[2 * i + 1], bits[n])
+                    valid = packref.unpack_mask(
+                        jax.lax.dynamic_slice_in_dim(
+                            flat[2 * i + 1], row0 // cpw, n_rows // cpw),
+                        bits[n])
             sel = relational.eval_plan_codes(plan, cols) & valid
             keys3 = gops.lift_chunks([cols[key]])
             sel3 = gops.lift_chunks([sel.astype(jnp.int32)])
-            out = {}
-            for name in (aggs if aggs else ("",)):
-                vals3 = gops.lift_chunks([cols[name]]) if name \
-                    else jnp.zeros_like(keys3)
-                out[name] = gops.group_sum_count_batched(
-                    keys3, vals3, sel3, gk, mode=mode)
-            return out
+            return {name: gops.group_sum_count_batched(
+                        keys3, gops.lift_chunks([cols[name]]) if name
+                        else jnp.zeros_like(keys3), sel3, gk, mode=mode)[0]
+                    for name in value_cols}
 
-        return jax.jit(shard_map(
+        def per_shard(gk, *flat):
+            acc = {name: jnp.zeros((gk.shape[0], 3), jnp.int32)
+                   for name in value_cols}
+            if n_slabs:
+                acc = jax.lax.fori_loop(
+                    0, n_slabs, lambda s, a: _merge_planes(
+                        a, slab_planes(gk, flat, s * slab, slab)), acc)
+            if tail:
+                acc = _merge_planes(
+                    acc, slab_planes(gk, flat, n_slabs * slab, tail))
+            return {name: p[None] for name, p in acc.items()}
+
+        return jax.jit(jax.shard_map(
             per_shard, mesh=self.mesh,
             in_specs=(P(),) + (P(axis),) * (2 * len(names)),
-            out_specs=P(axis), check_rep=False))
+            out_specs=P(axis), check_vma=False))
 
     def execute_grouped(self, query, mode=None) -> dict:
         """GroupBy/HashJoin across the mesh: per-shard dense accumulator

@@ -234,9 +234,8 @@ def encode_chunk(codes, code_bits: int,
         return EncodedChunk(
             enc, n, code_bits, stats, n_runs=n_runs,
             values=jnp.asarray(values), lengths=jnp.asarray(lengths),
-            valid=jnp.asarray(packref.pack_mask(
-                np.arange(plain_nbytes(n, code_bits) // 4
-                          * (32 // code_bits)) < n, code_bits))).seal()
+            valid=jnp.asarray(packref.valid_mask(
+                plain_nbytes(n, code_bits) // 4, n, code_bits))).seal()
     if enc is Encoding.FOR:
         base, width = stats.vmin, stats.delta_bits
         payload = codes - np.uint32(base)
@@ -244,8 +243,7 @@ def encode_chunk(codes, code_bits: int,
         base, width = 0, code_bits
         payload = codes
     words = packref.pack(payload, width)
-    valid = packref.pack_mask(
-        np.arange(len(words) * (32 // width)) < n, width)
+    valid = packref.valid_mask(len(words), n, width)
     return EncodedChunk(enc, n, code_bits, stats, width=width, base=base,
                         words=jnp.asarray(words),
                         valid=jnp.asarray(valid)).seal()

@@ -37,7 +37,7 @@ from repro.kernels.aggregate import ops as agg_ops
 from repro.kernels.scan_aggregate import ops as fused_ops
 from repro.kernels.scan_compressed import ops as rle_ops
 from repro.kernels.scan_filter import ops as scan_ops
-from repro.kernels.scan_filter.ref import codes_per_word, pack, pack_mask
+from repro.kernels.scan_filter.ref import codes_per_word, pack, valid_mask
 from repro.query import physical
 from repro.query.physical import ColumnSlice
 from repro.query.plan import And, Or, Plan, Pred, columns_of
@@ -193,14 +193,12 @@ def _bind_group(col, cids, W: int) -> _BoundGroup:
                 np.uint32)
             words_np.append(pack(delta, W))
             bases.append(ch.base)
-    cpw = codes_per_word(W)
     nw = max(w.size for w in words_np)
     words3 = np.zeros((len(cids), nw), np.uint32)
     valid3 = np.zeros((len(cids), nw), np.uint32)
-    rows_idx = np.arange(nw * cpw)
     for k, (ci, w) in enumerate(zip(cids, words_np)):
         words3[k, :w.size] = w
-        valid3[k] = pack_mask(rows_idx < col.chunks[ci].n_rows, W)[:nw]
+        valid3[k] = valid_mask(nw, col.chunks[ci].n_rows, W)
     return _BoundGroup(jnp.asarray(words3), jnp.asarray(valid3),
                        tuple(bases))
 

@@ -117,6 +117,56 @@ def test_tune_cache_json_roundtrip_and_second_call_hit(tmp_path):
         tune.set_cache_path(None)    # back to the default cache file
 
 
+def test_autotune_keeps_refused_candidates_with_their_error(tmp_path):
+    """A candidate the compiler refuses stays in the sweep with its error
+    text and never wins; when every candidate is refused the error names
+    each refusal."""
+    tune.set_cache_path(tmp_path / "tune.json")
+    try:
+        def bench(params):
+            if params["block_rows"] == 128:
+                raise ValueError("Mosaic refused block (128, 128)")
+
+        entry = tune.autotune("refusing_op", "rows=512",
+                              {"block_rows": (64, 128)}, bench, repeat=1)
+        assert entry["params"] == {"block_rows": 64}
+        refused = [r for r in entry["sweep"] if "refused" in r]
+        assert refused == [{"params": {"block_rows": 128},
+                            "refused": "ValueError: Mosaic refused block "
+                                       "(128, 128)"}]
+
+        def never(params):
+            raise RuntimeError(f"no tile {params['block_rows']}")
+
+        with pytest.raises(ValueError, match="no tile 32"):
+            tune.autotune("refusing_op", "rows=64",
+                          {"block_rows": (32,)}, never, repeat=1)
+    finally:
+        tune.set_cache_path(None)
+
+
+@pytest.mark.parametrize("env_dir", [None, "/some/shared/jax_cache"])
+def test_compile_cache_honours_env_else_fixed_repo_dir(monkeypatch,
+                                                       env_dir):
+    from repro.launch import compile_cache
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(compile_cache.DEFAULT_DIR)
+            assert want.endswith("artifacts/jax_cache")
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+            want = env_dir
+        assert compile_cache.enable() == want
+        # with the variable set, JAX reads it and nothing is set in code
+        assert jax.config.jax_compilation_cache_dir == (
+            want if env_dir is None else None)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+
+
 def test_ops_consult_tuned_block_sizes(tmp_path):
     """A cached winner changes the block size scan_filter actually uses."""
     from repro.kernels.scan_filter import kernel as K

@@ -55,6 +55,26 @@ def test_scan_filter_semantics_vs_plain_numpy():
     np.testing.assert_array_equal(sel, codes < 40)
 
 
+@pytest.mark.parametrize("code_bits", [2, 4, 8, 16])
+@pytest.mark.parametrize("n", [0, 1, 7, 129, 1000])
+def test_pack_and_validity_planes_vs_plain_numpy(code_bits, n):
+    """Host packing helpers against per-row definitions: pack round-trips
+    through unpack, valid_mask sets exactly the first n delimiter bits
+    (also over extra padding words), code_range is the codes' (min, max)."""
+    codes = RNG.integers(0, 1 << (code_bits - 1), n)
+    words = scan_ref.pack(codes, code_bits)
+    cpw = 32 // code_bits
+    assert words.size == -(-n // cpw)
+    np.testing.assert_array_equal(
+        np.asarray(scan_ref.unpack(words, code_bits))[:n], codes)
+    for n_words in (words.size, words.size + 3):
+        valid = scan_ref.valid_mask(n_words, n, code_bits)
+        sel = np.asarray(scan_ref.unpack_mask(valid, code_bits))
+        np.testing.assert_array_equal(sel, np.arange(n_words * cpw) < n)
+    want = (int(codes.min()), int(codes.max())) if n else (0, -1)
+    assert scan_ref.code_range(words, code_bits, n) == want
+
+
 # --------------------------------------------------------------------------
 # aggregate
 # --------------------------------------------------------------------------

@@ -280,3 +280,20 @@ def test_grouped_charges_tier_and_energy(table, encoded):
     # physical bytes: the compressed footprint of r+u+f, not the logical
     assert r.bytes_scanned < r.logical_bytes
     assert s["energy"]["total_j"] > 0
+
+
+@pytest.mark.parametrize("slab_rows", [1024, 1 << 24])
+@pytest.mark.parametrize("mode", ("pallas", "xla_ref"))
+def test_sharded_grouped_slabs_match_numpy(table, monkeypatch, slab_rows,
+                                           mode):
+    """The sharded grouped path unpacks one slab of rows per kernel launch
+    and merges the normalized planes on the device: several slabs plus a
+    ragged tail give the same answer as one slab and as numpy."""
+    from repro.launch.mesh import make_mesh
+    from repro.query import sharded
+    monkeypatch.setattr(sharded, "GROUP_SLAB_ROWS", slab_rows)
+    st = sharded.ShardedTable.shard(table, make_mesh((1,), ("data",)))
+    q = GroupBy("u", ("w", "f"), where=Pred("w", "lt", 9050))
+    sel = table.columns["w"].decode() < 9050
+    assert st.execute_grouped(q, mode=mode) == \
+        _np_grouped(table, "u", ("w", "f"), sel)

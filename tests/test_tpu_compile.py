@@ -1,0 +1,170 @@
+"""Compile the query-side kernels and served-path programs for a described
+TPU v5e, at real plane shapes, without a chip.
+
+Interpret mode accepts layouts the TPU compiler (Mosaic) refuses — scalar
+stores to VMEM, blocks that break the (8, 128) tiling rule — so every
+query-side Pallas entry point is lowered with interpret=False against a
+`v5e:2x2` topology description and must produce a `tpu_custom_call`.
+The topology is described inside a fixture (never at import), so every
+pytest worker collects the same tests and only the worker running this
+file loads the TPU compiler.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import dispatch
+from repro.kernels.aggregate import kernel as agg_k
+from repro.kernels.group_aggregate import kernel as group_k
+from repro.kernels.scan_aggregate import kernel as fused_k
+from repro.kernels.scan_compressed import kernel as rle_k
+from repro.kernels.scan_filter import kernel as scan_k
+from repro.query import And, GroupBy, Pred, Query
+from repro.query.physical import ColumnSlice
+from repro.query.sharded import ShardedTable
+
+ROWS = 1 << 16            # (ROWS, 128) words per plane: 32 MiB
+CHUNKS = 8
+TABLE_ROWS = 1 << 30      # the one-chip smoke table
+SCHEMA = {"a": 8, "b": 8, "c": 16}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache off meanwhile."""
+    from jax.experimental.compilation_cache import compilation_cache
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+def _kernel_cases(s):
+    """entry point -> (fn, arg shapes); `s(shape, dtype)` places a shape
+    on the described chip."""
+    w2 = s((ROWS, 128), jnp.uint32)
+    w3 = s((CHUNKS, ROWS, 128), jnp.uint32)
+    i2 = s((ROWS, 128), jnp.int32)
+    i3 = s((CHUNKS, ROWS, 128), jnp.int32)
+    scal = s((CHUNKS,), jnp.int32)
+    off = {"interpret": False}
+    return {
+        "scan_packed": (lambda w: scan_k.scan_packed(
+            w, 5, op="ge", code_bits=8, **off), (w2,)),
+        "aggregate_packed": (lambda w, m: agg_k.aggregate_packed(
+            w, m, code_bits=8, **off), (w2, w2)),
+        "aggregate_batched_packed": (lambda w, m:
+                                     agg_k.aggregate_batched_packed(
+                                         w, m, code_bits=16, **off),
+                                     (w3, w3)),
+        "scan_aggregate_packed": (lambda p, a, v:
+                                  fused_k.scan_aggregate_packed(
+                                      p, a, v, constant=3, op="eq",
+                                      invert=True, code_bits=8, **off),
+                                  (w2, w2, w2)),
+        "scan_aggregate_batched_packed": (
+            lambda c, f, p, a, v: fused_k.scan_aggregate_batched_packed(
+                c, f, p, a, v, code_bits=8, **off),
+            (scal, scal, w3, w3, w3)),
+        "rle_scan_aggregate_packed": (lambda v, n:
+                                      rle_k.rle_scan_aggregate_packed(
+                                          v, n, constant=3, op="lt",
+                                          code_bits=8, **off), (i2, i2)),
+        "rle_scan_aggregate_batched_packed": (
+            lambda v, n: rle_k.rle_scan_aggregate_batched_packed(
+                v, n, constant=3, op="ne", code_bits=16, **off), (i3, i3)),
+        "group_sum_count_batched_planes": (
+            lambda k, v, m, g: group_k.group_sum_count_batched_planes(
+                k, v, m, g, **off), (i3, i3, i3, s((128,), jnp.int32))),
+        "rle_group_accumulate_batched_planes": (
+            lambda v, n, g: group_k.rle_group_accumulate_batched_planes(
+                v, n, g, pred=("ge", 3, True), **off),
+            (i3, i3, s((13,), jnp.int32))),
+    }
+
+
+KERNELS = ("scan_packed", "aggregate_packed", "aggregate_batched_packed",
+           "scan_aggregate_packed", "scan_aggregate_batched_packed",
+           "rle_scan_aggregate_packed", "rle_scan_aggregate_batched_packed",
+           "group_sum_count_batched_planes",
+           "rle_group_accumulate_batched_planes")
+
+
+@pytest.mark.parametrize("entry", KERNELS)
+def test_kernel_compiles_for_v5e(entry, one_chip, no_persistent_cache):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = _kernel_cases(s)[entry]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+SERVED = {
+    "fused": Query(Pred("a", "lt", 40), ("b",)),
+    "mask_and_aggregate": Query(And.of(Pred("a", "lt", 64),
+                                       Pred("b", "ge", 32)), ("a", "b")),
+    "grouped": GroupBy("a", ("b",), where=Pred("c", "lt", 16384)),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SERVED))
+def test_served_program_compiles_for_v5e(shape, topo, no_persistent_cache,
+                                         monkeypatch):
+    """The sharded engine's per-query program over a 2^30-row table on one
+    described chip: kernels compile, and the program fits the chip."""
+    # the engine resolves interpret mode from the backend it runs on,
+    # which here is the CPU; steer it to the chip's compiled branch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    st = ShardedTable(table=None, mesh=mesh, axis="data",
+                      rows_per_shard=TABLE_ROWS,
+                      slices={n: ColumnSlice(None, None, b)
+                              for n, b in SCHEMA.items()})
+    plane = NamedSharding(mesh, P("data"))
+
+    def planes(name):
+        sds = jax.ShapeDtypeStruct((TABLE_ROWS * SCHEMA[name] // 32,),
+                                   jnp.uint32, sharding=plane)
+        return [sds, sds]
+
+    q = SERVED[shape]
+    if shape == "grouped":
+        names = st._referenced(q.plan(), q.aggs + (q.key,))
+        fn = st._build_grouped(q.plan(), q.key, q.aggs, "pallas")
+        args = [jax.ShapeDtypeStruct((128,), jnp.int32,
+                                     sharding=NamedSharding(mesh, P()))]
+    else:
+        names = st._referenced(q.plan(), q.aggregates)
+        fn = st._build(q.plan(), q.aggregates, "pallas")
+        args = []
+    for n in names:
+        args += planes(n)
+    compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # table planes + temporaries within a v5e chip's 16 GB of HBM
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * 2**30
