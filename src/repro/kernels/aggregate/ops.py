@@ -16,6 +16,7 @@ from repro.kernels import dispatch, tune
 from repro.kernels.aggregate import kernel as K
 from repro.kernels.aggregate import ref
 from repro.kernels.scan_filter.kernel import DEFAULT_BLOCK_ROWS, LANES
+from repro.obs import metrics as obs_metrics
 
 
 def sum_bound_block_rows(code_bits: int) -> int:
@@ -26,13 +27,19 @@ def sum_bound_block_rows(code_bits: int) -> int:
     return max(1, (2**31 - 1) // (LANES * cpw * vmax))
 
 
+def _fetch(x) -> int:
+    """One blocking device-to-host read of a scalar, counted."""
+    obs_metrics.count("d2h_fetches")
+    return int(x)
+
+
 def finalize(d: dict) -> dict:
     """Device aggregate dict -> exact host ints, planes reassembled
     (the only step that may exceed int32, hence Python ints)."""
-    return {"sum": (int(d["sum_hi"]) << 16) + int(d["sum_lo"]),
-            "count": int(d["count"]),
-            "min": int(d["min"]),
-            "max": int(d["max"])}
+    return {"sum": (_fetch(d["sum_hi"]) << 16) + _fetch(d["sum_lo"]),
+            "count": _fetch(d["count"]),
+            "min": _fetch(d["min"]),
+            "max": _fetch(d["max"])}
 
 
 def aggregate(words, mask_words, code_bits: int,

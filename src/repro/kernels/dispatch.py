@@ -108,11 +108,12 @@ def registered() -> dict[str, KernelOp]:
 # --------------------------------------------------------------------------
 # launch accounting
 # --------------------------------------------------------------------------
-# Per-family dispatch counters so tests and benchmarks can assert that
-# batched execution really collapses N per-chunk launches into ~1 per
-# (column, encoding) group. A "launch" is one host->device dispatch of a
-# family's public op — Pallas kernel and XLA_REF oracle alike (the cost
-# being measured is the per-call round trip, which both pay).
+# Per-family call counters so tests can assert that batched execution
+# really collapses N per-chunk launches into ~1 per (column, encoding)
+# group. A "launch" is one call of a family's public op — Pallas kernel
+# and XLA_REF oracle alike — made while the calling program is traced:
+# inside jit the counter moves once per trace, not per execution, so a
+# warm served query counts 0 (the device trace counts executions).
 #
 # The counters themselves live in repro.obs.metrics now: increments land
 # in every active MetricsRegistry scope (an engine wrapping execution in

@@ -1,7 +1,8 @@
 """Observability: deterministic query tracing, scoped metrics, analysis.
 
-- `trace`         per-query span trees on the VirtualClock
-                  (Tracer/NullTracer)
+- `trace`         per-query span trees (Tracer/NullTracer): modeled on
+                  the VirtualClock for tiered engines, on the host clock
+                  and the profiler's timeline for flat ones
 - `metrics`       scoped counter/gauge/histogram registry + unified
                   snapshot
 - `audit`         conservation checker: span bytes/joules == ledger lines

@@ -2,7 +2,8 @@
 
 `chrome_trace` emits the Trace Event Format (the JSON Perfetto and
 chrome://tracing load): one process lane per tenant, one thread lane per
-span kind, complete ("X") events in microseconds of *modeled* time.
+span kind, complete ("X") events in microseconds of the trace's time
+(modeled for a tiered engine, the host clock for a flat one).
 `chrome_trace_json` serializes with sorted keys and fixed separators, so
 two runs from the same seed produce byte-identical files — the
 determinism contract tests/test_obs.py pins down.
@@ -15,11 +16,14 @@ from __future__ import annotations
 import json
 import math
 
-# stable thread-lane order: the execution story top to bottom
+# stable thread-lane order: the execution story top to bottom, the
+# modeled kinds of a tiered trace, then the host-clock kinds of a flat one
 _LANES = ("admission", "read", "prefetch_read", "prefetch_cancel",
           "prefetch_stall", "stall", "retry", "failover", "repair",
           "shard_failover", "launch", "launch_batch", "compute",
-          "throttle")
+          "throttle", "query.submit", "query.bind", "query.admission",
+          "query.serve", "query.dispatch", "query.build",
+          "query.finalize")
 
 
 def _lane(kind: str) -> int:

@@ -13,6 +13,11 @@ with explicit `MetricsRegistry` scopes on a dynamic stack:
   own scope sees only its own launches while the global view still adds
   up.
 
+A launch is one call of a kernel family's public op, made while a
+program is traced: a jitted program counts its launches when it is
+traced, and not again each time it runs. `d2h_fetches` counts at run
+time: one per blocking device-to-host read on the served path.
+
 The registry also names the canonical cross-subsystem byte keys:
 `unified_snapshot(engine)` folds the per-subsystem `stats()` dicts
 (placement, prefetch, energy, SLA) into one flat dotted-key namespace and
@@ -168,9 +173,18 @@ def scoped(registry: MetricsRegistry):
 
 
 def count_launch(family: str, n: int = 1) -> None:
-    """Record `n` kernel dispatches for `family` in every active scope."""
+    """Record `n` calls of `family`'s public op in every active scope.
+    Inside a jitted program the op is called while the program is
+    traced, so the count moves once per trace, not per execution."""
     for reg in _STACK:
         reg.count_launch(family, n)
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add `n` to counter `name` in every active scope (``d2h_fetches``:
+    blocking device-to-host reads on the served path)."""
+    for reg in _STACK:
+        reg.counter(name).inc(n)
 
 
 def record_batch(family: str, width: int, n_chunks: int) -> None:

@@ -1,11 +1,19 @@
-"""Per-query span trees on the VirtualClock: deterministic query tracing.
+"""Per-query span trees, on one of two clocks.
 
-Every span is stamped in *modeled* time — the engine's VirtualClock,
-never the wall clock — so a traced run is a pure function of (workload,
-seed): replaying the same chaos trace twice exports byte-identical JSON
-(tests/test_obs.py pins this down).
+A tiered engine stamps every span in *modeled* time — the engine's
+VirtualClock, never the wall clock — so a traced run is a pure function
+of (workload, seed): replaying the same chaos trace twice exports
+byte-identical JSON (tests/test_obs.py pins this down).
 
-Span taxonomy (`Span.kind`):
+A flat engine stamps its spans on its own clock (`time.perf_counter`,
+in seconds) and enters each one as a `jax.profiler.TraceAnnotation` of
+the same name with the query's `qid`, so that a profiler trace holds the
+engine's stages on the same timeline as the device's operations
+(`HostSpan`). The layers below the engine open them through `span()`,
+which records into the query trace `active()` made current, and is one
+shared no-op context when none is.
+
+Modeled span taxonomy (`Span.kind`, tiered engines):
 
 - ``admission``       queue wait, submit -> dispatch
 - ``read``            one chunk's nominal tier read (attrs: cid, hit,
@@ -33,6 +41,23 @@ Span taxonomy (`Span.kind`):
 - ``throttle``        power-cap stretch beyond busy time (race-to-idle:
                       no bytes, no joules)
 
+Host-clock span taxonomy (flat engines; every span carries ``qid``):
+
+- ``query.submit``    QueryEngine.submit
+- ``query.bind``      the plan's bind check, inside ``query.submit``
+- ``query.admission`` queue wait: the end of submit to the start of
+                      serving
+- ``query.serve``     QueryEngine._serve_one: execution and the result
+                      and SLA bookkeeping
+- ``query.dispatch``  plan-cache lookup, argument list and uploads, up
+                      to the return of the enqueued program, inside
+                      ``query.serve``
+- ``query.build``     a plan-cache miss: building and first lowering of
+                      a program, inside ``query.dispatch``
+- ``query.finalize``  the blocking device-to-host reads and the host
+                      arithmetic that turn device results into exact
+                      answers, inside ``query.serve``
+
 Attribution contract: each span carries the `nbytes` and `joules` it
 accounts for and the ledger `kind` those bytes were charged on
 ("query" | "recovery" | "prefetch"); `obs.audit` proves the span sums
@@ -47,7 +72,10 @@ wire points skip span construction entirely when `trace is None`.
 """
 from __future__ import annotations
 
+import contextlib
 import math
+
+import jax
 
 
 class Span:
@@ -93,7 +121,8 @@ class QueryTrace:
 
     def __init__(self, qid: int, *, tenant: int = 0,
                  submitted_at: float = 0.0, deadline: float = math.inf,
-                 bytes_expected: int = 0, shape: str = "scan"):
+                 bytes_expected: int = 0, shape: str = "scan",
+                 clock=None):
         self.qid = qid
         self.tenant = tenant
         self.submitted_at = submitted_at
@@ -111,12 +140,28 @@ class QueryTrace:
         self.met: bool | None = None
         self.degraded = False
         self.error: str | None = None
+        # the host clock a flat engine stamps spans with; None: modeled
+        self.clock = clock
+        self._admission: HostSpan | None = None
 
     # --- emission ---------------------------------------------------------
+    def admit(self) -> None:
+        """Host clock: the query joined the queue; its ``query.admission``
+        span runs until serving begins or the query is shed."""
+        self._admission = HostSpan(self, "query.admission").begin()
+
+    def _end_admission(self) -> None:
+        if self._admission is not None:
+            self._admission.end()
+            self._admission = None
+
     def begin_run(self, t: float) -> None:
         self.t_start = float(t)
-        self.add("admission", t0=self.submitted_at,
-                 dur_s=max(t - self.submitted_at, 0.0))
+        if self.clock is None:
+            self.add("admission", t0=self.submitted_at,
+                     dur_s=max(t - self.submitted_at, 0.0))
+        else:
+            self._end_admission()
 
     def add(self, kind: str, **kw) -> Span:
         sp = Span(kind, **kw)
@@ -146,6 +191,7 @@ class QueryTrace:
 
     def close(self, t: float, *, met: bool, degraded: bool = False,
               error: str | None = None) -> None:
+        self._end_admission()
         self.t_end = float(t)
         self.met = bool(met)
         self.degraded = bool(degraded)
@@ -178,6 +224,10 @@ class _NullQueryTrace:
     enabled = False
     spans: tuple = ()
     reads: tuple = ()
+    clock = None
+
+    def admit(self):
+        pass
 
     def begin_run(self, t):
         pass
@@ -199,7 +249,9 @@ NULL_TRACE = _NullQueryTrace()
 
 
 class Tracer:
-    """Collects one QueryTrace per served query, in service order."""
+    """Collects one QueryTrace per query: a tiered engine's begin when they
+    are served, in service order; a flat engine's when they are
+    submitted, in submit order."""
 
     enabled = True
 
@@ -241,6 +293,67 @@ class NullTracer:
 
     def __len__(self) -> int:
         return 0
+
+
+# --------------------------------------------------------------------------
+# host-clock spans: a flat engine's stages on the profiler's timeline
+# --------------------------------------------------------------------------
+
+class HostSpan:
+    """One host-clock span of a query: a `Span` on its trace, stamped by
+    the trace's clock, inside a `jax.profiler.TraceAnnotation` of the same
+    name and ``qid``. ``with`` opens and closes it; `begin` and `end` may
+    also be called apart (the admission span begins in submit and ends
+    when serving begins)."""
+
+    __slots__ = ("qt", "kind", "t0", "_annotation")
+
+    def __init__(self, qt: QueryTrace, kind: str):
+        self.qt = qt
+        self.kind = kind
+
+    def begin(self) -> "HostSpan":
+        self._annotation = jax.profiler.TraceAnnotation(self.kind,
+                                                        qid=self.qt.qid)
+        self._annotation.__enter__()
+        self.t0 = self.qt.clock()
+        return self
+
+    def end(self) -> None:
+        t1 = self.qt.clock()
+        self._annotation.__exit__(None, None, None)
+        self.qt.add(self.kind, t0=self.t0, dur_s=t1 - self.t0,
+                    qid=self.qt.qid)
+
+    __enter__ = begin
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+_NO_SPAN = contextlib.nullcontext()
+_ACTIVE: QueryTrace | None = None     # the trace span() records into
+
+
+def span(kind: str):
+    """A host-clock span of the active query; the one shared no-op
+    context when no host-clock trace is active, so tracing off costs
+    one global read."""
+    qt = _ACTIVE
+    return _NO_SPAN if qt is None else HostSpan(qt, kind)
+
+
+@contextlib.contextmanager
+def active(qt):
+    """Make `qt` the trace `span()` records into for the block. The null
+    trace and modeled traces (no host clock) leave `span()` a no-op."""
+    global _ACTIVE
+    prev = _ACTIVE
+    _ACTIVE = qt if qt.clock is not None else None
+    try:
+        yield
+    finally:
+        _ACTIVE = prev
 
 
 # --------------------------------------------------------------------------

@@ -20,6 +20,12 @@ The runtime embodiment of the paper's serving story for analytic scans:
   charged per chunk at its tier's rate (the tiered latency model), and
   admission feasibility uses the blended rate. Placement never changes
   answers — execution is identical; only the time/energy accounting moves.
+
+With `tracer=`, a tiered engine records each query's modeled span tree
+(repro.obs.trace); a flat engine records host-clock spans on its own
+clock (query.submit, query.bind, query.admission, query.serve and, from
+the layers below, query.dispatch, query.build, query.finalize), each
+also a profiler annotation on the device trace's timeline.
 """
 from __future__ import annotations
 
@@ -29,7 +35,9 @@ from dataclasses import dataclass
 
 from repro.kernels.dispatch import KernelMode
 from repro.obs import metrics as obs_metrics
-from repro.obs.trace import NullTracer, layout_pipeline, layout_sync
+from repro.obs import trace as obs_trace
+from repro.obs.trace import (NULL_TRACE, NullTracer, layout_pipeline,
+                             layout_sync)
 from repro.query import physical
 from repro.query.plan import HashJoin, Query, is_grouped
 from repro.serve.sla import DeadlineQueue, SLAReport, summarize
@@ -44,6 +52,13 @@ class _Pending:
     chunks: dict | None = None      # tiered mode: per-chunk byte counts
     tenant: int = 0                 # energy-ledger attribution
     logical_bytes: int = 0          # plain-format bytes the query covers
+    trace: object = NULL_TRACE      # flat engine: the host-clock trace
+
+
+def _shape(query) -> str:
+    """The query-shape key trace-diff attribution uses."""
+    return ("join" if isinstance(query, HashJoin)
+            else "grouped" if is_grouped(query) else "scan")
 
 
 @dataclass
@@ -96,14 +111,8 @@ class QueryEngine:
         # (process-global) scope keeps accumulating for the legacy shims
         self.metrics = (metrics if metrics is not None
                         else obs_metrics.MetricsRegistry("engine"))
+        # tiered: spans in modeled time; flat: host-clock spans
         self.tracer = tracer if tracer is not None else NullTracer()
-        if getattr(self.tracer, "enabled", True) and tracer is not None \
-                and tiered is None:
-            # spans are stamped in *modeled* time; a flat engine only has
-            # the wall clock, which would make traces nondeterministic
-            raise ValueError(
-                "tracer= records the modeled tiered timeline; pass "
-                "tiered=repro.tier.PlacementEngine(...) as well")
         if prefetch is not None:
             if tiered is None:
                 # the pipeline overlaps *modeled* tier reads; without the
@@ -251,24 +260,38 @@ class QueryEngine:
         service charge all use the placement engine's chunk accounting
         (device-resident bytes, shard padding included) — one byte basis,
         so an admitted estimate and the charged service can't diverge.
-        `tenant` tags the query's line on the energy meter."""
-        if is_grouped(query):
-            # the relational bind adds the join-key width check on top of
-            # the column checks
-            from repro.query import relational
-            relational.bind_check(query, self.table.columns)
-        else:
-            physical.bind_check(query.plan(), query.aggregates,
-                                self.table.columns)
+        `tenant` tags the query's line on the energy meter.
+
+        Every call takes a query id, a malformed query's too; a flat
+        engine's trace of the query begins here."""
         self._qid += 1
-        chunks = (self.chunk_accesses(query) if self.tiered is not None
-                  else None)
-        nbytes = (sum(chunks.values()) if chunks is not None
-                  else self.bytes_scanned(query))
-        pend = _Pending(self._qid, query, nbytes, self.clock(),
-                        chunks=chunks, tenant=tenant,
-                        logical_bytes=self.logical_bytes(query))
-        if self.queue.push(pend, deadline):
+        qt = (self.tracer.begin_query(self._qid, tenant=tenant,
+                                      deadline=deadline,
+                                      shape=_shape(query), clock=self.clock)
+              if self.tiered is None else NULL_TRACE)
+        with obs_trace.active(qt), obs_trace.span("query.submit"):
+            with obs_trace.span("query.bind"):
+                if is_grouped(query):
+                    # the relational bind adds the join-key width check on
+                    # top of the column checks
+                    from repro.query import relational
+                    relational.bind_check(query, self.table.columns)
+                else:
+                    physical.bind_check(query.plan(), query.aggregates,
+                                        self.table.columns)
+            chunks = (self.chunk_accesses(query) if self.tiered is not None
+                      else None)
+            nbytes = (sum(chunks.values()) if chunks is not None
+                      else self.bytes_scanned(query))
+            pend = _Pending(self._qid, query, nbytes, self.clock(),
+                            chunks=chunks, tenant=tenant,
+                            logical_bytes=self.logical_bytes(query),
+                            trace=qt)
+            if qt.enabled:
+                qt.submitted_at, qt.bytes_expected = pend.submitted_at, nbytes
+            admitted = self.queue.push(pend, deadline)
+        if admitted:
+            qt.admit()
             return pend.qid
         if self.monitor is not None:
             self.monitor.observe_rejected(tenant=tenant)
@@ -298,9 +321,11 @@ class QueryEngine:
             guard = self.chaos.guard if self.chaos is not None else None
             return execute_encoded(query.plan(), query.aggregates,
                                    self.table, mode=self.mode, guard=guard)
-        return physical.finalize_aggs(physical.execute(
-            query.plan(), query.aggregates,
-            physical.table_slices(self.table), mode=self.mode))
+        with obs_trace.span("query.dispatch"):
+            out = physical.execute(query.plan(), query.aggregates,
+                                   physical.table_slices(self.table),
+                                   mode=self.mode)
+        return physical.finalize_aggs(out)
 
     def run(self) -> list[QueryResult]:
         """Drain the queue in deadline order; returns this batch's results.
@@ -312,9 +337,10 @@ class QueryEngine:
         while True:
             n_rej = len(self.queue.rejected)
             got = self.queue.pop()        # sheds now-hopeless queries
-            if self.monitor is not None:
+            for p in self.queue.rejected[n_rej:]:
                 # each shed query broke its promise without being served
-                for p in self.queue.rejected[n_rej:]:
+                p.trace.close(self.clock(), met=False)
+                if self.monitor is not None:
                     self.monitor.observe_rejected(tenant=p.tenant)
             if got is None:
                 break
@@ -344,18 +370,27 @@ class QueryEngine:
 
     def _serve_one(self, pend: _Pending, deadline: float) -> QueryResult:
         t0 = self.clock()
-        shape = ("join" if isinstance(pend.query, HashJoin)
-                 else "grouped" if is_grouped(pend.query) else "scan")
-        qt = self.tracer.begin_query(
-            pend.qid, tenant=pend.tenant, submitted_at=pend.submitted_at,
-            deadline=deadline, bytes_expected=pend.bytes_scanned,
-            shape=shape)
-        trace = qt if qt.enabled else None
-        if trace is not None:
+        if self.tiered is None:
+            qt = pend.trace
+        else:
+            qt = self.tracer.begin_query(
+                pend.qid, tenant=pend.tenant,
+                submitted_at=pend.submitted_at, deadline=deadline,
+                bytes_expected=pend.bytes_scanned, shape=_shape(pend.query))
+        if qt.enabled:
             qt.begin_run(t0)
+        with obs_trace.active(qt), obs_trace.span("query.serve"):
+            return self._serve(pend, deadline, t0, qt)
+
+    def _serve(self, pend: _Pending, deadline: float, t0: float,
+               qt) -> QueryResult:
+        trace = qt if qt.enabled else None
+        # launch spans are modeled only: the counters move while programs
+        # are traced, so on the host clock they would read 0 once warm
+        modeled = trace is not None and qt.clock is None
         launches0 = ({k: c.value
                       for k, c in self.metrics.counters.items()}
-                     if trace is not None else None)
+                     if modeled else None)
         error = None
         tier_info = None
         if self.tiered is not None:
@@ -427,8 +462,9 @@ class QueryEngine:
             # t1 - t0 covers the full scan
             t1 = self.clock()
             self.seconds_total += max(t1 - t0, 1e-12)
-        if trace is not None:
+        if modeled:
             self._emit_launches(qt, launches0, t0)
+        if trace is not None:
             qt.close(t1, met=t1 <= deadline and error is None,
                      degraded=error is not None, error=error)
         self.bytes_total += pend.bytes_scanned

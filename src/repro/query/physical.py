@@ -34,6 +34,7 @@ from repro.kernels.aggregate import ops as agg_ops
 from repro.kernels.scan_aggregate import ops as fused_ops
 from repro.kernels.scan_filter import ops as scan_ops
 from repro.kernels.scan_filter.ref import codes_per_word, unpack_mask
+from repro.obs import trace as obs_trace
 from repro.query.plan import And, Or, Plan, Pred, columns_of
 
 
@@ -144,7 +145,8 @@ def finalize_aggs(out: dict) -> dict:
     """{column: device aggregate dict} -> {column: exact host-int dict}
     with the 16-bit sum planes reassembled (the only step allowed to
     exceed int32, hence Python ints)."""
-    return {col: agg_ops.finalize(d) for col, d in out.items()}
+    with obs_trace.span("query.finalize"):
+        return {col: agg_ops.finalize(d) for col, d in out.items()}
 
 
 def referenced_bytes(plan: Plan, aggregates, columns: dict) -> int:

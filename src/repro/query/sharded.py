@@ -11,6 +11,12 @@ mixed code widths. Validity masks cancel all padding rows.
 The paper's provisioning model maps directly: chips = shards, and per-shard
 scan throughput is what `core_perf` claims each chip sustains — the query
 engine compares the two.
+
+A served query's host stages are spans of the active query trace
+(`obs.trace.span`): `query.dispatch` from the plan-cache lookup to the
+return of the enqueued program (`query.build` inside it on a miss), then
+`query.finalize` for the blocking reads and host merge; each blocking
+device-to-host read counts one `d2h_fetches`.
 """
 from __future__ import annotations
 
@@ -23,6 +29,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.scan_filter import ref as packref
+from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.query import physical
 from repro.query.physical import ColumnSlice
 from repro.query.plan import columns_of
@@ -41,6 +49,16 @@ def _merge_planes(a: dict, b: dict) -> dict:
         lo = p[:, 0] + q[:, 0]
         out[name] = jnp.stack([lo & 0xFFFF, p[:, 1] + q[:, 1] + (lo >> 16),
                                p[:, 2] + q[:, 2]], axis=1)
+    return out
+
+
+def _fetch_planes(stacked: dict) -> dict:
+    """Device plane stacks -> host numpy, each one counted blocking
+    read."""
+    out = {}
+    for name, v in stacked.items():
+        obs_metrics.count("d2h_fetches")
+        out[name] = np.asarray(v)
     return out
 
 
@@ -117,6 +135,22 @@ class ShardedTable:
     def _referenced(self, plan, aggregates: tuple) -> tuple:
         return tuple(sorted(columns_of(plan) | set(aggregates)))
 
+    def _args(self, names) -> list:
+        """The (words, valid) device arrays of `names`, flat, in order."""
+        return [a for n in names
+                for a in (self.slices[n].words, self.slices[n].valid)]
+
+    def _call(self, key, build, *args):
+        """The program cached under `key`, called on `args` (enqueued, not
+        awaited); a miss builds it, and building it and its first call's
+        lowering are the `query.build` span."""
+        fn = self._jitted.get(key)
+        if fn is not None:
+            return fn(*args)
+        with obs_trace.span("query.build"):
+            fn = self._jitted[key] = build()
+            return fn(*args)
+
     def execute(self, plan, aggregates, mode=None) -> dict:
         """Per-shard scan+aggregate with a psum combine; returns
         {agg_column: {sum, count, min, max}} as exact host ints.
@@ -126,13 +160,11 @@ class ShardedTable:
         """
         aggregates = tuple(aggregates)
         key = (plan, aggregates, None if mode is None else str(mode))
-        fn = self._jitted.get(key)
-        if fn is None:
-            fn = self._jitted[key] = self._build(plan, aggregates, mode)
-        args = []
-        for n in self._referenced(plan, aggregates):
-            args += [self.slices[n].words, self.slices[n].valid]
-        return physical.finalize_aggs(fn(*args))
+        with obs_trace.span("query.dispatch"):
+            out = self._call(
+                key, lambda: self._build(plan, aggregates, mode),
+                *self._args(self._referenced(plan, aggregates)))
+        return physical.finalize_aggs(out)
 
     def execute_partials(self, plan, aggregates, mode=None) -> list[dict]:
         """Per-shard finalized aggregates in shard order (exact host ints).
@@ -146,14 +178,10 @@ class ShardedTable:
         aggregates = tuple(aggregates)
         key = (plan, aggregates, None if mode is None else str(mode),
                "partials")
-        fn = self._jitted.get(key)
-        if fn is None:
-            fn = self._jitted[key] = self._build_partials(plan, aggregates,
-                                                          mode)
-        args = []
-        for n in self._referenced(plan, aggregates):
-            args += [self.slices[n].words, self.slices[n].valid]
-        stacked = fn(*args)     # {col: {field: (n_shards,) device arrays}}
+        # {col: {field: (n_shards,) device arrays}}
+        stacked = self._call(
+            key, lambda: self._build_partials(plan, aggregates, mode),
+            *self._args(self._referenced(plan, aggregates)))
         return [physical.finalize_aggs(
                     {col: {k: v[i] for k, v in d.items()}
                      for col, d in stacked.items()})
@@ -248,18 +276,21 @@ class ShardedTable:
         the shard planes host-side equals an unsharded execution bit for
         bit: the planes are normalized per shard and the partial algebra
         is associative in exact ints."""
+        return _fetch_planes(self._dispatch_grouped(plan, key, aggs,
+                                                    domain, mode))
+
+    def _dispatch_grouped(self, plan, key: str, aggs, domain, mode):
+        """Enqueue the grouped program: {name: (n_shards, n_groups, 3)}
+        device stacks, not yet awaited."""
         aggs = tuple(aggs)
         cache_key = (plan, key, aggs,
                      None if mode is None else str(mode), "grouped")
-        fn = self._jitted.get(cache_key)
-        if fn is None:
-            fn = self._jitted[cache_key] = self._build_grouped(
-                plan, key, aggs, mode)
-        args = []
-        for n in self._referenced(plan, aggs + (key,)):
-            args += [self.slices[n].words, self.slices[n].valid]
-        stacked = fn(jnp.asarray(np.asarray(domain), jnp.int32), *args)
-        return {name: np.asarray(v) for name, v in stacked.items()}
+        with obs_trace.span("query.dispatch"):
+            return self._call(
+                cache_key,
+                lambda: self._build_grouped(plan, key, aggs, mode),
+                jnp.asarray(np.asarray(domain), jnp.int32),
+                *self._args(self._referenced(plan, aggs + (key,))))
 
     def _build_grouped(self, plan, key: str, aggs: tuple, mode):
         from repro.kernels.group_aggregate import ops as gops
@@ -330,13 +361,14 @@ class ShardedTable:
             dispatch.count_launch("group_aggregate_fallback",
                                   self.n_shards)
             return relational.execute_grouped_oracle(query, self.table)
-        planes = self.execute_grouped_planes(
-            query.plan(), query.key, query.aggs, domain, mode=mode)
+        stacked = self._dispatch_grouped(query.plan(), query.key,
+                                         query.aggs, domain, mode)
         first = query.aggs[0] if query.aggs else ""
-        part = relational.new_partial()
-        for name, stack in planes.items():
-            for i in range(stack.shape[0]):
-                relational.absorb_plane(part, domain, stack[i],
-                                        name or None,
-                                        count_source=(name == first))
-        return relational.finalize(part)
+        with obs_trace.span("query.finalize"):
+            part = relational.new_partial()
+            for name, stack in _fetch_planes(stacked).items():
+                for i in range(stack.shape[0]):
+                    relational.absorb_plane(part, domain, stack[i],
+                                            name or None,
+                                            count_source=(name == first))
+            return relational.finalize(part)

@@ -399,6 +399,32 @@ def check_relational():
     print("OK relational")
 
 
+def check_d2h_fetches():
+    """Blocking device-to-host reads per served query on a table sharded
+    over 4 devices: a Q6-shaped query reads five scalars for each of its
+    two aggregate columns, a Q1-shaped grouped query one plane stack per
+    value column."""
+    from repro.db import Table
+    from repro.query import GroupBy, Pred, Query, QueryEngine, ShardedTable
+
+    table = Table.synthetic("t", 20_000, {"d": 16, "q": 8, "x": 8, "k": 8},
+                            seed=3)
+    st = ShardedTable.shard(table, make_mesh((4,), ("data",)))
+    eng = QueryEngine(st, mode="xla_ref")
+    q6 = Query(Pred("d", "ge", 100) & Pred("d", "lt", 9000)
+               & Pred("x", "ge", 5) & Pred("x", "le", 70)
+               & Pred("q", "lt", 24), aggregates=("x", "q"))
+    q1 = GroupBy("k", ("q", "x", "d"), where=Pred("d", "le", 20000))
+    for query, want in ((q6, 10), (q1, 3)):
+        for _ in range(2):       # cold (program built) and warm alike
+            before = eng.metrics.counter("d2h_fetches").value
+            eng.submit(query)
+            eng.run()
+            got = eng.metrics.counter("d2h_fetches").value - before
+            assert got == want, (query, got, want)
+    print("OK d2h_fetches")
+
+
 def check_serve_step_sharded():
     from repro.configs import get_config
     from repro.configs.base import ShapeSpec
@@ -426,6 +452,7 @@ if __name__ == "__main__":
         "store": check_compressed_store,
         "resilience": check_resilience,
         "relational": check_relational,
+        "d2h": check_d2h_fetches,
     }
     if which == "all":
         for fn in checks.values():

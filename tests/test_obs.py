@@ -11,7 +11,9 @@ Pins down PR 9's contracts:
 - the unified snapshot's canonical byte keys agree with both
   PlacementEngine totals and PrefetchPipeline.stats() (the
   overlapping-key normalization regression test);
-- the bench regression gate trips on a >30% drop and passes otherwise.
+- the bench regression gate trips on a >30% drop and passes otherwise;
+- a flat engine's host-clock spans nest, share one qid, and land in the
+  profiler's trace; with tracing off no annotation is opened.
 """
 import json
 import os
@@ -268,9 +270,119 @@ def test_engine_default_has_no_tracing_overhead():
     run_queries(eng2, n=1)   # runs clean with tracing off
 
 
-def test_tracer_requires_tiered():
-    with pytest.raises(ValueError, match="tiered"):
-        QueryEngine(make_table(), mode="xla_ref", tracer=Tracer())
+# --------------------------------------------------------------------------
+# host-clock spans of a flat engine
+# --------------------------------------------------------------------------
+
+Q6_SHAPED = Query(Pred("c00", "ge", 10) & Pred("c01", "lt", 90)
+                  & Pred("c02", "le", 100), aggregates=("c03", "c04"))
+
+
+def flat_traced_run(n=2):
+    st = ShardedTable.shard(make_table(), make_mesh((1,), ("data",)))
+    tracer = Tracer()
+    eng = QueryEngine(st, mode="xla_ref", tracer=tracer)
+    for _ in range(n):
+        assert eng.submit(Q6_SHAPED) is not None
+        eng.run()
+    return tracer
+
+
+def _one(qt, kind):
+    (sp,) = [sp for sp in qt.spans if sp.kind == kind]
+    return sp
+
+
+def _within(inner, outer):
+    return outer.t0 <= inner.t0 and inner.t1 <= outer.t1
+
+
+def test_flat_tracer_records_host_clock_spans():
+    tracer = flat_traced_run()
+    assert len(tracer) == 2
+    for i, qt in enumerate(tracer.queries):
+        assert {sp.attrs["qid"] for sp in qt.spans} == {qt.qid}
+        kinds = set(qt.span_kinds())
+        assert not kinds & {"launch", "launch_batch", "admission"}
+        sub, bind = _one(qt, "query.submit"), _one(qt, "query.bind")
+        serve, adm = _one(qt, "query.serve"), _one(qt, "query.admission")
+        disp, fin = _one(qt, "query.dispatch"), _one(qt, "query.finalize")
+        assert _within(bind, sub)
+        assert sub.t1 <= adm.t0 and adm.t1 <= serve.t0
+        assert _within(disp, serve) and _within(fin, serve)
+        assert disp.t1 <= fin.t0
+        assert qt.t_start <= serve.t0 and serve.t1 >= qt.t_end
+        assert all(sp.dur_s > 0 for sp in qt.spans)
+        if i == 0:     # the first query builds its program
+            assert _within(_one(qt, "query.build"), disp)
+        else:
+            assert "query.build" not in kinds
+    # every host-clock kind has a lane of its own in the Chrome export
+    lanes = {e["cat"]: e["tid"] for e in chrome_trace(tracer)["traceEvents"]
+             if e["ph"] == "X" and e["tid"]}
+    assert len({lanes[k] for k in lanes if k.startswith("query.")}) == 7
+
+
+def test_null_tracer_opens_no_annotation(monkeypatch):
+    import jax
+    from repro.obs import trace as obs_trace
+    opened = []
+    real = jax.profiler.TraceAnnotation
+
+    def counting(name, **kw):
+        opened.append(name)
+        return real(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counting)
+    st = ShardedTable.shard(make_table(), make_mesh((1,), ("data",)))
+    eng = QueryEngine(st, mode="xla_ref")
+    eng.submit(Q6_SHAPED)
+    eng.run()
+    assert opened == []
+    assert obs_trace.span("query.dispatch") is obs_trace.span("query.serve")
+    with obs_trace.active(NULL_TRACE):
+        assert obs_trace.span("query.dispatch") is obs_trace._NO_SPAN
+    flat_traced_run(n=1)         # the same path with a tracer opens them
+    assert "query.dispatch" in opened
+
+
+def test_host_spans_land_in_the_profiler_trace(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    flat_traced_run(n=1)         # JAX's own compile stays out of the window
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("outer"):
+            tracer = flat_traced_run(n=1)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    events = [e for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name == "outer" or e.name.startswith("query.")]
+    (outer,) = [e for e in events if e.name == "outer"]
+    spans = [e for e in events if e.name != "outer"]
+    qid = tracer.queries[0].qid
+    assert {e.name for e in spans} == {
+        "query.submit", "query.bind", "query.admission", "query.serve",
+        "query.dispatch", "query.build", "query.finalize"}
+    for e in spans:
+        assert dict(e.stats)["qid"] == qid
+        assert outer.start_ns <= e.start_ns
+        assert e.start_ns + e.duration_ns <= outer.start_ns + \
+            outer.duration_ns
+
+
+def test_tiered_tracer_records_no_host_spans():
+    eng, pe, tracer = tiered_engine(make_table())
+    run_queries(eng, n=2)
+    for qt in tracer.queries:
+        assert not any(k.startswith("query.") for k in qt.span_kinds())
+        assert qt.span_kinds()["admission"] == 1
 
 
 # --------------------------------------------------------------------------
