@@ -32,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.scan_filter.kernel import DEFAULT_BLOCK_ROWS, LANES
+from repro.obs import metrics as obs_metrics
 
 # one chunk's output tile: the smallest int32 block the (8, 128) rule allows
 OUT_TILE = (8, LANES)
@@ -115,6 +116,7 @@ def pad_rows(planes, block_rows: int):
     block_rows = min(block_rows, rows)
     pad = (-rows) % block_rows
     if pad:
+        obs_metrics.count("tile_pads")
         planes = [jnp.pad(p, ((0, 0), (0, pad), (0, 0))) for p in planes]
     return planes, rows + pad, block_rows
 

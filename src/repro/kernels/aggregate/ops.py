@@ -58,8 +58,10 @@ def aggregate(words, mask_words, code_bits: int,
     w = jnp.asarray(words, jnp.uint32)
     m = jnp.asarray(mask_words, jnp.uint32)
     pad = (-w.shape[0]) % LANES
-    w = jnp.pad(w, (0, pad)).reshape(-1, LANES)
-    m = jnp.pad(m, (0, pad)).reshape(-1, LANES)
+    if pad:
+        obs_metrics.count("tile_pads")
+        w, m = jnp.pad(w, (0, pad)), jnp.pad(m, (0, pad))
+    w, m = w.reshape(-1, LANES), m.reshape(-1, LANES)
     rows = w.shape[0]
     br = block_rows
     if br is None:

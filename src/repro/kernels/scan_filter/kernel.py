@@ -21,9 +21,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.scan_filter.ref import field_masks
+from repro.obs import metrics as obs_metrics
 
 LANES = 128
 DEFAULT_BLOCK_ROWS = 256
+# words in one default (block_rows, 128) tile: a plane of a multiple of
+# this many words reaches the kernels with nothing to pad or slice
+TILE_WORDS = LANES * DEFAULT_BLOCK_ROWS
 
 
 def _scan_kernel(x_ref, o_ref, *, op: str, const_packed, delim, low):
@@ -54,6 +58,7 @@ def scan_packed(words2d, constant: int, *, op: str, code_bits: int,
     block_rows = min(block_rows, rows)
     pad = (-rows) % block_rows
     if pad:
+        obs_metrics.count("tile_pads")
         words2d = jnp.pad(words2d, ((0, pad), (0, 0)))
     grid_rows = rows + pad
     delim, low, value = field_masks(code_bits)

@@ -11,13 +11,16 @@ from repro.kernels import dispatch, tune
 from repro.kernels.scan_filter import kernel as K
 from repro.kernels.scan_filter import ref
 from repro.kernels.scan_filter.ref import OPS, field_masks
+from repro.obs import metrics as obs_metrics
 
 
 def _to_2d(words):
     n = words.shape[0]
     pad = (-n) % K.LANES
-    w = jnp.pad(words, (0, pad))
-    return w.reshape(-1, K.LANES), n
+    if pad:
+        obs_metrics.count("tile_pads")
+        words = jnp.pad(words, (0, pad))
+    return words.reshape(-1, K.LANES), n
 
 
 def _block_rows(rows: int, code_bits: int, tuned: bool) -> int:

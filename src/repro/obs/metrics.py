@@ -182,7 +182,8 @@ def count_launch(family: str, n: int = 1) -> None:
 
 def count(name: str, n: int = 1) -> None:
     """Add `n` to counter `name` in every active scope (``d2h_fetches``:
-    blocking device-to-host reads on the served path)."""
+    blocking device-to-host reads on the served path; ``tile_pads``: zero
+    pads a kernel wrapper emits, counted per trace as launches are)."""
     for reg in _STACK:
         reg.counter(name).inc(n)
 

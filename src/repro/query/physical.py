@@ -171,7 +171,7 @@ def align_chunk_rows(columns: dict, chunk_rows: int) -> int:
     """Round `chunk_rows` up so a row-range boundary is a word boundary
     for every column (multiple of each width's codes-per-word). The one
     alignment invariant shared by tier chunking and shard splitting
-    (ShardedTable.shard sizes rows_per_shard through this)."""
+    (sharded.shard_rows starts from the same word boundary)."""
     if chunk_rows < 1:
         raise ValueError(f"chunk_rows={chunk_rows} must be >= 1")
     align = math.lcm(*(32 // c.code_bits for c in columns.values()))

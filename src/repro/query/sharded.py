@@ -6,7 +6,9 @@ aggregate scalars per shard cross the interconnect (psum/pmin/pmax inside a
 shard_map). Rows are padded so one shard boundary works for every column:
 rows_per_shard is a multiple of every column's codes-per-word (lcm), hence
 each column's word array splits evenly on the same row boundaries despite
-mixed code widths. Validity masks cancel all padding rows.
+mixed code widths; a shard of at least one kernel tile is further a whole
+number of tiles of every column (`shard_rows`), so the kernel wrappers
+neither pad nor slice its planes. Validity masks cancel all padding rows.
 
 The paper's provisioning model maps directly: chips = shards, and per-shard
 scan throughput is what `core_perf` claims each chip sustains — the query
@@ -20,6 +22,7 @@ device-to-host read counts one `d2h_fetches`.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -29,6 +32,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.scan_filter import ref as packref
+from repro.kernels.scan_filter.kernel import TILE_WORDS
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.query import physical
@@ -38,6 +42,41 @@ from repro.query.plan import columns_of
 
 # rows a shard unpacks per grouped-kernel launch (int32 planes of 64 MiB)
 GROUP_SLAB_ROWS = 1 << 24
+
+
+def shard_rows(code_bits, num_rows: int, n_shards: int) -> int:
+    """Rows each of `n_shards` shards holds of a `num_rows`-row table whose
+    columns have the widths `code_bits`: the ceiling share, rounded up to a
+    word boundary of every width. A shard that already holds a whole
+    TILE_WORDS tile of every column is rounded up further, to a whole
+    number of them, at a cost of under one tile of rows: each plane then
+    reshapes to (rows, LANES) in whole kernel blocks, and the wrappers
+    emit no pad and no slice. Smaller shards stay word-aligned only."""
+    cpw = math.lcm(*(32 // b for b in code_bits))
+    rows = -(-max(1, -(-num_rows // n_shards)) // cpw) * cpw
+    tile = TILE_WORDS * cpw
+    return rows if rows < tile else -(-rows // tile) * tile
+
+
+_zero_extend = jax.jit(lambda a, n: jnp.pad(a, (0, n - a.shape[0])),
+                       static_argnums=1)
+
+
+def _put_zero_extended(words, n_words: int, sharding):
+    """Host `words` zero-extended to `n_words`, placed with `sharding`.
+    Each device receives only its own rows, straight from the host array,
+    and a shard that runs past the data gets its zero tail on its device:
+    no host copy of a column is made, and nothing else stays resident."""
+    parts = []
+    for dev, idx in sharding.addressable_devices_indices_map(
+            (n_words,)).items():
+        lo, hi, _ = idx[0].indices(n_words)
+        part = jax.device_put(words[lo:hi], dev)
+        if part.shape[0] < hi - lo:
+            part = _zero_extend(part, hi - lo)
+        parts.append(part)
+    return jax.make_array_from_single_device_arrays((n_words,), sharding,
+                                                    parts)
 
 
 def _merge_planes(a: dict, b: dict) -> dict:
@@ -98,24 +137,19 @@ class ShardedTable:
             raise ValueError(f"mesh has no axis {axis!r}; axes are "
                              f"{tuple(mesh.shape)}")
         n = int(mesh.shape[axis])
-        rps = physical.align_chunk_rows(table.columns,
-                                        max(1, -(-table.num_rows // n)))
+        rps = shard_rows([c.code_bits for c in table.columns.values()],
+                         table.num_rows, n)
         total_rows = rps * n
         sharding = NamedSharding(mesh, P(axis))
         slices = {}
         for name, col in table.columns.items():
-            # host numpy straight into the sharded layout: each device
-            # receives only its own rows, and nothing else stays resident
             n_words = total_rows * col.code_bits // 32
-            w = np.asarray(col.words, np.uint32)
-            if w.size != n_words:
-                w = np.zeros(n_words, np.uint32)
-                w[:col.words.size] = col.words
             valid = packref.valid_mask(n_words, table.num_rows,
                                        col.code_bits)
-            slices[name] = ColumnSlice(jax.device_put(w, sharding),
-                                       jax.device_put(valid, sharding),
-                                       col.code_bits)
+            slices[name] = ColumnSlice(
+                _put_zero_extended(np.asarray(col.words, np.uint32),
+                                   n_words, sharding),
+                jax.device_put(valid, sharding), col.code_bits)
         return cls(table, mesh, axis, rps, slices)
 
     # --- tier accounting --------------------------------------------------

@@ -425,6 +425,47 @@ def check_d2h_fetches():
     print("OK d2h_fetches")
 
 
+def check_whole_tile_shards():
+    """Mixed-width tables just past one kernel tile per shard, on 1 and 4
+    devices: each shard is placed at whole tiles of every column, the
+    wrappers pad nothing, and the Pallas answers equal numpy's."""
+    from repro.db import Table
+    from repro.kernels.scan_filter.kernel import TILE_WORDS
+    from repro.obs.metrics import MetricsRegistry, scoped
+    from repro.query import Pred, Query, ShardedTable
+
+    spec = {"a": 8, "b": 8, "w": 16}
+    tile_rows = TILE_WORDS * 4                 # one tile of the 8-bit codes
+    queries = [
+        (Query(Pred("a", "lt", 64), aggregates=("b",)),          # fused
+         lambda c: c["a"] < 64),
+        (Query(Pred("a", "lt", 50) & Pred("w", "ge", 9000)       # mixed AND
+               & Pred("b", "le", 100), aggregates=("w", "b")),
+         lambda c: (c["a"] < 50) & (c["w"] >= 9000) & (c["b"] <= 100)),
+        (Query(Pred("b", "eq", 3) | Pred("w", "lt", 500),        # mixed OR
+               aggregates=("a",)),
+         lambda c: (c["b"] == 3) | (c["w"] < 500)),
+    ]
+    for n in (1, 4):
+        table = Table.synthetic("t", n * tile_rows + 777, spec, seed=13)
+        cols = {c: table.columns[c].decode().astype(np.int64)
+                for c in spec}
+        st = ShardedTable.shard(table, make_mesh((n,), ("data",)))
+        assert st.rows_per_shard == 2 * tile_rows, st.rows_per_shard
+        reg = MetricsRegistry("tiles")
+        for q, mksel in queries:
+            sel = mksel(cols)
+            with scoped(reg):
+                got = st.execute(q.plan(), q.aggregates, mode="pallas")
+            for a in q.aggregates:
+                v = cols[a][sel]
+                want = {"sum": int(v.sum()), "count": int(sel.sum()),
+                        "min": int(v.min()), "max": int(v.max())}
+                assert got[a] == want, (n, q, a, got[a], want)
+        assert reg.counter("tile_pads").value == 0
+    print("OK whole_tile_shards")
+
+
 def check_serve_step_sharded():
     from repro.configs import get_config
     from repro.configs.base import ShapeSpec
@@ -453,6 +494,7 @@ if __name__ == "__main__":
         "resilience": check_resilience,
         "relational": check_relational,
         "d2h": check_d2h_fetches,
+        "tiles": check_whole_tile_shards,
     }
     if which == "all":
         for fn in checks.values():

@@ -26,7 +26,7 @@ def run_child(which: str):
 @pytest.mark.parametrize("which", ["pipeline", "pipeline2d", "compression",
                                    "ef", "train", "serve", "elastic",
                                    "query", "store", "resilience",
-                                   "relational", "d2h"])
+                                   "relational", "d2h", "tiles"])
 def test_multidevice(which):
     out = run_child(which)
     assert "OK" in out

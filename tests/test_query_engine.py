@@ -11,9 +11,12 @@ import numpy as np
 import pytest
 
 from repro.db import Table
+from repro.kernels.scan_filter.kernel import TILE_WORDS
 from repro.launch.mesh import make_mesh
+from repro.obs.metrics import MetricsRegistry, scoped
 from repro.query import And, Or, Pred, Query, QueryEngine, ShardedTable
 from repro.query.plan import normalize
+from repro.query.sharded import shard_rows
 
 MODES = ("pallas", "xla_ref", "auto")
 
@@ -134,6 +137,77 @@ def test_engine_sum_exact_beyond_int32():
         res = eng.run()[0]
         assert res.aggregates["p"]["sum"] == want
         assert res.count == 300_000
+
+
+# --- shard placement at whole kernel tiles --------------------------------
+
+TILE_SCHEMAS = {"16": (16, 16, 16), "8_16": (8, 16, 16, 8),
+                "2_4": (2, 4, 4), "2_8_16": (2, 8, 16)}
+
+
+def _word_aligned(widths, rows: int, n: int) -> int:
+    """rows_per_shard at word alignment alone: the ceiling share rounded
+    up to every width's codes-per-word."""
+    align = math.lcm(*(32 // b for b in widths))
+    return -(-max(1, -(-rows // n)) // align) * align
+
+
+def _tile_rows(widths) -> int:
+    """Rows that fill one TILE_WORDS tile of every column."""
+    return TILE_WORDS * max(32 // b for b in widths)
+
+
+@pytest.mark.parametrize("n", (1, 4))
+@pytest.mark.parametrize("schema", sorted(TILE_SCHEMAS))
+def test_shard_rows_under_one_tile_keeps_word_alignment(schema, n):
+    widths = TILE_SCHEMAS[schema]
+    tile = _tile_rows(widths)
+    for rows in (0, 1, 10_001, n * (tile - 32) - 3):
+        assert shard_rows(widths, rows, n) == _word_aligned(widths, rows,
+                                                            n), rows
+
+
+@pytest.mark.parametrize("n", (1, 4))
+@pytest.mark.parametrize("schema", sorted(TILE_SCHEMAS))
+def test_shard_rows_whole_tiles_from_one_tile(schema, n):
+    """At and past one tile a shard is a whole number of tiles of every
+    column, and costs under one tile of rows more than word alignment."""
+    widths = TILE_SCHEMAS[schema]
+    tile = _tile_rows(widths)
+    for rows in (n * tile, n * tile + 1, 3 * n * tile - 5, 600_037_902,
+                 1_799_989_091):
+        rps = shard_rows(widths, rows, n)
+        assert rps * n >= rows
+        assert 0 <= rps - _word_aligned(widths, rows, n) < tile, rows
+        for b in widths:
+            assert rps * b % 32 == 0
+            assert (rps * b // 32) % TILE_WORDS == 0, (rows, b)
+
+
+@pytest.mark.parametrize("rows,pads", ((10_001, True), (65_537, False)),
+                         ids=("under_one_tile", "whole_tiles"))
+def test_tile_pads_counts_q6_wrapper_pads(rows, pads):
+    """A Q6-shaped program over a word-aligned shard pads planes to the
+    kernel tiling; over a shard placed at whole tiles it pads nothing.
+    Either way the answers are the numpy oracle's."""
+    spec = {"d": 16, "x": 16, "q": 16}
+    t = Table.synthetic("q6", rows, spec, seed=7)
+    st = ShardedTable.shard(t, make_mesh((1,), ("data",)))
+    assert (st.rows_per_shard % _tile_rows(spec.values()) != 0) == pads
+    q = Query(Pred("d", "ge", 100) & Pred("d", "lt", 9000)
+              & Pred("x", "ge", 5) & Pred("x", "le", 7000)
+              & Pred("q", "lt", 24000), aggregates=("x", "q"))
+    cols = {c: t.columns[c].decode().astype(np.int64) for c in spec}
+    sel = ((cols["d"] >= 100) & (cols["d"] < 9000) & (cols["x"] >= 5)
+           & (cols["x"] <= 7000) & (cols["q"] < 24000))
+    reg = MetricsRegistry("tile_pads")
+    with scoped(reg):
+        got = st.execute(q.plan(), q.aggregates, mode="pallas")
+    assert (reg.counter("tile_pads").value > 0) == pads
+    for a in q.aggregates:
+        v = cols[a][sel]
+        assert got[a] == {"sum": int(v.sum()), "count": int(sel.sum()),
+                          "min": int(v.min()), "max": int(v.max())}
 
 
 class TestPlanLayer:

@@ -11,6 +11,9 @@ file loads the TPU compiler.
 """
 from __future__ import annotations
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,9 +27,10 @@ from repro.kernels.group_aggregate import kernel as group_k
 from repro.kernels.scan_aggregate import kernel as fused_k
 from repro.kernels.scan_compressed import kernel as rle_k
 from repro.kernels.scan_filter import kernel as scan_k
+from repro.obs.metrics import MetricsRegistry, scoped
 from repro.query import And, GroupBy, Pred, Query
 from repro.query.physical import ColumnSlice
-from repro.query.sharded import ShardedTable
+from repro.query.sharded import ShardedTable, shard_rows
 
 ROWS = 1 << 16            # (ROWS, 128) words per plane: 32 MiB
 CHUNKS = 8
@@ -168,3 +172,46 @@ def test_served_program_compiles_for_v5e(shape, topo, no_persistent_cache,
     mem = compiled.memory_analysis()
     # table planes + temporaries within a v5e chip's 16 GB of HBM
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * 2**30
+
+
+# TPC-H Q6 at its validation parameters over its three columns, one 16-bit
+# code width (the benchmark's q6_power traffic)
+Q6 = Query(And.of(Pred("l_shipdate", "ge", 731),
+                  Pred("l_shipdate", "lt", 1096),
+                  Pred("l_discount", "ge", 5), Pred("l_discount", "le", 7),
+                  Pred("l_quantity", "lt", 24)),
+           ("l_discount", "l_quantity"))
+Q6_SCHEMA = {"l_shipdate": 16, "l_quantity": 16, "l_discount": 16}
+
+
+@pytest.mark.parametrize("chips,rows,temp_bytes",
+                         ((1, 600_037_902, 6.1e9),
+                          (4, 1_799_989_091, 4.6e9)),
+                         ids=("sf100_1chip", "sf300_4chip"))
+def test_q6_program_over_whole_tiles_pads_nothing(chips, rows, temp_bytes,
+                                                  topo, no_persistent_cache,
+                                                  monkeypatch):
+    """Q6's served program over a shard placed at whole kernel tiles
+    (SF 100 on one chip, SF 300 on four): no pad, no plane-sized slice,
+    no `tile_pads`, and the mask temporaries shrink to the masks."""
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    rps = shard_rows(Q6_SCHEMA.values(), rows, chips)
+    mesh = Mesh(np.asarray(topo.devices[:chips]), ("data",))
+    st = ShardedTable(table=None, mesh=mesh, axis="data",
+                      rows_per_shard=rps,
+                      slices={n: ColumnSlice(None, None, b)
+                              for n, b in Q6_SCHEMA.items()})
+    plane = jax.ShapeDtypeStruct((rps * chips * 16 // 32,), jnp.uint32,
+                                 sharding=NamedSharding(mesh, P("data")))
+    n_planes = 2 * len(st._referenced(Q6.plan(), Q6.aggregates))
+    reg = MetricsRegistry("q6")
+    with scoped(reg):
+        compiled = st._build(Q6.plan(), Q6.aggregates, "pallas").lower(
+            *[plane] * n_planes).compile()
+    hlo = compiled.as_text()
+    assert " pad(" not in hlo
+    sliced = [math.prod(int(d) for d in dims.split(",") if d)
+              for dims in re.findall(r"\[([\d,]*)\]\S* slice\(", hlo)]
+    assert sliced and max(sliced) < scan_k.TILE_WORDS, sliced
+    assert reg.counter("tile_pads").value == 0
+    assert compiled.memory_analysis().temp_size_in_bytes <= temp_bytes
