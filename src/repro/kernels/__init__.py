@@ -2,6 +2,8 @@
 
 - scan_filter:       BitWeaving-H predicate scan (the paper's workload)
 - aggregate:         fused masked aggregate (scan+aggregate query)
+- mask_repack:       predicate mask from one code width's layout to
+                     another's, bits moved across lanes on the MXU
 - flash_attention:   blockwise online-softmax attention w/ causal skip
 - decode_attention:  split-K one-token decode over the ring KV cache
                      (kernel-native (B, KVH, S, D) layout — the models'

@@ -27,19 +27,20 @@ def sum_bound_block_rows(code_bits: int) -> int:
     return max(1, (2**31 - 1) // (LANES * cpw * vmax))
 
 
-def _fetch(x) -> int:
-    """One blocking device-to-host read of a scalar, counted."""
+def fetch(tree):
+    """One blocking device-to-host read of a pytree of device arrays,
+    counted once: every copy starts before the first is awaited."""
     obs_metrics.count("d2h_fetches")
-    return int(x)
+    return jax.device_get(tree)
 
 
 def finalize(d: dict) -> dict:
-    """Device aggregate dict -> exact host ints, planes reassembled
-    (the only step that may exceed int32, hence Python ints)."""
-    return {"sum": (_fetch(d["sum_hi"]) << 16) + _fetch(d["sum_lo"]),
-            "count": _fetch(d["count"]),
-            "min": _fetch(d["min"]),
-            "max": _fetch(d["max"])}
+    """Aggregate dict -> exact host ints, planes reassembled (the only
+    step that may exceed int32, hence Python ints). Device values are
+    read one by one: `fetch` them first on a served path."""
+    return {"sum": (int(d["sum_hi"]) << 16) + int(d["sum_lo"]),
+            "count": int(d["count"]), "min": int(d["min"]),
+            "max": int(d["max"])}
 
 
 def aggregate(words, mask_words, code_bits: int,

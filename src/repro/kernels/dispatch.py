@@ -78,8 +78,8 @@ class KernelOp:
 _REGISTRY: dict[str, KernelOp] = {}
 
 _OP_MODULES = ("scan_filter", "aggregate", "scan_aggregate",
-               "scan_compressed", "group_aggregate", "flash_attention",
-               "decode_attention", "ssd_chunk")
+               "scan_compressed", "group_aggregate", "mask_repack",
+               "flash_attention", "decode_attention", "ssd_chunk")
 
 
 def register(name: str, *, fn, ref, tunables=None, example=None) -> KernelOp:

@@ -15,8 +15,9 @@ with explicit `MetricsRegistry` scopes on a dynamic stack:
 
 A launch is one call of a kernel family's public op, made while a
 program is traced: a jitted program counts its launches when it is
-traced, and not again each time it runs. `d2h_fetches` counts at run
-time: one per blocking device-to-host read on the served path.
+traced, and not again each time it runs; so do `tile_pads` and
+`mask_repacks`. `d2h_fetches` counts at run time: one per blocking
+device-to-host read on the served path.
 
 The registry also names the canonical cross-subsystem byte keys:
 `unified_snapshot(engine)` folds the per-subsystem `stats()` dicts
@@ -183,7 +184,9 @@ def count_launch(family: str, n: int = 1) -> None:
 def count(name: str, n: int = 1) -> None:
     """Add `n` to counter `name` in every active scope (``d2h_fetches``:
     blocking device-to-host reads on the served path; ``tile_pads``: zero
-    pads a kernel wrapper emits, counted per trace as launches are)."""
+    pads a kernel wrapper emits; ``mask_repacks``: predicate masks
+    repacked from one code width to another; the last two counted per
+    trace, as launches are)."""
     for reg in _STACK:
         reg.counter(name).inc(n)
 

@@ -8,9 +8,11 @@ A logical Plan tree binds to a table's packed columns as `ColumnSlice`s
   only as padding — the pack()-to-a-word-multiple tail, or shard-alignment
   rows — can never match a predicate (the seed's scan counted tail-pad
   codes that happened to satisfy the predicate);
-- AND/OR combine masks word-wise; when children live at different code
-  widths the masks are repacked automatically (delimiter-bit layout of one
-  width -> boolean rows -> delimiter layout of the other);
+- AND/OR combine masks word-wise: siblings at one code width first, in
+  their own layout, then one repack (repro.kernels.mask_repack) per other
+  width to the node's layout; a query whose aggregates share one width
+  forms its mask in their layout, and the final mask is repacked once per
+  other aggregate width;
 - each aggregate column reduces the selection through the dispatch-routed
   masked aggregate, and the dominant single-predicate/single-aggregate
   query takes the fused scan+aggregate kernel instead (no mask HBM
@@ -31,9 +33,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.aggregate import ops as agg_ops
+from repro.kernels.mask_repack import ops as repack_ops
 from repro.kernels.scan_aggregate import ops as fused_ops
 from repro.kernels.scan_filter import ops as scan_ops
-from repro.kernels.scan_filter.ref import codes_per_word, unpack_mask
 from repro.obs import trace as obs_trace
 from repro.query.plan import And, Or, Plan, Pred, columns_of
 
@@ -54,34 +56,6 @@ def table_slices(table) -> dict[str, ColumnSlice]:
     """Bind a repro.db Table's columns for single-device execution."""
     return {name: ColumnSlice(col.words, col.valid_words, col.code_bits)
             for name, col in table.columns.items()}
-
-
-def jnp_pack_mask(sel, code_bits: int):
-    """In-graph inverse of unpack_mask: boolean rows -> packed delimiter
-    mask (rows padded to a word multiple with False)."""
-    c = codes_per_word(code_bits)
-    sel = jnp.pad(jnp.asarray(sel, bool), (0, (-sel.shape[0]) % c))
-    sel = sel.reshape(-1, c)
-    shifts = (jnp.arange(c, dtype=jnp.uint32) * code_bits + code_bits - 1)
-    return jnp.bitwise_or.reduce(
-        jnp.where(sel, jnp.uint32(1) << shifts[None, :], jnp.uint32(0)),
-        axis=1)
-
-
-def repack_mask(mask_words, from_bits: int, to_bits: int, to_words: int):
-    """Repack a delimiter-bit mask from one code width to another.
-
-    Row counts may differ by padding (each width pads to its own word
-    multiple); rows beyond either count are padding and carry zero bits, so
-    slicing/zero-extending is exact.
-    """
-    sel = unpack_mask(mask_words, from_bits)
-    rows = to_words * codes_per_word(to_bits)
-    if sel.shape[0] >= rows:
-        sel = sel[:rows]
-    else:
-        sel = jnp.pad(sel, (0, rows - sel.shape[0]))
-    return jnp_pack_mask(sel, to_bits)
 
 
 def bind_check(plan: Plan, aggregates, columns: dict) -> None:
@@ -107,26 +81,34 @@ def bind_check(plan: Plan, aggregates, columns: dict) -> None:
     walk(plan)
 
 
-def eval_mask(plan: Plan, slices: dict[str, ColumnSlice], mode=None):
+def eval_mask(plan: Plan, slices: dict[str, ColumnSlice], mode=None,
+              layout: tuple[int, int] | None = None):
     """Evaluate a predicate tree -> (packed mask, code_bits of its layout).
 
-    The mask layout is the leftmost leaf's width; sibling masks at other
-    widths are repacked to it before combining. Always validity-masked.
+    The mask comes in `layout`, (code_bits, words), or by default in the
+    leftmost leaf's. A node combines the children of one layout in that
+    layout first, then repacks each other layout's combined mask once to
+    its own. Always validity-masked.
     """
+    combine = jnp.bitwise_or if isinstance(plan, Or) else jnp.bitwise_and
     if isinstance(plan, Pred):
         s = slices[plan.column]
         m = scan_ops.scan_filter(s.words, plan.constant, plan.op,
-                                 s.code_bits, mode=mode)
-        return m & s.valid, s.code_bits
-    if not isinstance(plan, (And, Or)):
+                                 s.code_bits, mode=mode) & s.valid
+        parts = {(s.code_bits, m.shape[0]): m}
+    elif isinstance(plan, (And, Or)):
+        parts = {}
+        for c in plan.children:
+            m, b = eval_mask(c, slices, mode)
+            key = (b, m.shape[0])
+            parts[key] = combine(parts[key], m) if key in parts else m
+    else:
         raise ValueError(f"unknown plan node {type(plan).__name__!r}")
-    parts = [eval_mask(c, slices, mode) for c in plan.children]
-    out, bits = parts[0]
-    combine = jnp.bitwise_and if isinstance(plan, And) else jnp.bitwise_or
-    for m, b in parts[1:]:
-        if b != bits or m.shape != out.shape:
-            m = repack_mask(m, b, bits, out.shape[0])
-        out = combine(out, m)
+    bits, words = layout or next(iter(parts))
+    out = None
+    for (b, _), m in parts.items():
+        m = repack_ops.repack_mask(m, b, bits, words, mode=mode)
+        out = m if out is None else combine(out, m)
     return out, bits
 
 
@@ -144,9 +126,11 @@ def _psum_aggs(d: dict, axis: str) -> dict:
 def finalize_aggs(out: dict) -> dict:
     """{column: device aggregate dict} -> {column: exact host-int dict}
     with the 16-bit sum planes reassembled (the only step allowed to
-    exceed int32, hence Python ints)."""
+    exceed int32, hence Python ints), after one blocking read of them
+    all."""
     with obs_trace.span("query.finalize"):
-        return {col: agg_ops.finalize(d) for col, d in out.items()}
+        return {col: agg_ops.finalize(d)
+                for col, d in agg_ops.fetch(out).items()}
 
 
 def referenced_bytes(plan: Plan, aggregates, columns: dict) -> int:
@@ -238,13 +222,18 @@ def execute(plan: Plan, aggregates: tuple, slices: dict[str, ColumnSlice],
             p.words, a.words, p.valid, plan.constant, plan.op, p.code_bits,
             mode=mode)
     else:
-        mask, mbits = eval_mask(plan, slices, mode)
+        layouts = list(dict.fromkeys(
+            (slices[c].code_bits, slices[c].words.shape[0])
+            for c in aggregates))
+        mask, mbits = eval_mask(plan, slices, mode,
+                                layouts[0] if len(layouts) == 1 else None)
+        masks = {key: repack_ops.repack_mask(mask, mbits, *key, mode=mode)
+                 for key in layouts}
         for col in aggregates:
             s = slices[col]
-            m = mask
-            if s.code_bits != mbits or m.shape != s.words.shape:
-                m = repack_mask(m, mbits, s.code_bits, s.words.shape[0])
-            out[col] = agg_ops.aggregate(s.words, m, s.code_bits, mode=mode)
+            out[col] = agg_ops.aggregate(
+                s.words, masks[(s.code_bits, s.words.shape[0])],
+                s.code_bits, mode=mode)
     if axis is not None:
         out = {col: _psum_aggs(d, axis) for col, d in out.items()}
     return out

@@ -358,7 +358,8 @@ def execute_encoded(plan: Plan, aggregates, table: EncodedTable,
             d = rle_ops.rle_scan_aggregate(ch.values, ch.lengths,
                                            plan.constant, plan.op,
                                            ch.code_bits, mode=mode)
-            _accumulate(out[plan.column], agg_ops.finalize(d))
+            _accumulate(out[plan.column],
+                        agg_ops.finalize(agg_ops.fetch(d)))
             continue
         bound = {n: _bind_chunk(table.columns[n], ci) for n in names}
         frames = {n: (b.base, b.slice.code_bits)
@@ -367,6 +368,7 @@ def execute_encoded(plan: Plan, aggregates, table: EncodedTable,
         raw = physical.execute(tplan, aggregates,
                                {n: b.slice for n, b in bound.items()},
                                mode=mode)
+        raw = agg_ops.fetch(raw)
         for a in aggregates:
             part = fixup_base(agg_ops.finalize(raw[a]), bound[a].base,
                               table.columns[a].code_bits)

@@ -26,7 +26,8 @@ def run_child(which: str):
 @pytest.mark.parametrize("which", ["pipeline", "pipeline2d", "compression",
                                    "ef", "train", "serve", "elastic",
                                    "query", "store", "resilience",
-                                   "relational", "d2h", "tiles"])
+                                   "relational", "d2h", "tiles",
+                                   "q6_narrow"])
 def test_multidevice(which):
     out = run_child(which)
     assert "OK" in out

@@ -210,6 +210,55 @@ def test_tile_pads_counts_q6_wrapper_pads(rows, pads):
                           "min": int(v.min()), "max": int(v.max())}
 
 
+Q6_NARROW = {"l_shipdate": 16, "l_quantity": 8, "l_discount": 8}
+Q6_WIDE = dict.fromkeys(Q6_NARROW, 16)
+
+
+def q6_table(rows: int, widths: dict) -> Table:
+    """Q6's three lineitem columns over their TPC-H domains (ship day
+    codes up to 2,526, quantity 1-50, discount 0-10) at `widths`."""
+    from repro.db.columnar import BitPackedColumn
+    rng = np.random.default_rng(17)
+    t = Table("lineitem")
+    for name, hi in (("l_shipdate", 2526), ("l_quantity", 50),
+                     ("l_discount", 10)):
+        t.add(BitPackedColumn.from_values(
+            name, rng.integers(name == "l_quantity", hi + 1, rows),
+            widths[name]))
+    return t
+
+
+@pytest.mark.parametrize("widths,repacks", ((Q6_NARROW, 1), (Q6_WIDE, 0)),
+                         ids=("narrow", "one_width"))
+@pytest.mark.parametrize("placed", ("flat", "sharded"))
+def test_q6_at_narrow_widths_takes_one_repack(widths, repacks, placed):
+    """TPC-H Q6 through QueryEngine in mode pallas, over lineitem at its
+    own widths (16, 8, 8 bits) and at one width: the numpy answer, with
+    one mask repack (the ship-date mask to the aggregates' 8 bits) at
+    mixed widths and none at one width."""
+    t = q6_table(20_001, widths)
+    table = (t if placed == "flat"
+             else ShardedTable.shard(t, make_mesh((1,), ("data",))))
+    q = Query(And.of(Pred("l_shipdate", "ge", 731),
+                     Pred("l_shipdate", "lt", 1096),
+                     Pred("l_discount", "ge", 5),
+                     Pred("l_discount", "le", 7),
+                     Pred("l_quantity", "lt", 24)),
+              ("l_discount", "l_quantity"))
+    c = {n: t.columns[n].decode().astype(np.int64) for n in widths}
+    sel = ((c["l_shipdate"] >= 731) & (c["l_shipdate"] < 1096)
+           & (c["l_discount"] >= 5) & (c["l_discount"] <= 7)
+           & (c["l_quantity"] < 24))
+    eng = QueryEngine(table, mode="pallas")
+    eng.submit(q)
+    got = eng.run()[0].aggregates
+    for a in q.aggregates:
+        v = c[a][sel]
+        assert got[a] == {"sum": int(v.sum()), "count": int(sel.sum()),
+                          "min": int(v.min()), "max": int(v.max())}
+    assert eng.metrics.counter("mask_repacks").value == repacks
+
+
 class TestPlanLayer:
     def test_operators_build_flattened_trees(self):
         p = Pred("a", "lt", 3) & Pred("b", "ge", 1) & Pred("x", "eq", 2)

@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import dispatch
 from repro.kernels.aggregate import kernel as agg_k
 from repro.kernels.group_aggregate import kernel as group_k
+from repro.kernels.mask_repack import kernel as repack_k
 from repro.kernels.scan_aggregate import kernel as fused_k
 from repro.kernels.scan_compressed import kernel as rle_k
 from repro.kernels.scan_filter import kernel as scan_k
@@ -107,6 +108,10 @@ def _kernel_cases(s):
             lambda v, n, g: group_k.rle_group_accumulate_batched_planes(
                 v, n, g, pred=("ge", 3, True), **off),
             (i3, i3, s((13,), jnp.int32))),
+        "repack_mask_packed_16to8": (lambda m: repack_k.repack_mask_packed(
+            m, from_bits=16, to_bits=8, **off), (w2,)),
+        "repack_mask_packed_2to16": (lambda m: repack_k.repack_mask_packed(
+            m, from_bits=2, to_bits=16, **off), (w2,)),
     }
 
 
@@ -114,7 +119,8 @@ KERNELS = ("scan_packed", "aggregate_packed", "aggregate_batched_packed",
            "scan_aggregate_packed", "scan_aggregate_batched_packed",
            "rle_scan_aggregate_packed", "rle_scan_aggregate_batched_packed",
            "group_sum_count_batched_planes",
-           "rle_group_accumulate_batched_planes")
+           "rle_group_accumulate_batched_planes", "repack_mask_packed_16to8",
+           "repack_mask_packed_2to16")
 
 
 @pytest.mark.parametrize("entry", KERNELS)
@@ -215,3 +221,37 @@ def test_q6_program_over_whole_tiles_pads_nothing(chips, rows, temp_bytes,
     assert sliced and max(sliced) < scan_k.TILE_WORDS, sliced
     assert reg.counter("tile_pads").value == 0
     assert compiled.memory_analysis().temp_size_in_bytes <= temp_bytes
+
+
+def test_q6_program_at_narrow_widths_fits_one_chip(topo, no_persistent_cache,
+                                                   monkeypatch):
+    """Q6 over lineitem at its own widths (16, 8, 8 bits), SF 100 on one
+    chip: the ship-date mask reaches the aggregates' 8-bit layout through
+    one `repack_mask_packed` kernel, and no buffer has a minor dimension
+    of 2 or 4 (a (words, codes per word) mask pads those to 128 lanes:
+    76.8 GB for this table)."""
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    schema = {"l_shipdate": 16, "l_quantity": 8, "l_discount": 8}
+    rps = shard_rows(schema.values(), 600_037_902, 1)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    st = ShardedTable(table=None, mesh=mesh, axis="data",
+                      rows_per_shard=rps,
+                      slices={n: ColumnSlice(None, None, b)
+                              for n, b in schema.items()})
+    args = []
+    for n in st._referenced(Q6.plan(), Q6.aggregates):
+        plane = jax.ShapeDtypeStruct((rps * schema[n] // 32,), jnp.uint32,
+                                     sharding=NamedSharding(mesh, P("data")))
+        args += [plane, plane]
+    reg = MetricsRegistry("q6_narrow")
+    with scoped(reg):
+        compiled = st._build(Q6.plan(), Q6.aggregates, "pallas").lower(
+            *args).compile()
+    hlo = compiled.as_text()
+    assert re.search(r"%repack_mask_packed\S* = \S+ custom-call\(", hlo)
+    assert reg.counter("mask_repacks").value == 1
+    minor = set(re.findall(r"\b[a-z]+\d*\[[\d,]*,(\d+)\]", hlo))
+    assert minor and not minor & {"2", "4"}, minor
+    # q6_power's bound; measured 2,400,287,232: the two 1.2 GB ship-date
+    # masks live at once before their AND, the 8-bit masks reuse them
+    assert compiled.memory_analysis().temp_size_in_bytes <= 6.1e9
