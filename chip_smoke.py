@@ -50,7 +50,8 @@ DEVICE_BYTES_PER_ROW = 8
 # (or a host fallback) fails the run
 PALLAS_FAMILIES = {"scan_filter", "aggregate", "scan_aggregate",
                    "scan_compressed", "group_aggregate",
-                   "group_aggregate_rle"}
+                   "group_aggregate_packed", "group_aggregate_rle",
+                   "mask_repack"}
 
 
 def say(*parts) -> None:

@@ -5,6 +5,9 @@ repro.kernels.dispatch.
 SELECT key, count(*), sum(val) GROUP BY key over int32 code planes, with
 the group domain handed in explicitly (an arange when a FOR frame bounds
 the key range, the sorted distinct build keys for a hash join).
+`group_sum_count_packed` is the same strategy over packed words, for a
+key and value columns that share one code width: no plane is unpacked,
+and one launch reduces every value column.
 `rle_group_accumulate[_batched]` is the fused pre-grouped strategy over
 RLE run planes — a run of length n contributes n to one group's count and
 n*value to its sum in registers, no scatter. The sort/hash fallback for
@@ -25,6 +28,7 @@ from repro.kernels import dispatch, tune
 from repro.kernels.group_aggregate import kernel as K
 from repro.kernels.group_aggregate import ref
 from repro.kernels.scan_filter.kernel import LANES
+from repro.obs import metrics as obs_metrics
 
 # dense strategy cutoff: above this many groups the accumulator plane
 # (and its (group_block, block_rows, LANES) compare tiles) stops paying
@@ -116,6 +120,68 @@ def group_sum_count(keys, vals, sel, group_keys, *, mode=None,
     return out[0]
 
 
+# largest tile of the packed kernel, and the VMEM its double-buffered
+# key, mask and value blocks may take (a v5e kernel has about 16 MiB)
+PACKED_BLOCK_ROWS = 1024
+PACKED_VMEM_BYTES = 12 << 20
+
+
+def packed_block_rows(rows: int, code_bits: int, n_planes: int) -> int:
+    """Rows of words per packed-kernel step over `n_planes` (rows, LANES)
+    planes: the largest power of two up to PACKED_BLOCK_ROWS that keeps
+    every per-lane partial of a step int32-exact (block_rows / SUBLANES
+    rows of codes per word at the width's maximum) and the blocks within
+    PACKED_VMEM_BYTES; halved further, down to the default block, until
+    it divides `rows`, so a shard at whole tiles needs no pad."""
+    cpw, vmax = 32 // code_bits, (1 << (code_bits - 1)) - 1
+    cap = min(K.SUBLANES * ((2**31 - 1) // (cpw * vmax)),
+              PACKED_VMEM_BYTES // (2 * n_planes * LANES * 4))
+    br = PACKED_BLOCK_ROWS
+    while br > max(cap, K.SUBLANES):
+        br //= 2
+    while br > K.DEFAULT_BLOCK_ROWS and rows % br:
+        br //= 2
+    return min(br, -(-rows // K.SUBLANES) * K.SUBLANES)
+
+
+def group_sum_count_packed(key_words, mask_words, value_words, group_keys,
+                           *, code_bits: int, mode=None):
+    """Dense grouped aggregate over packed words, every value column in
+    ONE launch.
+
+    key_words: (n_words,) uint32 packed key codes; mask_words: the packed
+    selection mask in the key's layout (delimiter bit set per selected
+    row, so rows past the table must carry none); value_words: a sequence
+    of k (n_words,) packed columns at the key's code width; group_keys:
+    sorted (G,) int32. Returns int32[max(k, 1), G, 3] of normalized
+    [sum_lo, sum_hi, count] rows, one plane per value column (sums 0 in
+    the count-only plane when k is 0).
+    """
+    r = dispatch.resolve(mode)
+    dispatch.count_launch("group_aggregate_packed")
+    value_words = tuple(jnp.asarray(w, jnp.uint32) for w in value_words)
+    key_words = jnp.asarray(key_words, jnp.uint32)
+    mask_words = jnp.asarray(mask_words, jnp.uint32)
+    gk = jnp.asarray(group_keys, jnp.int32)
+    n_planes, g = max(len(value_words), 1), gk.shape[0]
+    if key_words.shape[0] == 0 or g == 0:
+        return jnp.zeros((n_planes, g, 3), jnp.int32)
+    if not r.use_pallas:
+        return ref.group_sum_count_packed_ref(
+            key_words, mask_words, value_words, gk, code_bits=code_bits)
+    rows = -(-key_words.shape[0] // LANES)
+    br = packed_block_rows(rows, code_bits, 2 + len(value_words))
+    n = -(-rows // br) * br * LANES
+    if n != key_words.shape[0]:
+        # zero words carry no mask bit: padded rows select nothing
+        obs_metrics.count("tile_pads")
+    planes = [jnp.pad(w, (0, n - w.shape[0])).reshape(-1, LANES)
+              for w in (key_words, mask_words) + value_words]
+    return K.group_sum_count_packed(
+        planes[0], planes[1], tuple(planes[2:]), gk, code_bits=code_bits,
+        block_rows=br, interpret=r.interpret)
+
+
 def rle_group_accumulate_batched(run_planes, group_keys, *, pred=None,
                                  mode=None, block_rows: int | None = None,
                                  group_block: int | None = None):
@@ -184,7 +250,23 @@ def _example(rng):
             {})
 
 
+def _packed_example(rng):
+    from repro.kernels.scan_filter.ref import pack
+    rows, bits = 5000, 8                 # ragged: a part word, padding
+    keys = rng.integers(0, 7, rows)
+    vals = [rng.integers(0, 128, rows) for _ in range(3)]
+    sel = rng.integers(0, 2, rows) << (bits - 1)
+    return ((jnp.asarray(pack(keys, bits)), jnp.asarray(pack(sel, bits)),
+             tuple(jnp.asarray(pack(v, bits)) for v in vals),
+             jnp.arange(7, dtype=jnp.int32)),
+            {"code_bits": bits})
+
+
 dispatch.register(
     "group_aggregate", fn=group_sum_count_batched, ref=_batched_ref,
     tunables={"block_rows": (64, 128, 256), "group_block": (4, 8, 16)},
     example=_example)
+
+dispatch.register(
+    "group_aggregate_packed", fn=group_sum_count_packed,
+    ref=ref.group_sum_count_packed_ref, example=_packed_example)

@@ -24,6 +24,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.scan_filter.ref import unpack, unpack_mask
+
 _STAGE = 4096        # segment partials stay < 2^27: exact in int32
 
 
@@ -91,6 +93,24 @@ def group_sum_count_batched_ref(keys3, vals3, sel3, group_keys):
         _slots(kc.reshape(-1), gk), vc.reshape(-1),
         sc.reshape(-1) > 0, gk.shape[0]))
     return fn(k, v, s)
+
+
+@partial(jax.jit, static_argnames=("code_bits",))
+def group_sum_count_packed_ref(key_words, mask_words, value_words,
+                               group_keys, *, code_bits: int):
+    """Packed oracle: unpack the key, the mask's delimiter bits and each
+    value column's payload to rows, then the batched plane oracle, one
+    chunk per value column (one count-only chunk when there is none) ->
+    int32[max(k, 1), G, 3]."""
+    keys = unpack(key_words, code_bits).astype(jnp.int32)
+    sel = unpack_mask(mask_words, code_bits).astype(jnp.int32)
+    payload = (1 << (code_bits - 1)) - 1
+    vals = [unpack(w, code_bits).astype(jnp.int32) & payload
+            for w in value_words] or [jnp.zeros_like(keys)]
+    n = len(vals)
+    return group_sum_count_batched_ref(
+        jnp.broadcast_to(keys, (n,) + keys.shape), jnp.stack(vals),
+        jnp.broadcast_to(sel, (n,) + sel.shape), group_keys)
 
 
 def _rle_one(vals, lens, group_keys, pred):

@@ -40,7 +40,8 @@ from repro.query.physical import ColumnSlice
 from repro.query.plan import columns_of
 
 
-# rows a shard unpacks per grouped-kernel launch (int32 planes of 64 MiB)
+# rows a shard unpacks per grouped-kernel launch on the slab path, where
+# a value column is at another width than the key (int32 planes of 64 MiB)
 GROUP_SLAB_ROWS = 1 << 24
 
 
@@ -327,12 +328,49 @@ class ShardedTable:
                 *self._args(self._referenced(plan, aggs + (key,))))
 
     def _build_grouped(self, plan, key: str, aggs: tuple, mode):
+        """The per-shard grouped program. Where the key and every value
+        column share one code width, field i of word w is the same row in
+        each, so one packed kernel groups the words as they lie, under the
+        plan's mask built in the key's layout. Otherwise the shard unpacks
+        slab by slab to int32 planes and groups those."""
         from repro.kernels.group_aggregate import ops as gops
-        from repro.query import relational
         names = self._referenced(plan, aggs + (key,))
         bits = {n: self.slices[n].code_bits for n in names}
         axis = self.axis
         value_cols = aggs if aggs else ("",)
+
+        def packed(gk, *flat):
+            obs_metrics.count("grouped_packed")
+            slices = {n: ColumnSlice(flat[2 * i], flat[2 * i + 1], bits[n])
+                      for i, n in enumerate(names)}
+            k = slices[key]
+            # every leaf's mask is ANDed with its column's validity, and
+            # all columns are valid on the same rows: the mask selects no
+            # row past the table, so the key's validity adds nothing
+            mask, _ = physical.eval_mask(plan, slices, mode,
+                                         layout=(bits[key], k.words.shape[0]))
+            planes = gops.group_sum_count_packed(
+                k.words, mask, [slices[a].words for a in aggs], gk,
+                code_bits=bits[key], mode=mode)
+            return {name: planes[i][None]
+                    for i, name in enumerate(value_cols)}
+
+        same_width = all(bits[a] == bits[key] for a in aggs)
+        return jax.jit(jax.shard_map(
+            packed if same_width else self._grouped_slabs(
+                plan, key, value_cols, names, bits, mode),
+            mesh=self.mesh,
+            in_specs=(P(),) + (P(axis),) * (2 * len(names)),
+            out_specs=P(axis), check_vma=False))
+
+    def _grouped_slabs(self, plan, key: str, value_cols: tuple, names,
+                       bits: dict, mode):
+        """The per-shard body for value columns at another width than the
+        key: a loop over slabs of rows, each unpacked to int32 code
+        planes, filtered by the plan on the codes, and grouped once per
+        value column; the normalized planes merge on the device."""
+        from repro.kernels.group_aggregate import ops as gops
+        from repro.query import relational
         # the kernel reads int32 code planes, 4 B per row per column: a
         # shard unpacks one slab of rows at a time, so the planes stay
         # small next to the packed table at any table size
@@ -361,6 +399,7 @@ class ShardedTable:
                     for name in value_cols}
 
         def per_shard(gk, *flat):
+            obs_metrics.count("grouped_slabs")
             acc = {name: jnp.zeros((gk.shape[0], 3), jnp.int32)
                    for name in value_cols}
             if n_slabs:
@@ -372,10 +411,7 @@ class ShardedTable:
                     acc, slab_planes(gk, flat, n_slabs * slab, tail))
             return {name: p[None] for name, p in acc.items()}
 
-        return jax.jit(jax.shard_map(
-            per_shard, mesh=self.mesh,
-            in_specs=(P(),) + (P(axis),) * (2 * len(names)),
-            out_specs=P(axis), check_vma=False))
+        return per_shard
 
     def execute_grouped(self, query, mode=None) -> dict:
         """GroupBy/HashJoin across the mesh: per-shard dense accumulator

@@ -507,6 +507,48 @@ def check_q6_narrow():
     print("OK q6_narrow")
 
 
+def check_grouped_packed():
+    """Grouped queries whose key and value columns share one code width,
+    on 8 shards below one kernel tile and on 2 at whole tiles: one packed
+    kernel per shard (`grouped_packed` 1, `grouped_slabs` 0, no pad over
+    whole tiles) and numpy's answers; a value column at another width
+    still takes the slab path."""
+    from repro.db.columnar import BitPackedColumn, Table
+    from repro.kernels.scan_filter.kernel import TILE_WORDS
+    from repro.obs.metrics import MetricsRegistry, scoped
+    from repro.query import GroupBy, HashJoin, Pred, relational
+    from repro.query.sharded import ShardedTable
+
+    rng = np.random.default_rng(23)
+    dim = Table("dim")
+    dim.add(BitPackedColumn.from_values(
+        "k", np.array([0, 2, 3, 9, 100]), 8))
+    queries = [
+        (GroupBy("k", ("q", "x", "t"), where=Pred("d", "le", 2436)), 1),
+        (GroupBy("k", where=Pred("q", "lt", 30) & Pred("d", "ge", 900)), 1),
+        (HashJoin(dim, "k", "k", aggs=("x",), where=Pred("t", "ne", 4)), 1),
+        (GroupBy("k", ("d",), where=Pred("x", "lt", 5)), 0),   # 16-bit value
+    ]
+    for n, rows in ((8, 100_001), (2, 2 * 2 * TILE_WORDS * 4 + 777)):
+        table = Table("lineitem")
+        for name, hi, bits in (("d", 2526, 16), ("q", 50, 8), ("x", 10, 8),
+                               ("t", 8, 8), ("k", 3, 8)):
+            table.add(BitPackedColumn.from_values(
+                name, rng.integers(0, hi + 1, rows), bits))
+        st = ShardedTable.shard(table, make_mesh((n,), ("data",)))
+        for q, packed in queries:
+            reg = MetricsRegistry("grouped")
+            with scoped(reg):
+                got = st.execute_grouped(q, mode="pallas")
+            assert got == relational.execute_grouped_oracle(q, table), \
+                (n, q)
+            assert reg.counter("grouped_packed").value == packed, (n, q)
+            assert reg.counter("grouped_slabs").value == 1 - packed, (n, q)
+            if packed and n == 2:
+                assert reg.counter("tile_pads").value == 0, q
+    print("OK grouped_packed")
+
+
 def check_serve_step_sharded():
     from repro.configs import get_config
     from repro.configs.base import ShapeSpec
@@ -537,6 +579,7 @@ if __name__ == "__main__":
         "d2h": check_d2h_fetches,
         "tiles": check_whole_tile_shards,
         "q6_narrow": check_q6_narrow,
+        "grouped_packed": check_grouped_packed,
     }
     if which == "all":
         for fn in checks.values():

@@ -47,7 +47,8 @@ def test_xla_ref_and_legacy_use_kernel_flag():
 def test_registry_has_all_families():
     assert set(dispatch.registered()) == {
         "scan_filter", "aggregate", "scan_aggregate", "scan_compressed",
-        "group_aggregate", "mask_repack", "flash_attention",
+        "group_aggregate", "group_aggregate_packed", "mask_repack",
+        "flash_attention",
         "decode_attention", "ssd_chunk"}
 
 

@@ -27,7 +27,7 @@ def run_child(which: str):
                                    "ef", "train", "serve", "elastic",
                                    "query", "store", "resilience",
                                    "relational", "d2h", "tiles",
-                                   "q6_narrow"])
+                                   "q6_narrow", "grouped_packed"])
 def test_multidevice(which):
     out = run_child(which)
     assert "OK" in out

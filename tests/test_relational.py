@@ -290,10 +290,55 @@ def test_sharded_grouped_slabs_match_numpy(table, monkeypatch, slab_rows,
     and merges the normalized planes on the device: several slabs plus a
     ragged tail give the same answer as one slab and as numpy."""
     from repro.launch.mesh import make_mesh
+    from repro.obs.metrics import MetricsRegistry, scoped
     from repro.query import sharded
     monkeypatch.setattr(sharded, "GROUP_SLAB_ROWS", slab_rows)
     st = sharded.ShardedTable.shard(table, make_mesh((1,), ("data",)))
     q = GroupBy("u", ("w", "f"), where=Pred("w", "lt", 9050))
     sel = table.columns["w"].decode() < 9050
-    assert st.execute_grouped(q, mode=mode) == \
-        _np_grouped(table, "u", ("w", "f"), sel)
+    reg = MetricsRegistry("slabs")
+    with scoped(reg):
+        got = st.execute_grouped(q, mode=mode)
+    assert got == _np_grouped(table, "u", ("w", "f"), sel)
+    # "w" is 16-bit, the key 8-bit: the slab path, not the packed one
+    assert reg.counter("grouped_slabs").value == 1
+    assert reg.counter("grouped_packed").value == 0
+
+
+def _same_width_queries(dim):
+    """Grouped queries whose key and value columns are all 8-bit, under
+    predicates at 8 and 16 bits, with the rows each selects."""
+    return {
+        "groupby_16bit_pred": (
+            GroupBy("r", ("u", "f"), where=Pred("w", "lt", 9050)),
+            lambda c: c["w"] < 9050),
+        "groupby_no_where": (GroupBy("u", ("r",)), lambda c: c["u"] >= 0),
+        "count_only": (GroupBy("r", where=Pred("f", "ge", 44)),
+                       lambda c: c["f"] >= 44),
+        "join": (HashJoin(dim, "u", "u", aggs=("f", "r"),
+                          where=Pred("w", "ge", 9020) | Pred("r", "lt", 2)),
+                 lambda c: ((c["w"] >= 9020) | (c["r"] < 2))
+                 & np.isin(c["u"], [2, 7, 50, 90])),
+    }
+
+
+@pytest.mark.parametrize("name", ["groupby_16bit_pred", "groupby_no_where",
+                                  "count_only", "join"])
+@pytest.mark.parametrize("mode", ("pallas", "xla_ref"))
+def test_sharded_grouped_packed_match_numpy(table, dim, name, mode):
+    """Key and value columns at one code width: the sharded grouped path
+    groups the packed words in one kernel launch, under the plan's mask
+    built in the key's layout, with numpy's answers."""
+    from repro.launch.mesh import make_mesh
+    from repro.obs.metrics import MetricsRegistry, scoped
+    from repro.query import sharded
+    st = sharded.ShardedTable.shard(table, make_mesh((1,), ("data",)))
+    q, mksel = _same_width_queries(dim)[name]
+    cols = {n: c.decode().astype(np.int64)
+            for n, c in table.columns.items()}
+    reg = MetricsRegistry("packed")
+    with scoped(reg):
+        got = st.execute_grouped(q, mode=mode)
+    assert got == _np_grouped(table, q.key, q.aggs, mksel(cols))
+    assert reg.counter("grouped_packed").value == 1
+    assert reg.counter("grouped_slabs").value == 0

@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import dispatch
 from repro.kernels.aggregate import kernel as agg_k
 from repro.kernels.group_aggregate import kernel as group_k
+from repro.kernels.group_aggregate import ops as group_ops
 from repro.kernels.mask_repack import kernel as repack_k
 from repro.kernels.scan_aggregate import kernel as fused_k
 from repro.kernels.scan_compressed import kernel as rle_k
@@ -108,6 +109,16 @@ def _kernel_cases(s):
             lambda v, n, g: group_k.rle_group_accumulate_batched_planes(
                 v, n, g, pred=("ge", 3, True), **off),
             (i3, i3, s((13,), jnp.int32))),
+        "group_sum_count_packed": (
+            lambda k, m, v, g: group_k.group_sum_count_packed(
+                k, m, (v, v, v), g, code_bits=8, block_rows=1024, **off),
+            (w2, w2, w2, s((13,), jnp.int32))),
+        # twenty value columns: the block shrinks to fit the kernel's VMEM
+        "group_sum_count_packed_20_values": (
+            lambda k, m, v, g: group_k.group_sum_count_packed(
+                k, m, (v,) * 20, g, code_bits=8,
+                block_rows=group_ops.packed_block_rows(ROWS, 8, 22), **off),
+            (w2, w2, w2, s((4,), jnp.int32))),
         "repack_mask_packed_16to8": (lambda m: repack_k.repack_mask_packed(
             m, from_bits=16, to_bits=8, **off), (w2,)),
         "repack_mask_packed_2to16": (lambda m: repack_k.repack_mask_packed(
@@ -118,7 +129,8 @@ def _kernel_cases(s):
 KERNELS = ("scan_packed", "aggregate_packed", "aggregate_batched_packed",
            "scan_aggregate_packed", "scan_aggregate_batched_packed",
            "rle_scan_aggregate_packed", "rle_scan_aggregate_batched_packed",
-           "group_sum_count_batched_planes",
+           "group_sum_count_batched_planes", "group_sum_count_packed",
+           "group_sum_count_packed_20_values",
            "rle_group_accumulate_batched_planes", "repack_mask_packed_16to8",
            "repack_mask_packed_2to16")
 
@@ -255,3 +267,47 @@ def test_q6_program_at_narrow_widths_fits_one_chip(topo, no_persistent_cache,
     # q6_power's bound; measured 2,400,287,232: the two 1.2 GB ship-date
     # masks live at once before their AND, the 8-bit masks reuse them
     assert compiled.memory_analysis().temp_size_in_bytes <= 6.1e9
+
+
+def test_q1_program_groups_the_packed_words(topo, no_persistent_cache,
+                                            monkeypatch):
+    """TPC-H Q1 over SF 100's lineitem at its own widths (ship date 16
+    bits, key and values 8) on one chip: the ship-date mask reaches the
+    key's layout through one repack and one packed kernel groups all
+    three value columns; no slab loop, and the temporaries are the two
+    masks, not the slab path's int32 planes (2.61 GB)."""
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    schema = {"l_shipdate": 16, "l_quantity": 8, "l_discount": 8,
+              "l_tax": 8, "l_rfls": 8}
+    q = GroupBy("l_rfls", ("l_quantity", "l_discount", "l_tax"),
+                where=Pred("l_shipdate", "le", 2436))
+    rps = shard_rows(schema.values(), 600_047_616, 1)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    st = ShardedTable(table=None, mesh=mesh, axis="data",
+                      rows_per_shard=rps,
+                      slices={n: ColumnSlice(None, None, b)
+                              for n, b in schema.items()})
+    args = [jax.ShapeDtypeStruct((4,), jnp.int32,
+                                 sharding=NamedSharding(mesh, P()))]
+    for n in st._referenced(q.plan(), q.aggs + (q.key,)):
+        plane = jax.ShapeDtypeStruct((rps * schema[n] // 32,), jnp.uint32,
+                                     sharding=NamedSharding(mesh, P("data")))
+        args += [plane, plane]
+    reg = MetricsRegistry("q1")
+    with scoped(reg):
+        compiled = st._build_grouped(q.plan(), q.key, q.aggs,
+                                     "pallas").lower(*args).compile()
+    hlo = compiled.as_text()
+    calls = re.findall(r"%(\w+?)(?:\.\d+)? = \S+ custom-call\(", hlo)
+    assert sorted(calls) == ["group_sum_count_packed",
+                             "repack_mask_packed", "scan_packed"], calls
+    assert not re.search(r"\bwhile\(", hlo)
+    assert {k: reg.counter(k).value for k in ("grouped_packed",
+                                              "grouped_slabs",
+                                              "mask_repacks",
+                                              "tile_pads")} == {
+        "grouped_packed": 1, "grouped_slabs": 0, "mask_repacks": 1,
+        "tile_pads": 0}
+    # measured 1,800,239,616: the 1.2 GB ship-date mask and its 0.6 GB
+    # repack into the key's layout
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.85e9
