@@ -39,14 +39,6 @@ def _load(name: str) -> tuple[list, dict]:
     return hist, rec
 
 
-def check_kernels() -> str:
-    hist, rec = _load("kernels")
-    assert rec["tuned_gbps"] > 0 and rec["speedup"] > 0, rec
-    return (f"{len(hist)} record(s), last: {rec['op']} "
-            f"{rec['default_gbps']}->{rec['tuned_gbps']} GB/s "
-            f"({rec['speedup']}x)")
-
-
 def check_queries() -> str:
     hist, rec = _load("queries")
     assert rec["scan_agg_gbps"] > 0 and rec["n_shards"] >= 1, rec
@@ -150,7 +142,6 @@ def check_resilience() -> str:
 
 
 CHECKS = {
-    "kernels": check_kernels,
     "queries": check_queries,
     "tier": check_tier,
     "energy": check_energy,

@@ -54,7 +54,6 @@ def _resilience_headline(rec: dict) -> float:
 
 HEADLINES = {
     # bench -> (label, extractor); every metric is higher-is-better
-    "kernels": ("tuned_gbps", lambda r: r["tuned_gbps"]),
     "queries": ("scan_agg_gbps", lambda r: r["scan_agg_gbps"]),
     "tier": ("memcache hit_rate @skew=1.1",
              lambda r: r["policies"]["memcache"]["1.1"]["hit_rate"]),

@@ -12,9 +12,8 @@ Two experiments, both appended to BENCH_energy.json at the repo root:
 
 2. *Decision surface*: the paper's 16 TiB / 20%-accessed workload swept
    over SLA x skew x power budget (Fig. 4's 50 kW / 250 kW / 1 MW
-   operating points), winners priced from the CostSheet — with the fast
-   tier at the autotune cache's measured rate when one exists, so the
-   surface answers for the system we actually built.
+   operating points), winners priced from the CostSheet at the
+   datasheet's Eq. 4 tier rates.
 
 Set REPRO_ENERGY_BENCH_QUICK=1 for a smaller table/trace (CI smoke).
 """
@@ -31,8 +30,8 @@ from repro.core.advisor import advise_cost
 from repro.core.systems import DIE_STACKED, TiB
 from repro.db import Table
 from repro.energy import PowerCap, chip_compute_watts, decision_surface
-from repro.tier import (Policy, TraceSpec, make_trace, measured_fast_gbps,
-                        paper_tiers, replay_trace)
+from repro.tier import (Policy, TraceSpec, make_trace, paper_tiers,
+                        replay_trace)
 
 BENCH_PATH = Path(__file__).resolve().parents[1] / "BENCH_energy.json"
 
@@ -55,8 +54,7 @@ def _capped_replay() -> tuple[list, dict]:
     n_cols, n_rows, chunk_rows, n_queries = _sizes()
     table = Table.synthetic("energy", n_rows,
                             {f"c{i:02d}": 8 for i in range(n_cols)}, seed=0)
-    fast_gbps = measured_fast_gbps(default=8.0)
-    tiers = paper_tiers(table.nbytes * FAST_FRACTION, fast_gbps=fast_gbps)
+    tiers = paper_tiers(table.nbytes * FAST_FRACTION, fast_gbps=8.0)
     trace = make_trace(table, TraceSpec(n_queries=n_queries, skew=SKEW,
                                         seed=7))
     compute_w = chip_compute_watts(DIE_STACKED)
@@ -117,14 +115,12 @@ def _capped_replay() -> tuple[list, dict]:
 
 
 def _surface() -> tuple[list, dict]:
-    fast_gbps = measured_fast_gbps()       # None -> datasheet Eq. 4 rates
     quick = bool(os.environ.get("REPRO_ENERGY_BENCH_QUICK"))
     slas = (0.010, 0.060, 1.0) if quick else (0.005, 0.010, 0.060, 0.250,
                                               1.0)
     t0 = time.perf_counter()
     surf = decision_surface(PAPER_DB, PAPER_ACCESSED * PAPER_DB,
-                            slas=slas, skews=(None, SKEW),
-                            fast_gbps=fast_gbps)
+                            slas=slas, skews=(None, SKEW))
     us = (time.perf_counter() - t0) / max(len(surf["cells"]), 1) * 1e6
     rows = []
     for cell in surf["cells"]:
@@ -133,12 +129,12 @@ def _surface() -> tuple[list, dict]:
                 f"energy/surface/sla={cell['sla_s']:g}s/1MW", us,
                 f"winner={cell['winner']}"))
     cheapest = advise_cost(PAPER_DB, PAPER_ACCESSED * PAPER_DB, 0.010, 1e6,
-                           skew=SKEW, fast_gbps=fast_gbps)
+                           skew=SKEW)
     rows.append(("energy/advise_cost/10ms/1MW/zipf1.1", 0.0,
                  f"winner={cheapest['winner']},"
                  f"${(cheapest['usd_per_query'] or 0):.4f}/q"))
     record = {
-        "fast_gbps": fast_gbps,
+        "fast_gbps": None,              # the datasheet's Eq. 4 rates
         "winners": {f"sla={c['sla_s']:g};skew={c['skew']};"
                     f"budget={c['power_budget_w']:g}": c["winner"]
                     for c in surf["cells"]},

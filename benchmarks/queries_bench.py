@@ -25,6 +25,7 @@ from benchmarks.common import append_trajectory, obs_digest, timed
 from repro.db import Table
 from repro.db.columnar import BitPackedColumn
 from repro.launch.mesh import make_mesh
+from repro.obs import metrics as obs_metrics
 from repro.query import GroupBy, Pred, Query, QueryEngine, ShardedTable
 from repro.query import relational
 
@@ -89,10 +90,10 @@ def _rle_vs_fallback() -> tuple[dict, object]:
     against the host sort/hash fallback on the same bytes — the
     pre-grouped-data win the RLE strategy exists for. The fallback is
     forced by shrinking the dense cutoff, the documented strategy knob."""
-    from repro.kernels import dispatch
     from repro.kernels.group_aggregate import ops as gops
     from repro.store import EncodedTable
     from repro.store.exec import execute_grouped_encoded
+    default = obs_metrics.default_registry()
     rng = np.random.default_rng(11)
     n = 1 << 18
     t = Table("rle")
@@ -105,12 +106,12 @@ def _rle_vs_fallback() -> tuple[dict, object]:
         "sorted low-cardinality key did not RLE-encode"
     q = GroupBy("k")                              # count-only: RLE-fused
     execute_grouped_encoded(q, store, mode="xla_ref")      # warm
-    before = dict(dispatch.launch_counts())
+    before = dict(default.launch_counts())
     want, rle_us = timed(lambda: execute_grouped_encoded(
         q, store, mode="xla_ref"), repeat=3)
     # timed() makes 1 warm + 3 timed calls after the snapshot
     launches = {k: (v - before.get(k, 0)) / 4
-                for k, v in dispatch.launch_counts().items()
+                for k, v in default.launch_counts().items()
                 if v != before.get(k, 0)}
     saved = relational.DENSE_MAX_GROUPS, gops.DENSE_MAX_GROUPS
     try:
@@ -143,11 +144,11 @@ def rows():
     # compile the execution into st's jit cache with a throwaway engine so
     # eng's cumulative totals (model_check/provision below) measure hot
     # scans, not trace+compile
-    warm = QueryEngine(st, mode="auto")
+    warm = QueryEngine(st)
     warm.submit(q)
     warm.run()
 
-    eng = QueryEngine(st, mode="auto")
+    eng = QueryEngine(st)
 
     def once():
         eng.submit(q)

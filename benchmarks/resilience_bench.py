@@ -40,8 +40,8 @@ from benchmarks.store_bench import compressible_table
 from repro.query import physical
 from repro.resilience import ChaosHarness, ChunkGuard, FaultSpec, RetryPolicy
 from repro.store import EncodedTable
-from repro.tier import (Policy, TraceSpec, make_trace, measured_fast_gbps,
-                        paper_tiers, replay_trace)
+from repro.tier import (Policy, TraceSpec, make_trace, paper_tiers,
+                        replay_trace)
 
 BENCH_PATH = Path(__file__).resolve().parents[1] / "BENCH_resilience.json"
 
@@ -112,8 +112,7 @@ def rows():
     n_cols, n_rows, chunk_rows, n_queries = _sizes()
     table = compressible_table(n_cols, n_rows, seed=0)
     encoded = EncodedTable.from_table(table, chunk_rows=chunk_rows)
-    fast_gbps = measured_fast_gbps(default=8.0)
-    tiers = paper_tiers(table.nbytes * FAST_FRACTION, fast_gbps=fast_gbps)
+    tiers = paper_tiers(table.nbytes * FAST_FRACTION, fast_gbps=8.0)
     trace = make_trace(table, TraceSpec(n_queries=n_queries, skew=1.1,
                                         seed=7))
     bytes_typ = sum(

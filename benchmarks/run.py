@@ -2,7 +2,7 @@
 
 Prints ``name,us_per_call,derived`` CSV rows, or a JSON array with
 ``--json``. ``--only substr`` restricts to matching module names (CI runs
-``--only kernels --json`` as the smoke invocation).
+``--only queries --json`` as one smoke invocation).
 """
 from __future__ import annotations
 
@@ -22,7 +22,6 @@ MODULES = (
     "benchmarks.fig6_energy",
     "benchmarks.crossover",
     "benchmarks.advisor_tpu",
-    "benchmarks.kernels_bench",
     "benchmarks.queries_bench",
     "benchmarks.tier_bench",
     "benchmarks.energy_bench",

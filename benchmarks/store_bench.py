@@ -47,12 +47,12 @@ from repro.db.columnar import BitPackedColumn, Table
 from repro.energy.tco import (cheapest_architecture,
                               compression_crossover_ratio)
 from repro.core.systems import TiB
-from repro.kernels import dispatch
+from repro.obs import metrics as obs_metrics
 from repro.query import physical
 from repro.store import EncodedTable
 from repro.store.exec import execute_encoded
-from repro.tier import (Policy, TraceSpec, make_trace, measured_fast_gbps,
-                        paper_tiers, replay_trace)
+from repro.tier import (Policy, TraceSpec, make_trace, paper_tiers,
+                        replay_trace)
 
 BENCH_PATH = Path(__file__).resolve().parents[1] / "BENCH_store.json"
 
@@ -146,9 +146,8 @@ def rows():
         for k, v in col.encodings().items():
             enc_counts[k] = enc_counts.get(k, 0) + v
 
-    fast_gbps = measured_fast_gbps(default=8.0)
     # fixed *absolute* fast capacity: 25% of the PLAIN table for both runs
-    tiers = paper_tiers(table.nbytes * FAST_FRACTION, fast_gbps=fast_gbps)
+    tiers = paper_tiers(table.nbytes * FAST_FRACTION, fast_gbps=8.0)
     trace = make_trace(table, TraceSpec(n_queries=n_queries, skew=SKEW,
                                         seed=7))
     sla_s = SLA_SLACK * (table.nbytes / n_cols * 2) / tiers.fast.bandwidth
@@ -166,15 +165,16 @@ def rows():
     pe_p, eng_p, att_p = replay_trace(table, trace, tiers, Policy.CACHE,
                                       sla_s=sla_s, chunk_rows=chunk_rows)
     plain_us = (time.perf_counter() - t0) / len(trace) * 1e6
-    dispatch.reset_launch_counts()
+    default = obs_metrics.default_registry()
+    default.reset_launches()
     t0 = time.perf_counter()
     pe_e, eng_e, att_e = replay_trace(encoded, trace, tiers, Policy.CACHE,
                                       sla_s=sla_s, chunk_rows=chunk_rows)
     enc_us = (time.perf_counter() - t0) / len(trace) * 1e6
     launches = {
-        "per_query": round(dispatch.total_launches() / len(trace), 2),
+        "per_query": round(default.total_launches() / len(trace), 2),
         "n_chunks": encoded.n_chunks,
-        "by_family": dispatch.launch_counts(),
+        "by_family": default.launch_counts(),
     }
     se, sp = eng_e.summary(), eng_p.summary()
 

@@ -5,10 +5,9 @@ policies (STATIC memory-style pinning, CACHE LRU, MEMCACHE frequency-aware
 admission) at three skew levels, with the fast tier capped at 25% of the
 table — the regime where the paper's question ("is the bandwidth-rich,
 capacity-poor tier worth it?") has a non-trivial answer. The fast tier
-runs at the autotuned kernel sweep's measured rate (repro.tier.tiers.
-measured_fast_gbps); the capacity tier is derated by the Table 1 bandwidth
-ratio. Deadlines ride a VirtualClock on the modeled tiered latency, so the
-numbers are CPU-speed-independent and reproducible.
+runs at a fixed 8 GB/s; the capacity tier is derated by the Table 1
+bandwidth ratio. Deadlines ride a VirtualClock on the modeled tiered
+latency, so the numbers are CPU-speed-independent and reproducible.
 
 Appends one record per run to BENCH_tier.json at the repo root — a
 trajectory future PRs diff to catch placement/accounting regressions.
@@ -26,8 +25,8 @@ from benchmarks.common import append_trajectory, obs_digest
 from repro.core.advisor import advise_tier_split
 from repro.db import Table
 from repro.query import physical
-from repro.tier import (Policy, TraceSpec, make_trace, measured_fast_gbps,
-                        paper_tiers, replay_trace, zipf_hit_curve)
+from repro.tier import (Policy, TraceSpec, make_trace, paper_tiers,
+                        replay_trace, zipf_hit_curve)
 
 BENCH_PATH = Path(__file__).resolve().parents[1] / "BENCH_tier.json"
 
@@ -68,8 +67,7 @@ def rows():
     n_cols, n_rows, chunk_rows, n_queries = _sizes()
     table = Table.synthetic("tier", n_rows,
                             {f"c{i:02d}": 8 for i in range(n_cols)}, seed=0)
-    fast_gbps = measured_fast_gbps(default=8.0)
-    tiers = paper_tiers(table.nbytes * FAST_FRACTION, fast_gbps=fast_gbps)
+    tiers = paper_tiers(table.nbytes * FAST_FRACTION, fast_gbps=8.0)
 
     out = []
     record: dict = {"policies": {}}

@@ -26,7 +26,7 @@ import numpy as np
 
 from repro.db.columnar import BitPackedColumn, Table
 from repro.energy.tco import decision_surface
-from repro.kernels import dispatch
+from repro.obs import metrics as obs_metrics
 from repro.query import GroupBy, HashJoin, Pred, QueryEngine, relational
 from repro.store import EncodedTable
 from repro.store.exec import execute_grouped_encoded
@@ -62,12 +62,13 @@ def main():
     # --- the RLE pre-grouped path: one launch, no scatter --------------
     hist = GroupBy("region")                    # count-only histogram
     execute_grouped_encoded(hist, store, mode="xla_ref")       # warm
-    before = dict(dispatch.launch_counts())
+    default = obs_metrics.default_registry()
+    before = dict(default.launch_counts())
     t0 = time.perf_counter()
     got = execute_grouped_encoded(hist, store, mode="xla_ref")
     rle_s = time.perf_counter() - t0
     launches = {k: v - before.get(k, 0)
-                for k, v in dispatch.launch_counts().items()
+                for k, v in default.launch_counts().items()
                 if v != before.get(k, 0)}
     print(f"count-only histogram on the RLE key: launches={launches} "
           f"({store.n_chunks} chunks) in {rle_s * 1e3:.1f} ms")

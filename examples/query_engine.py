@@ -30,7 +30,7 @@ print()
 print("=" * 70)
 print("2. Deadline-batched queries (logical plans -> dispatch kernels)")
 print("=" * 70)
-engine = QueryEngine(st, mode="auto", est_gbps=0.5)
+engine = QueryEngine(st, est_gbps=0.5)
 queries = {
     "cheap & west": Query(Pred("price", "lt", 5000)
                           & Pred("region", "lt", 32),

@@ -324,8 +324,8 @@ def advise_cost(db_bytes: float, bytes_per_query: float, sla_s: float,
     Delegates to repro.energy.tco.cheapest_architecture (Table-1 systems
     performance-provisioned for the SLA, power-infeasible ones excluded,
     plus — with `skew` — a two-tier node at the zipf hit curve's blended
-    rate; `fast_gbps` prices the fast tier from the measured autotune
-    sweep; `compression_ratio` — e.g. a measured EncodedTable.ratio —
+    rate; `fast_gbps` prices the fast tier at that rate instead of the
+    datasheet's; `compression_ratio` — e.g. a measured EncodedTable.ratio —
     shrinks both footprint and traffic, the repro.store axis). With
     `measured_energy_j`/`measured_latency_s` from a metered run
     (EnergyMeter + QueryEngine), the winner's $/query is re-priced at

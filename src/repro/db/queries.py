@@ -5,7 +5,7 @@ The seed's ad-hoc single-device functions grew into the repro.query engine
 sharding, SLA-batched execution); these wrappers keep the original call
 signatures and route through that same execution path, so there is exactly
 one way a scan runs. Kernel selection is a dispatch `mode=`
-(KernelMode.PALLAS | XLA_REF | AUTO) — the `use_kernel=` booleans are gone.
+(KernelMode.PALLAS | XLA_REF).
 """
 from __future__ import annotations
 

@@ -17,9 +17,8 @@ module makes that verdict executable:
   provision_performance, plus a two-tier die-stacked-over-DDR node priced
   from the tier model), drop the power-infeasible ones, and name the
   cheapest $/query per cell — Figures 4/6/7 as one queryable surface.
-  With `fast_gbps` from the autotune cache (tier.tiers.measured_fast_gbps)
-  the tiered candidate runs at *measured* blended rates instead of
-  datasheet numbers.
+  With `fast_gbps` the tiered candidate's fast tier runs at that rate
+  instead of the datasheet's.
 """
 from __future__ import annotations
 
@@ -148,10 +147,9 @@ def evaluate_tiered(db_bytes: float, bytes_per_query: float, sla_s: float,
     feasible fraction: the whole database in capacity-tier DRAM, the fast
     fraction duplicated into die-stacked stacks, chips sized by the
     blended rate. Returns the cheapest feasible fraction's candidate, or
-    None when no fraction meets the SLA. With `fast_gbps` (the measured
-    autotune rate) both tiers move to the measured scale — the capacity
-    tier derated by the Table-1 bandwidth ratio — instead of Eq. 4
-    datasheet rates.
+    None when no fraction meets the SLA. With `fast_gbps` both tiers move
+    to that scale — the capacity tier derated by the Table-1 bandwidth
+    ratio — instead of Eq. 4 datasheet rates.
     """
     from repro.core.advisor import advise_tier_split
     from repro.tier.tiers import table1_bandwidth_ratio

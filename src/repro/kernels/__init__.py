@@ -14,15 +14,11 @@ Each package: kernel.py (pallas_call + BlockSpec), ops.py (public jit'd
 wrapper), ref.py (pure-jnp oracle).
 
 Dispatch architecture (dispatch.py): every ops.py routes through one
-KernelMode switch — PALLAS (the kernel, interpret mode off-TPU), XLA_REF
-(the oracle; differentiable), AUTO (kernel + autotuned block sizes) — and
-registers itself in a KernelOp registry carrying its oracle, tunable
-block-size grid, and an example-input factory, so tests and tools can
-enumerate and parity-check every family generically. The legacy
-`use_kernel=False` flag maps to XLA_REF.
-
-Autotuning (tune.py): ops consult a JSON on-disk cache (keyed by
-op | backend | shape) for block sizes instead of hardcoding DEFAULT_*
-constants; `tune.autotune` runs the timed sweep that populates it (wired
-into benchmarks/kernels_bench.py, trajectory in BENCH_kernels.json).
+KernelMode switch — PALLAS (the kernel, interpret mode off-TPU; the
+default) or XLA_REF (the oracle; differentiable) — and registers itself
+in a KernelOp registry carrying its oracle and an example-input factory,
+so tests and tools can enumerate and parity-check every family
+generically. Each wrapper picks its block sizes from the shape alone
+(its DEFAULT_* constants, clamped to the shape); no file or environment
+variable changes them.
 """

@@ -12,7 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import dispatch, tune
+from repro.kernels import dispatch
 from repro.kernels.aggregate import kernel as K
 from repro.kernels.aggregate import ref
 from repro.kernels.scan_filter.kernel import DEFAULT_BLOCK_ROWS, LANES
@@ -51,7 +51,7 @@ def aggregate(words, mask_words, code_bits: int,
     Codes in padded tail words have mask delimiter bits 0 and are ignored.
     """
     r = dispatch.resolve(mode)
-    dispatch.count_launch("aggregate")
+    obs_metrics.count_launch("aggregate")
     if not r.use_pallas:
         return ref.aggregate_ref(words, mask_words, code_bits)
     if words.size == 0:              # zero-row grid is undefined
@@ -64,14 +64,7 @@ def aggregate(words, mask_words, code_bits: int,
         w, m = jnp.pad(w, (0, pad)), jnp.pad(m, (0, pad))
     w, m = w.reshape(-1, LANES), m.reshape(-1, LANES)
     rows = w.shape[0]
-    br = block_rows
-    if br is None:
-        br = min(DEFAULT_BLOCK_ROWS, rows)
-        if r.tuned:
-            br = tune.best_params("aggregate",
-                                  tune.shape_key(rows=rows, bits=code_bits),
-                                  {"block_rows": br})["block_rows"]
-            br = max(1, min(int(br), rows))
+    br = block_rows or min(DEFAULT_BLOCK_ROWS, rows)
     br = min(br, sum_bound_block_rows(code_bits))
     out = K.aggregate_packed(w, m, code_bits=code_bits, block_rows=br,
                              interpret=r.interpret)
@@ -96,7 +89,7 @@ def aggregate_batched(words3, mask3, code_bits: int,
     words + packed masks -> int32[n_chunks, 5], each row bit-identical to
     the per-chunk `aggregate` at that chunk's words/mask."""
     r = dispatch.resolve(mode)
-    dispatch.count_launch("aggregate")
+    obs_metrics.count_launch("aggregate")
     w = jnp.asarray(words3, jnp.uint32)
     if w.shape[0] == 0 or w.shape[1] == 0:   # empty-selection identities
         vmax = (1 << (code_bits - 1)) - 1
@@ -108,14 +101,7 @@ def aggregate_batched(words3, mask3, code_bits: int,
     w3 = to3d_words(words3)
     m3 = to3d_words(mask3)
     rows = w3.shape[1]
-    br = block_rows
-    if br is None:
-        br = min(DEFAULT_BLOCK_ROWS, rows)
-        if r.tuned:
-            br = tune.best_params("aggregate",
-                                  tune.shape_key(rows=rows, bits=code_bits),
-                                  {"block_rows": br})["block_rows"]
-            br = max(1, min(int(br), rows))
+    br = block_rows or min(DEFAULT_BLOCK_ROWS, rows)
     br = min(br, sum_bound_block_rows(code_bits))
     return K.aggregate_batched_packed(w3, m3, code_bits=code_bits,
                                       block_rows=br, interpret=r.interpret)
@@ -135,6 +121,4 @@ def _example(rng):
 
 
 dispatch.register(
-    "aggregate", fn=aggregate, ref=ref.aggregate_ref,
-    tunables={"block_rows": (64, 256, 1024, 4096, 16384)},
-    example=_example)
+    "aggregate", fn=aggregate, ref=ref.aggregate_ref, example=_example)

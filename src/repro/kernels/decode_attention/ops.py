@@ -6,26 +6,21 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import dispatch, tune
+from repro.kernels import dispatch
 from repro.kernels.decode_attention import kernel as K
 from repro.kernels.decode_attention import ref
+from repro.kernels.flash_attention.ops import fit
 
 
 def decode_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
-                     bk: int | None = None, use_kernel: bool = True,
-                     mode=None):
+                     bk: int | None = None, mode=None):
     """q: (B, KVH, G, D); k/v: (B, KVH, S, D); q_pos (B,); kv_pos (B, S)."""
-    r = dispatch.resolve(mode, use_kernel=use_kernel)
+    r = dispatch.resolve(mode)
     if not r.use_pallas:
         return ref.decode_ref(q, k, v, q_pos, kv_pos, window=window)
-    s = k.shape[2]
-    if bk is None:
-        bk = K.DEFAULT_BK
-        if r.tuned:
-            bk = tune.best_params("decode_attention", tune.shape_key(s=s),
-                                  {"bk": bk})["bk"]
     return K.decode_attention_fwd(q, k, v, q_pos, kv_pos, window=window,
-                                  bk=tune.fit(s, bk), interpret=r.interpret)
+                                  bk=fit(k.shape[2], bk or K.DEFAULT_BK),
+                                  interpret=r.interpret)
 
 
 def _example(rng):
@@ -45,5 +40,4 @@ def _example(rng):
 
 dispatch.register(
     "decode_attention", fn=decode_attention, ref=ref.decode_ref,
-    tunables={"bk": (128, 256, 512, 1024, 2048)},
     example=_example)

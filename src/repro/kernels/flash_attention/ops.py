@@ -1,7 +1,7 @@
 """Public flash attention API, dispatched through repro.kernels.dispatch.
 
 - `flash5(q5, k, v, window)` — kernel-native layout, custom_vjp: the
-  forward runs the Pallas kernel (autotuned bq/bk), the backward
+  forward runs the Pallas kernel (bq/bk fit to the shape), the backward
   differentiates the jnp reference (correct gradients, kernel-speed
   forward).
 - `flash_attention` (models layout) — adapter used by
@@ -15,17 +15,21 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import dispatch, tune
+from repro.kernels import dispatch
 from repro.kernels.flash_attention import kernel as K
 from repro.kernels.flash_attention import ref
 
 
-def _blocks(sq: int, skv: int, tuned: bool) -> dict:
-    params = {"bq": min(K.DEFAULT_BQ, sq), "bk": min(K.DEFAULT_BK, skv)}
-    if tuned:
-        params = tune.best_params("flash_attention",
-                                  tune.shape_key(sq=sq, skv=skv), params)
-    return {"bq": tune.fit(sq, params["bq"]), "bk": tune.fit(skv, params["bk"])}
+def fit(n: int, block: int) -> int:
+    """Largest divisor of n that is <= block (block-shape validity)."""
+    block = max(1, min(int(block), int(n)))
+    while n % block:
+        block -= 1
+    return block
+
+
+def _blocks(sq: int, skv: int) -> dict:
+    return {"bq": fit(sq, K.DEFAULT_BQ), "bk": fit(skv, K.DEFAULT_BK)}
 
 
 def _forward(q, k, v, window, mode):
@@ -34,7 +38,7 @@ def _forward(q, k, v, window, mode):
         return ref.attention_ref(q, k, v, window=window)
     return K.flash_attention_fwd(q, k, v, window=window,
                                  interpret=r.interpret,
-                                 **_blocks(q.shape[3], k.shape[2], r.tuned))
+                                 **_blocks(q.shape[3], k.shape[2]))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -84,5 +88,4 @@ def _flash5_mode(q, k, v, *, mode=None):
 
 dispatch.register(
     "flash_attention", fn=_flash5_mode, ref=ref.attention_ref,
-    tunables={"bq": (64, 128, 256), "bk": (64, 128, 256, 512)},
     example=_example)

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import dispatch, tune
+from repro.kernels import dispatch
 from repro.kernels.group_aggregate import kernel as K
 from repro.kernels.group_aggregate import ref
 from repro.kernels.scan_filter.kernel import LANES
@@ -40,19 +40,12 @@ DENSE_MAX_GROUPS = 1024
 _MAX_BLOCK_ROWS = (2**31 - 1) // (LANES * ((1 << 15) - 1))
 
 
-def _params(rows: int, groups: int, tuned: bool,
-            block_rows: int | None, group_block: int | None):
-    br, gb = block_rows, group_block
-    defaults = {"block_rows": min(K.DEFAULT_BLOCK_ROWS, rows),
-                "group_block": min(K.DEFAULT_GROUP_BLOCK, groups)}
-    if (br is None or gb is None) and tuned:
-        best = tune.best_params("group_aggregate",
-                                tune.shape_key(rows=rows, groups=groups),
-                                defaults)
-        br = best["block_rows"] if br is None else br
-        gb = best["group_block"] if gb is None else gb
-    br = defaults["block_rows"] if br is None else br
-    gb = defaults["group_block"] if gb is None else gb
+def _params(rows: int, groups: int, block_rows: int | None,
+            group_block: int | None):
+    br = min(K.DEFAULT_BLOCK_ROWS, rows) if block_rows is None \
+        else block_rows
+    gb = min(K.DEFAULT_GROUP_BLOCK, groups) if group_block is None \
+        else group_block
     br = max(1, min(int(br), rows, _MAX_BLOCK_ROWS))
     gb = max(1, min(int(gb), groups))
     return br, gb
@@ -93,7 +86,7 @@ def group_sum_count_batched(keys3, vals3, sel3, group_keys, *, mode=None,
     int32[n_chunks, G, 3] of normalized [sum_lo, sum_hi, count] rows.
     """
     r = dispatch.resolve(mode)
-    dispatch.count_launch("group_aggregate")
+    obs_metrics.count_launch("group_aggregate")
     keys3 = jnp.asarray(keys3, jnp.int32)
     gk = jnp.asarray(group_keys, jnp.int32)
     n_chunks, rows = keys3.shape[0], keys3.shape[1]
@@ -102,7 +95,7 @@ def group_sum_count_batched(keys3, vals3, sel3, group_keys, *, mode=None,
         return jnp.zeros((n_chunks, g, 3), jnp.int32)
     if not r.use_pallas:
         return ref.group_sum_count_batched_ref(keys3, vals3, sel3, gk)
-    br, gb = _params(rows, g, r.tuned, block_rows, group_block)
+    br, gb = _params(rows, g, block_rows, group_block)
     return K.group_sum_count_batched_planes(
         keys3, jnp.asarray(vals3, jnp.int32), jnp.asarray(sel3, jnp.int32),
         gk, block_rows=br, group_block=gb, interpret=r.interpret)
@@ -158,7 +151,7 @@ def group_sum_count_packed(key_words, mask_words, value_words, group_keys,
     the count-only plane when k is 0).
     """
     r = dispatch.resolve(mode)
-    dispatch.count_launch("group_aggregate_packed")
+    obs_metrics.count_launch("group_aggregate_packed")
     value_words = tuple(jnp.asarray(w, jnp.uint32) for w in value_words)
     key_words = jnp.asarray(key_words, jnp.uint32)
     mask_words = jnp.asarray(mask_words, jnp.uint32)
@@ -195,7 +188,7 @@ def rle_group_accumulate_batched(run_planes, group_keys, *, pred=None,
     evaluated on the run value in-kernel. Returns int32[n_chunks, G, 3].
     """
     r = dispatch.resolve(mode)
-    dispatch.count_launch("group_aggregate_rle")
+    obs_metrics.count_launch("group_aggregate_rle")
     gk = jnp.asarray(group_keys, jnp.int32)
     n_chunks, g = len(run_planes), gk.shape[0]
     if n_chunks == 0 or g == 0:
@@ -206,7 +199,7 @@ def rle_group_accumulate_batched(run_planes, group_keys, *, pred=None,
     l3 = lift_chunks([l for _, l in run_planes])
     if not r.use_pallas:
         return ref.rle_group_accumulate_batched_ref(v3, l3, gk, pred)
-    br, gb = _params(v3.shape[1], g, r.tuned, block_rows, group_block)
+    br, gb = _params(v3.shape[1], g, block_rows, group_block)
     return K.rle_group_accumulate_batched_planes(
         v3, l3, gk, pred=pred, block_rows=br, group_block=gb,
         interpret=r.interpret)
@@ -264,7 +257,6 @@ def _packed_example(rng):
 
 dispatch.register(
     "group_aggregate", fn=group_sum_count_batched, ref=_batched_ref,
-    tunables={"block_rows": (64, 128, 256), "group_block": (4, 8, 16)},
     example=_example)
 
 dispatch.register(

@@ -26,7 +26,7 @@ def repack_mask(mask_words, from_bits: int, to_bits: int, to_words: int,
     if from_bits == to_bits:
         return ref.fit(words, to_words)
     r = dispatch.resolve(mode)
-    dispatch.count_launch("mask_repack")
+    obs_metrics.count_launch("mask_repack")
     obs_metrics.count("mask_repacks")
     if not r.use_pallas:
         return ref.repack_ref(words, from_bits, to_bits, to_words)

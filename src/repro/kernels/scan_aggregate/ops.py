@@ -14,7 +14,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import dispatch, tune
+from repro.kernels import dispatch
 from repro.kernels.aggregate import ops as agg_ops
 from repro.kernels.aggregate import ref as agg_ref
 from repro.kernels.scan_aggregate import kernel as K
@@ -22,6 +22,7 @@ from repro.kernels.scan_aggregate import ref
 from repro.kernels.scan_filter import ops as scan_ops
 from repro.kernels.scan_filter.kernel import DEFAULT_BLOCK_ROWS, LANES
 from repro.kernels.scan_filter.ref import OPS
+from repro.obs import metrics as obs_metrics
 
 
 def scan_aggregate(pred_words, agg_words, valid_words, constant: int,
@@ -38,7 +39,7 @@ def scan_aggregate(pred_words, agg_words, valid_words, constant: int,
     if op not in OPS:
         raise ValueError(f"unknown predicate op {op!r}; expected one of {OPS}")
     r = dispatch.resolve(mode)
-    dispatch.count_launch("scan_aggregate")
+    obs_metrics.count_launch("scan_aggregate")
     if not r.use_pallas:
         return ref.scan_aggregate_ref(pred_words, agg_words, valid_words,
                                       constant, op, code_bits)
@@ -69,14 +70,7 @@ def scan_aggregate(pred_words, agg_words, valid_words, constant: int,
 
     p2d, a2d, v2d = to2d(pred_words), to2d(agg_words), to2d(valid_words)
     rows = p2d.shape[0]
-    br = block_rows
-    if br is None:
-        br = min(DEFAULT_BLOCK_ROWS, rows)
-        if r.tuned:
-            br = tune.best_params("scan_aggregate",
-                                  tune.shape_key(rows=rows, bits=code_bits),
-                                  {"block_rows": br})["block_rows"]
-            br = max(1, min(int(br), rows))
+    br = block_rows or min(DEFAULT_BLOCK_ROWS, rows)
     br = min(br, agg_ops.sum_bound_block_rows(code_bits))
     out = K.scan_aggregate_packed(p2d, a2d, v2d, constant=cc, op=prim,
                                   invert=inv, code_bits=code_bits,
@@ -97,7 +91,7 @@ def scan_aggregate_batched(pred3, agg3, valid3, triples, code_bits: int,
     Returns int32[n_chunks, 5]; each row is bit-identical to the
     per-chunk `scan_aggregate` composition for that chunk."""
     r = dispatch.resolve(mode)
-    dispatch.count_launch("scan_aggregate")
+    obs_metrics.count_launch("scan_aggregate")
     p = jnp.asarray(pred3, jnp.uint32)
     n_chunks = p.shape[0]
     if len(triples) != n_chunks:
@@ -117,14 +111,7 @@ def scan_aggregate_batched(pred3, agg3, valid3, triples, code_bits: int,
     a3 = agg_ops.to3d_words(agg3)
     v3 = agg_ops.to3d_words(valid3)
     rows = p3.shape[1]
-    br = block_rows
-    if br is None:
-        br = min(DEFAULT_BLOCK_ROWS, rows)
-        if r.tuned:
-            br = tune.best_params("scan_aggregate",
-                                  tune.shape_key(rows=rows, bits=code_bits),
-                                  {"block_rows": br})["block_rows"]
-            br = max(1, min(int(br), rows))
+    br = block_rows or min(DEFAULT_BLOCK_ROWS, rows)
     br = min(br, agg_ops.sum_bound_block_rows(code_bits))
     return K.scan_aggregate_batched_packed(
         jnp.asarray(consts), jnp.asarray(flags), p3, a3, v3,
@@ -152,5 +139,4 @@ def _example(rng):
 
 dispatch.register(
     "scan_aggregate", fn=scan_aggregate, ref=ref.scan_aggregate_ref,
-    tunables={"block_rows": (64, 256, 1024, 4096, 16384)},
     example=_example)

@@ -13,12 +13,13 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import dispatch, tune
+from repro.kernels import dispatch
 from repro.kernels.aggregate import ref as agg_ref
 from repro.kernels.scan_compressed import kernel as K
 from repro.kernels.scan_compressed import ref
 from repro.kernels.scan_filter.kernel import DEFAULT_BLOCK_ROWS, LANES
 from repro.kernels.scan_filter.ref import OPS
+from repro.obs import metrics as obs_metrics
 
 
 def rle_scan_aggregate(values, lengths, constant: int, op: str,
@@ -35,7 +36,7 @@ def rle_scan_aggregate(values, lengths, constant: int, op: str,
         raise ValueError(f"unknown predicate op {op!r}; expected one of "
                          f"{OPS}")
     r = dispatch.resolve(mode)
-    dispatch.count_launch("scan_compressed")
+    obs_metrics.count_launch("scan_compressed")
     if not r.use_pallas:
         return ref.rle_scan_aggregate_ref(values, lengths, constant, op,
                                           code_bits)
@@ -49,14 +50,7 @@ def rle_scan_aggregate(values, lengths, constant: int, op: str,
 
     v2d, l2d = to2d(v), to2d(l)
     rows = v2d.shape[0]
-    br = block_rows
-    if br is None:
-        br = min(DEFAULT_BLOCK_ROWS, rows)
-        if r.tuned:
-            br = tune.best_params("scan_compressed",
-                                  tune.shape_key(rows=rows, bits=code_bits),
-                                  {"block_rows": br})["block_rows"]
-            br = max(1, min(int(br), rows))
+    br = block_rows or min(DEFAULT_BLOCK_ROWS, rows)
     out = K.rle_scan_aggregate_packed(v2d, l2d, constant=int(constant),
                                       op=op, code_bits=code_bits,
                                       block_rows=br, interpret=r.interpret)
@@ -79,7 +73,7 @@ def rle_scan_aggregate_batched(planes, constant: int, op: str,
         raise ValueError(f"unknown predicate op {op!r}; expected one of "
                          f"{OPS}")
     r = dispatch.resolve(mode)
-    dispatch.count_launch("scan_compressed")
+    obs_metrics.count_launch("scan_compressed")
     n_chunks = len(planes)
     if n_chunks == 0:
         return jnp.zeros((0, 5), jnp.int32)
@@ -99,14 +93,7 @@ def rle_scan_aggregate_batched(planes, constant: int, op: str,
     if not r.use_pallas:
         return ref.rle_scan_aggregate_batched_ref(v3, l3, constant, op,
                                                   code_bits)
-    br = block_rows
-    if br is None:
-        br = min(DEFAULT_BLOCK_ROWS, rows)
-        if r.tuned:
-            br = tune.best_params("scan_compressed",
-                                  tune.shape_key(rows=rows, bits=code_bits),
-                                  {"block_rows": br})["block_rows"]
-            br = max(1, min(int(br), rows))
+    br = block_rows or min(DEFAULT_BLOCK_ROWS, rows)
     return K.rle_scan_aggregate_batched_packed(
         v3, l3, constant=int(constant), op=op, code_bits=code_bits,
         block_rows=br, interpret=r.interpret)
@@ -122,5 +109,4 @@ def _example(rng):
 dispatch.register(
     "scan_compressed", fn=rle_scan_aggregate,
     ref=ref.rle_scan_aggregate_ref,
-    tunables={"block_rows": (64, 256, 1024, 4096)},
     example=_example)

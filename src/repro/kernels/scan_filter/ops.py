@@ -7,7 +7,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import dispatch, tune
+from repro.kernels import dispatch
 from repro.kernels.scan_filter import kernel as K
 from repro.kernels.scan_filter import ref
 from repro.kernels.scan_filter.ref import OPS, field_masks
@@ -23,19 +23,8 @@ def _to_2d(words):
     return words.reshape(-1, K.LANES), n
 
 
-def _block_rows(rows: int, code_bits: int, tuned: bool) -> int:
-    default = min(K.DEFAULT_BLOCK_ROWS, rows)
-    if not tuned:
-        return default
-    got = tune.best_params("scan_filter",
-                           tune.shape_key(rows=rows, bits=code_bits),
-                           {"block_rows": default})["block_rows"]
-    return max(1, min(int(got), rows))
-
-
 def scan_filter(words, constant: int, op: str, code_bits: int,
-                block_rows: int | None = None, use_kernel: bool = True,
-                mode=None):
+                block_rows: int | None = None, mode=None):
     """words: (n_words,) uint32 packed codes -> (n_words,) packed mask.
 
     Composition rules (payload max = 2^(bits-1) - 1):
@@ -45,8 +34,8 @@ def scan_filter(words, constant: int, op: str, code_bits: int,
     if op not in OPS:
         raise ValueError(f"unknown predicate op {op!r}; expected one of "
                          f"{OPS}")
-    r = dispatch.resolve(mode, use_kernel=use_kernel)
-    dispatch.count_launch("scan_filter")
+    r = dispatch.resolve(mode)
+    obs_metrics.count_launch("scan_filter")
     if not r.use_pallas:
         return ref.scan_ref(words, constant, op, code_bits)
     if words.shape[0] == 0:           # zero-row grid is undefined
@@ -55,7 +44,7 @@ def scan_filter(words, constant: int, op: str, code_bits: int,
     delim, _, value = field_masks(code_bits)
     vmax = int(value)
     w2d, n = _to_2d(jnp.asarray(words, jnp.uint32))
-    br = block_rows or _block_rows(w2d.shape[0], code_bits, r.tuned)
+    br = block_rows or min(K.DEFAULT_BLOCK_ROWS, w2d.shape[0])
     run = lambda c, o: K.scan_packed(w2d, c, op=o, code_bits=code_bits,
                                      block_rows=br, interpret=r.interpret)
     dm = jnp.uint32(delim)
@@ -169,7 +158,7 @@ def scan_filter_batched(words3, triples, code_bits: int, mode=None):
     fused/aggregate stages that consume the mask.
     """
     dispatch.resolve(mode)            # validates the mode string
-    dispatch.count_launch("scan_filter")
+    obs_metrics.count_launch("scan_filter")
     return mask_batched(words3, triples, code_bits)
 
 
@@ -179,6 +168,4 @@ def _example(rng):
 
 
 dispatch.register(
-    "scan_filter", fn=scan_filter, ref=ref.scan_ref,
-    tunables={"block_rows": (64, 256, 1024, 4096, 16384, 65536)},
-    example=_example)
+    "scan_filter", fn=scan_filter, ref=ref.scan_ref, example=_example)

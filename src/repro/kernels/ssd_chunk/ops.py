@@ -10,7 +10,7 @@ from repro.kernels.ssd_chunk import kernel as K
 from repro.kernels.ssd_chunk import ref
 
 
-def ssd(x, dt, a_log, b, c, chunk: int, use_kernel: bool = True, mode=None):
+def ssd(x, dt, a_log, b, c, chunk: int, mode=None):
     """Model layout: x (B, S, H, P); dt (B, S, H) fp32 post-softplus;
     a_log (H,); b/c (B, S, N) (groups=1, broadcast over heads).
     Returns (y (B, S, H, P), final_state (B, H, N, P))."""
@@ -30,7 +30,7 @@ def ssd(x, dt, a_log, b, c, chunk: int, use_kernel: bool = True, mode=None):
             t = jnp.moveaxis(t, 2, 1)
         return t.reshape(bsz * h, nc, chunk, -1)
 
-    r = dispatch.resolve(mode, use_kernel=use_kernel)
+    r = dispatch.resolve(mode)
     if not r.use_pallas:
         ys, hs = [], []
         for bi in range(bsz):
@@ -66,8 +66,7 @@ def _example(rng):
 
 
 def _ssd_ref(x, dt, a_log, b, c, chunk, **kw):
-    return ssd(x, dt, a_log, b, c, chunk, use_kernel=False)
+    return ssd(x, dt, a_log, b, c, chunk, mode="xla_ref")
 
 
-dispatch.register("ssd_chunk", fn=ssd, ref=_ssd_ref, tunables={},
-                  example=_example)
+dispatch.register("ssd_chunk", fn=ssd, ref=_ssd_ref, example=_example)

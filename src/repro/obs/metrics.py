@@ -1,13 +1,11 @@
 """Scoped counter/gauge/histogram registry: one metrics namespace per scope.
 
-`kernels.dispatch` used to keep launch counters in module-global state, so
-two engines in one process polluted each other's counts and a test could
-only assert launches by resetting the world. This module replaces that
-with explicit `MetricsRegistry` scopes on a dynamic stack:
+Launch counters and other counters live in explicit `MetricsRegistry`
+scopes on a dynamic stack, so two engines in one process never pollute
+each other's counts:
 
 - the *default* registry sits at the bottom of the stack forever and
-  accumulates everything — `dispatch.launch_counts()` & friends are shims
-  over it, so every existing assert keeps its exact behavior;
+  accumulates everything (`default_registry()`: the process-wide view);
 - a `scoped(registry)` context pushes a second registry; increments land
   in **every** active scope, so an engine that wraps its execution in its
   own scope sees only its own launches while the global view still adds
@@ -116,13 +114,13 @@ class MetricsRegistry:
             h = self.histograms[name] = Histogram(name)
         return h
 
-    # --- kernel-launch accounting (the dispatch shims' substrate) ---------
+    # --- kernel-launch accounting -----------------------------------------
     def count_launch(self, family: str, n: int = 1) -> None:
         self.counter(_LAUNCH_PREFIX + family).inc(n)
 
     def launch_counts(self) -> dict[str, int]:
-        """Per-family launch counts — the exact dict the old module-global
-        `dispatch.launch_counts()` returned."""
+        """Per-family launch counts: {family: calls} for every family
+        with a nonzero count."""
         return {k[len(_LAUNCH_PREFIX):]: c.value
                 for k, c in self.counters.items()
                 if k.startswith(_LAUNCH_PREFIX) and c.value}

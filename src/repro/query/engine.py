@@ -96,7 +96,7 @@ class QueryEngine:
     axis the service charges advance.
     """
 
-    def __init__(self, table, *, mode=KernelMode.AUTO,
+    def __init__(self, table, *, mode=KernelMode.PALLAS,
                  clock=time.perf_counter, est_gbps: float = 1.0,
                  tiered=None, power_cap=None, chaos=None, prefetch=None,
                  tracer=None, metrics=None, monitor=None):
@@ -108,7 +108,7 @@ class QueryEngine:
         self.prefetch = prefetch
         # per-engine metrics scope: execution runs inside scoped(metrics),
         # so launch counts here are this engine's alone while the default
-        # (process-global) scope keeps accumulating for the legacy shims
+        # (process-global) scope keeps accumulating
         self.metrics = (metrics if metrics is not None
                         else obs_metrics.MetricsRegistry("engine"))
         # tiered: spans in modeled time; flat: host-clock spans

@@ -32,6 +32,7 @@ import numpy as np
 from repro.kernels.group_aggregate import ops as gops
 from repro.kernels.group_aggregate.ops import DENSE_MAX_GROUPS
 from repro.kernels.scan_filter import ref as packref
+from repro.obs import metrics as obs_metrics
 from repro.query import physical
 from repro.query.plan import And, GroupBy, HashJoin, Or, Pred, is_grouped
 
@@ -248,8 +249,7 @@ def execute_grouped(query, table, mode=None) -> dict:
     domain = group_domain(query, kmin, kmax)
     part = new_partial()
     if not dense_ok(domain):
-        from repro.kernels import dispatch
-        dispatch.count_launch("group_aggregate_fallback")
+        obs_metrics.count_launch("group_aggregate_fallback")
         cols = {name: np.asarray(p)[:n] for name, p in planes.items()}
         sel_np = np.asarray(sel)[:n]
         if isinstance(query, HashJoin):

@@ -418,7 +418,6 @@ class ShardedTable:
         planes all-gathered and merged in exact host ints. Group domains
         past the dense cutoff fall back to the host numpy path (counted
         as group_aggregate_fallback launches), still bit-exact."""
-        from repro.kernels import dispatch
         from repro.query import relational
         relational.bind_check(query, self.table.columns)
         if self.num_rows == 0:
@@ -428,8 +427,8 @@ class ShardedTable:
         if len(domain) == 0:
             return relational.empty_result()
         if not relational.dense_ok(domain):
-            dispatch.count_launch("group_aggregate_fallback",
-                                  self.n_shards)
+            obs_metrics.count_launch("group_aggregate_fallback",
+                                     self.n_shards)
             return relational.execute_grouped_oracle(query, self.table)
         stacked = self._dispatch_grouped(query.plan(), query.key,
                                          query.aggs, domain, mode)

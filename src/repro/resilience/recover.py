@@ -253,8 +253,8 @@ def execute_grouped_degraded(table, query, lost, mode=None
     construction. All shards lost raises DegradedResultError; domains
     past the dense cutoff recover via the host oracle (counted as
     group_aggregate_fallback launches)."""
-    from repro.kernels import dispatch
     from repro.kernels.scan_filter import ref as packref
+    from repro.obs import metrics as obs_metrics
     from repro.query import relational
     n = table.n_shards
     lost = sorted(set(int(i) for i in lost))
@@ -283,7 +283,7 @@ def execute_grouped_degraded(table, query, lost, mode=None
     if len(domain) == 0:
         return relational.empty_result(), recovered_bytes
     if not relational.dense_ok(domain):
-        dispatch.count_launch("group_aggregate_fallback", n)
+        obs_metrics.count_launch("group_aggregate_fallback", n)
         host = table.store.decode_table() if frames is not None \
             else table.table
         return (relational.execute_grouped_oracle(query, host),

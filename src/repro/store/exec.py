@@ -32,12 +32,12 @@ from dataclasses import dataclass
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import dispatch
 from repro.kernels.aggregate import ops as agg_ops
 from repro.kernels.scan_aggregate import ops as fused_ops
 from repro.kernels.scan_compressed import ops as rle_ops
 from repro.kernels.scan_filter import ops as scan_ops
 from repro.kernels.scan_filter.ref import codes_per_word, pack, valid_mask
+from repro.obs import metrics as obs_metrics
 from repro.query import physical
 from repro.query.physical import ColumnSlice
 from repro.query.plan import And, Or, Plan, Pred, columns_of
@@ -281,8 +281,8 @@ def _execute_batched(plan: Plan, aggregates, table: EncodedTable,
                   for ci in rle_cids]
         res = np.asarray(rle_ops.rle_scan_aggregate_batched(
             planes, plan.constant, plan.op, col.code_bits, mode=mode))
-        dispatch.record_batch("rle_scan_aggregate", col.code_bits,
-                              len(rle_cids))
+        obs_metrics.record_batch("rle_scan_aggregate", col.code_bits,
+                                 len(rle_cids))
         for k in range(len(rle_cids)):
             _accumulate(out[plan.column],
                         agg_ops.finalize(_row_dict(res[k])))
@@ -301,7 +301,7 @@ def _execute_batched(plan: Plan, aggregates, table: EncodedTable,
             res = np.asarray(fused_ops.scan_aggregate_batched(
                 bound[pcol].words, bound[acol].words, bound[pcol].valid,
                 triples, W, mode=mode))
-            dispatch.record_batch("scan_aggregate", W, len(cids))
+            obs_metrics.record_batch("scan_aggregate", W, len(cids))
             for k in range(len(cids)):
                 part = fixup_base(agg_ops.finalize(_row_dict(res[k])),
                                   bound[acol].bases[k],
@@ -309,12 +309,12 @@ def _execute_batched(plan: Plan, aggregates, table: EncodedTable,
                 _accumulate(out[acol], part)
             continue
         mask3 = _batched_mask(tplans, bound, W, mode)
-        dispatch.record_batch("scan_filter", W, len(cids))
+        obs_metrics.record_batch("scan_filter", W, len(cids))
         for acol in aggregates:
             g = bound[acol]
             res = np.asarray(agg_ops.aggregate_batched(g.words, mask3, W,
                                                        mode=mode))
-            dispatch.record_batch("aggregate", W, len(cids))
+            obs_metrics.record_batch("aggregate", W, len(cids))
             for k in range(len(cids)):
                 part = fixup_base(agg_ops.finalize(_row_dict(res[k])),
                                   g.bases[k],
@@ -445,8 +445,8 @@ def execute_grouped_encoded(query, table: EncodedTable, mode=None,
         pred = None if kp == ("ge", 0, False) else kp
         res = np.asarray(gops.rle_group_accumulate_batched(
             planes, domain, pred=pred, mode=mode))
-        dispatch.record_batch("rle_group_accumulate", kcol.code_bits,
-                              len(rle_cids))
+        obs_metrics.record_batch("rle_group_accumulate", kcol.code_bits,
+                                 len(rle_cids))
         # normalized [lo, hi, count] planes are additive in int64:
         # (sum hi << 16) + sum lo == sum((hi << 16) + lo), so all RLE
         # chunks (base 0, shared domain) absorb as one summed plane
@@ -478,7 +478,7 @@ def execute_grouped_encoded(query, table: EncodedTable, mode=None,
                      for k in range(len(dense_cids))])
             res = np.asarray(gops.group_sum_count_batched(
                 keys3, vals3, sel3, domain, mode=mode))
-            dispatch.record_batch("group_sum_count", len(domain),
+            obs_metrics.record_batch("group_sum_count", len(domain),
                                   len(dense_cids))
             for k in range(len(dense_cids)):
                 relational.absorb_plane(part, domain, res[k], name,
@@ -488,7 +488,7 @@ def execute_grouped_encoded(query, table: EncodedTable, mode=None,
     if fb_cids:
         bk = relational.build_keys(query) \
             if hasattr(query, "build") else None
-        dispatch.count_launch("group_aggregate_fallback", len(fb_cids))
+        obs_metrics.count_launch("group_aggregate_fallback", len(fb_cids))
         for ci in fb_cids:
             cols = {n: table.columns[n].chunks[ci].decode()
                     for n in names}

@@ -24,6 +24,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from repro.db.columnar import BitPackedColumn, Table
+from repro.obs import metrics as obs_metrics
 from repro.query.sharded import ShardedTable
 from repro.store.encode import EncodedTable, width_for_span
 from repro.store.exec import fixup_base, translate_plan
@@ -121,7 +122,6 @@ class ShardedEncodedTable:
         words directly. Host-side absorb restores logical keys
         (key_base=kbase) and value sums (sum += vbase * count), both
         exact, so the result is bit-identical to every other surface."""
-        from repro.kernels import dispatch
         from repro.query import relational
         relational.bind_check(query, self.columns)
         if self.num_rows == 0:
@@ -135,8 +135,8 @@ class ShardedEncodedTable:
         if len(domain) == 0:
             return relational.empty_result()
         if not relational.dense_ok(domain):
-            dispatch.count_launch("group_aggregate_fallback",
-                                  self.n_shards)
+            obs_metrics.count_launch("group_aggregate_fallback",
+                                     self.n_shards)
             return relational.execute_grouped_oracle(
                 query, self.store.decode_table())
         planes = self.inner.execute_grouped_planes(

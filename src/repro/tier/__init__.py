@@ -17,8 +17,8 @@ and admission feasibility uses the blended rate.
 from repro.tier.placement import Access, PlacementEngine, Policy
 from repro.tier.prefetch import PrefetchPipeline, PrefetchPlan
 from repro.tier.tiers import (TieredBudget, TierPair, TierSpec,
-                              measured_fast_gbps, paper_tiers,
-                              table1_bandwidth_ratio, tier_from_system)
+                              paper_tiers, table1_bandwidth_ratio,
+                              tier_from_system)
 from repro.tier.trace import (TracedQuery, TraceSpec, make_trace,
                               replay_trace, zipf_hit_curve, zipf_weights)
 
@@ -26,7 +26,7 @@ __all__ = [
     "Access", "PlacementEngine", "Policy",
     "PrefetchPipeline", "PrefetchPlan",
     "TierSpec", "TierPair", "TieredBudget", "paper_tiers",
-    "tier_from_system", "table1_bandwidth_ratio", "measured_fast_gbps",
+    "tier_from_system", "table1_bandwidth_ratio",
     "TraceSpec", "TracedQuery", "make_trace", "replay_trace",
     "zipf_weights", "zipf_hit_curve",
 ]

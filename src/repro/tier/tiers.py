@@ -143,10 +143,9 @@ def paper_tiers(fast_capacity: float, *, fast_gbps: float | None = None,
     """The paper's two-tier node: die-stacked fast tier (capacity capped
     at `fast_capacity` bytes) over a traditional DDR capacity tier.
 
-    With `fast_gbps` (e.g. from the autotuned kernel sweep,
-    `measured_fast_gbps`) the fast tier runs at the measured rate and the
-    capacity tier is derated by the Table 1 bandwidth ratio, so model and
-    measurement stay on one scale.
+    With `fast_gbps` the fast tier runs at that rate and the capacity
+    tier is derated by the Table 1 bandwidth ratio, so both tiers stay on
+    one scale.
     """
     if fast_capacity <= 0:
         raise ValueError(f"fast_capacity={fast_capacity} must be positive; "
@@ -158,42 +157,6 @@ def paper_tiers(fast_capacity: float, *, fast_gbps: float | None = None,
     cap_bw = fast.bandwidth / ratio
     cap = tier_from_system(capacity_system, bandwidth=cap_bw)
     return TierPair(fast=fast, capacity=cap)
-
-
-def measured_fast_gbps(default: float | None = None) -> float | None:
-    """Best attained scan rate in the autotune cache (repro.kernels.tune):
-    the fast tier priced from the measured sweep, not the datasheet.
-
-    Scans `scan_filter`/`scan_aggregate` entries for the current backend;
-    bytes per call are recovered from the `rows=` shape key (rows of
-    (rows, LANES) uint32 word planes) times the number of input planes the
-    op streams — scan_filter reads one packed array, the fused
-    scan_aggregate reads three (pred, agg, valid) — so the two ops'
-    attained GB/s are commensurate. Returns `default` when nothing has
-    been tuned yet.
-    """
-    import jax
-
-    from repro.kernels import tune
-    from repro.kernels.scan_filter.kernel import LANES
-
-    streamed_planes = {"scan_filter": 1, "scan_aggregate": 3}
-    backend = jax.default_backend()
-    best = None
-    for key, entry in tune.get_cache().entries().items():
-        parts = key.split("|")
-        if len(parts) != 3 or parts[1] != backend:
-            continue
-        if parts[0] not in streamed_planes:
-            continue
-        dims = dict(kv.split("=") for kv in parts[2].split(","))
-        us = entry.get("us")
-        if "rows" not in dims or not us:
-            continue
-        nbytes = streamed_planes[parts[0]] * int(dims["rows"]) * LANES * 4
-        gbps = nbytes / (us * 1e-6) / 1e9
-        best = gbps if best is None else max(best, gbps)
-    return best if best is not None else default
 
 
 class TieredBudget:

@@ -214,8 +214,8 @@ def check_sharded_query_engine():
         Query(Pred("x", "eq", 3) | Pred("w", "lt", 500),        # mixed OR
               aggregates=("a",)),
     ]
-    single = QueryEngine(table, mode="auto")
-    sharded = QueryEngine(st, mode="auto")
+    single = QueryEngine(table, mode="pallas")
+    sharded = QueryEngine(st, mode="pallas")
     for q in queries:
         single.submit(q)
         sharded.submit(q)
@@ -266,8 +266,8 @@ def check_compressed_store():
         Query(Pred("f", "lt", 40), aggregates=("f",)),       # empty
         Query(Pred("w", "ge", 0), aggregates=("w",)),        # all-match
     ]
-    single = QueryEngine(table, mode="auto")
-    sharded = QueryEngine(st, mode="auto")
+    single = QueryEngine(table, mode="pallas")
+    sharded = QueryEngine(st, mode="pallas")
     for q in queries:
         single.submit(q)
         sharded.submit(q)
@@ -326,7 +326,7 @@ def check_resilience():
         clock = VirtualClock()
         pe = PlacementEngine.for_table(st, paper_tiers(st.nbytes // 2),
                                        Policy.CACHE, chunk_rows=4096)
-        eng = QueryEngine(st, mode="auto", clock=clock, tiered=pe,
+        eng = QueryEngine(st, mode="pallas", clock=clock, tiered=pe,
                           chaos=ChaosHarness(
                               FaultSpec(seed=5, shard_loss_rate=0.5)))
         want = st.execute(queries[0].plan(), queries[0].aggregates)

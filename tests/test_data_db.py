@@ -80,7 +80,7 @@ class TestQueries:
         assert n == self.t.columns["a"].nbytes + self.t.columns["b"].nbytes
 
     def test_kernel_and_ref_paths_agree(self):
-        for mode in ("pallas", "xla_ref", "auto"):
+        for mode in ("pallas", "xla_ref"):
             r = scan_aggregate_query(self.t, [Predicate("a", "ge", 64)],
                                      "a", mode=mode)
             sel = self.av >= 64

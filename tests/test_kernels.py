@@ -153,6 +153,12 @@ def test_flash_sliding_window(window):
                                rtol=2e-5, atol=2e-5)
 
 
+def test_fit_clamps_to_divisor():
+    assert flash_ops.fit(1024, 4096) == 1024
+    assert flash_ops.fit(96, 64) == 48
+    assert flash_ops.fit(7, 4) == 1
+
+
 def test_flash_block_shape_independence():
     """Different BlockSpec tilings must give the same answer."""
     key = jax.random.PRNGKey(2)

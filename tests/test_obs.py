@@ -6,8 +6,8 @@ Pins down PR 9's contracts:
   encoded (compressed store), sharded, grouped, prefetch on, chaos on —
   and *fails* on a deliberately double-charged synthetic ledger;
 - a seeded chaos replay exports byte-identical Chrome trace JSON twice;
-- the launch-counter migration: dispatch shims read the default scope
-  unchanged, two engines' scoped registries don't pollute each other;
+- launch counters: the default scope sees every launch, two engines'
+  scoped registries don't pollute each other;
 - the unified snapshot's canonical byte keys agree with both
   PlacementEngine totals and PrefetchPipeline.stats() (the
   overlapping-key normalization regression test);
@@ -24,12 +24,12 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from repro.db import Table
-from repro.kernels import dispatch
 from repro.launch.mesh import make_mesh
 from repro.obs import (ConservationError, MetricsRegistry, NullTracer,
                        Tracer, audit, check, chrome_trace,
                        chrome_trace_json, scoped, unified_snapshot,
                        waterfall)
+from repro.obs import metrics as obs_metrics
 from repro.obs.trace import NULL_TRACE
 from repro.query import Query, QueryEngine, ShardedTable
 from repro.query.plan import GroupBy, Pred
@@ -386,31 +386,33 @@ def test_tiered_tracer_records_no_host_spans():
 
 
 # --------------------------------------------------------------------------
-# scoped metrics + dispatch shims (the launch-counter migration)
+# scoped metrics: launch counters in the default and engine scopes
 # --------------------------------------------------------------------------
 
 def test_dispatch_shims_default_scope():
-    dispatch.reset_launch_counts()
-    dispatch.count_launch("fam_a", 2)
-    dispatch.count_launch("fam_b")
-    assert dispatch.launch_counts() == {"fam_a": 2, "fam_b": 1}
-    assert dispatch.total_launches() == 3
-    dispatch.reset_launch_counts()
-    assert dispatch.launch_counts() == {}
+    default = obs_metrics.default_registry()
+    default.reset_launches()
+    obs_metrics.count_launch("fam_a", 2)
+    obs_metrics.count_launch("fam_b")
+    assert default.launch_counts() == {"fam_a": 2, "fam_b": 1}
+    assert default.total_launches() == 3
+    default.reset_launches()
+    assert default.launch_counts() == {}
 
 
 def test_scoped_isolation_between_engines():
-    dispatch.reset_launch_counts()
+    default = obs_metrics.default_registry()
+    default.reset_launches()
     r1, r2 = MetricsRegistry("e1"), MetricsRegistry("e2")
     with scoped(r1):
-        dispatch.count_launch("fam", 3)
+        obs_metrics.count_launch("fam", 3)
     with scoped(r2):
-        dispatch.count_launch("fam", 5)
+        obs_metrics.count_launch("fam", 5)
     assert r1.launch_counts() == {"fam": 3}
     assert r2.launch_counts() == {"fam": 5}
-    # the default scope (the legacy shims) still sees the global view
-    assert dispatch.launch_counts() == {"fam": 8}
-    dispatch.reset_launch_counts()
+    # the default scope still sees the global view
+    assert default.launch_counts() == {"fam": 8}
+    default.reset_launches()
     # resetting the default does not clear engine scopes
     assert r1.launch_counts() == {"fam": 3}
 
@@ -500,17 +502,17 @@ def test_check_regress_gate(tmp_path, monkeypatch):
                                     "benchmarks"))
     import check_regress
     monkeypatch.setattr(check_regress, "ROOT", tmp_path)
-    path = tmp_path / "BENCH_kernels.json"
+    path = tmp_path / "BENCH_queries.json"
     path.write_text(json.dumps(
-        [{"tuned_gbps": v} for v in (10.0, 11.0, 10.5, 10.8)]))
-    ok, msg = check_regress.check_bench("kernels")
+        [{"scan_agg_gbps": v} for v in (10.0, 11.0, 10.5, 10.8)]))
+    ok, msg = check_regress.check_bench("queries")
     assert ok, msg
     # >30% drop from the median trips the gate
     path.write_text(json.dumps(
-        [{"tuned_gbps": v} for v in (10.0, 11.0, 10.5, 6.0)]))
-    ok, msg = check_regress.check_bench("kernels")
+        [{"scan_agg_gbps": v} for v in (10.0, 11.0, 10.5, 6.0)]))
+    ok, msg = check_regress.check_bench("queries")
     assert not ok and "REGRESSION" in msg
-    assert check_regress.main(["kernels"]) == 1
+    assert check_regress.main(["queries"]) == 1
     # a missing file is a skip, not a failure
     ok, msg = check_regress.check_bench("store")
     assert ok and "SKIP" in msg

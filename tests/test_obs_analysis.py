@@ -449,28 +449,28 @@ def test_check_regress_explains_category(tmp_path, monkeypatch):
                                     "benchmarks"))
     import check_regress
     monkeypatch.setattr(check_regress, "ROOT", tmp_path)
-    path = tmp_path / "BENCH_kernels.json"
+    path = tmp_path / "BENCH_queries.json"
     path.write_text(json.dumps([
-        {"tuned_gbps": 10.0},
-        {"tuned_gbps": 10.5,
+        {"scan_agg_gbps": 10.0},
+        {"scan_agg_gbps": 10.5,
          "obs": _obs({"scan/capacity_read": 0.4, "scan/fast_read": 0.1},
                      snapshot={"tier.hit_rate": 0.9})},
-        {"tuned_gbps": 6.0,
+        {"scan_agg_gbps": 6.0,
          "obs": _obs({"scan/capacity_read": 1.6, "scan/fast_read": 0.1},
                      snapshot={"tier.hit_rate": 0.4})},
     ]))
-    ok, msg = check_regress.check_bench("kernels")
+    ok, msg = check_regress.check_bench("queries")
     assert not ok and "REGRESSION" in msg
     assert ("dominant regressing span category: scan/capacity_read"
             in msg)
     assert "tier.hit_rate" in msg            # snapshot deltas rendered
     # --explain mode produces the JSON artifact without gating
     out = tmp_path / "diff.json"
-    assert check_regress.main(["kernels", "--explain",
+    assert check_regress.main(["queries", "--explain",
                                "--out", str(out)]) == 0
     payloads = json.loads(out.read_text())
     assert payloads[0]["dominant"] == "scan/capacity_read"
-    assert payloads[0]["bench"] == "kernels"
+    assert payloads[0]["bench"] == "queries"
 
 
 def test_check_regress_without_digest(tmp_path, monkeypatch):
@@ -478,13 +478,13 @@ def test_check_regress_without_digest(tmp_path, monkeypatch):
                                     "benchmarks"))
     import check_regress
     monkeypatch.setattr(check_regress, "ROOT", tmp_path)
-    path = tmp_path / "BENCH_kernels.json"
-    path.write_text(json.dumps([{"tuned_gbps": 10.0},
-                                {"tuned_gbps": 10.5},
-                                {"tuned_gbps": 4.0}]))
-    ok, msg = check_regress.check_bench("kernels")
+    path = tmp_path / "BENCH_queries.json"
+    path.write_text(json.dumps([{"scan_agg_gbps": 10.0},
+                                {"scan_agg_gbps": 10.5},
+                                {"scan_agg_gbps": 4.0}]))
+    ok, msg = check_regress.check_bench("queries")
     assert not ok and "no obs digest" in msg
-    msg, payload = check_regress.explain_bench("kernels")
+    msg, payload = check_regress.explain_bench("queries")
     assert payload is None and "SKIP" in msg
 
 

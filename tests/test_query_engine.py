@@ -18,7 +18,7 @@ from repro.query import And, Or, Pred, Query, QueryEngine, ShardedTable
 from repro.query.plan import normalize
 from repro.query.sharded import shard_rows
 
-MODES = ("pallas", "xla_ref", "auto")
+MODES = ("pallas", "xla_ref")
 
 # 10_001 rows: not a multiple of any codes-per-word, so every column carries
 # tail padding — the validity masks must cancel it under every plan shape
@@ -124,6 +124,24 @@ def test_empty_table_returns_identity():
         assert res.count == 0 and res.selectivity == 0
 
 
+def test_default_mode_engine_answers_like_the_reference():
+    """An engine built with no mode runs PALLAS, the mode every benchmark
+    cell runs, and answers a Q6-shaped and a Q1-shaped query over a small
+    placed lineitem exactly as the benchmark's numpy reference does."""
+    from chipbench import queries, reference, run
+    from repro.kernels.dispatch import KernelMode
+    _, _, config, q1 = run.load_cell("q1_power")
+    q6 = queries.load_traffic("q6_power")
+    codes, st = run.place(dict(config, rows=20_000), 7, 1)
+    eng = QueryEngine(st)
+    assert eng.mode is KernelMode.PALLAS
+    for template in (q6["queries"][0], q1["queries"][0]):
+        eng.submit(queries.build(template))
+        out = eng.run()
+        assert len(out) == 1 and not out[0].degraded
+        assert out[0].aggregates == reference.answer(template, codes)
+
+
 def test_engine_sum_exact_beyond_int32():
     """A 16-bit column over a few hundred k rows sums past 2^31: the
     engine must report the exact value, single-device and sharded."""
@@ -132,7 +150,7 @@ def test_engine_sum_exact_beyond_int32():
     assert want > 2**31
     q = Query(Pred("p", "ge", 0), aggregates=("p",))
     for tbl in (t, ShardedTable.shard(t, make_mesh((1,), ("data",)))):
-        eng = QueryEngine(tbl, mode="auto")
+        eng = QueryEngine(tbl)
         eng.submit(q)
         res = eng.run()[0]
         assert res.aggregates["p"]["sum"] == want

@@ -12,8 +12,8 @@ import numpy as np
 import pytest
 
 from repro.db.columnar import BitPackedColumn, Table
-from repro.kernels import dispatch
 from repro.kernels.group_aggregate import ops as gops
+from repro.obs.metrics import default_registry
 from repro.query import GroupBy, HashJoin, Pred, QueryEngine
 from repro.query import relational
 from repro.query.plan import And
@@ -23,7 +23,7 @@ from repro.store.exec import execute_grouped_encoded
 from repro.tier.placement import PlacementEngine, Policy
 from repro.tier.tiers import paper_tiers
 
-MODES = ("pallas", "xla_ref", "auto")
+MODES = ("pallas", "xla_ref")
 N_ROWS = 6001          # ragged vs every codes-per-word and the chunking
 CHUNK_ROWS = 1024
 
@@ -180,12 +180,12 @@ def test_wide_key_takes_fallback_and_matches(table):
     saved = relational.DENSE_MAX_GROUPS, gops.DENSE_MAX_GROUPS
     try:
         relational.DENSE_MAX_GROUPS = gops.DENSE_MAX_GROUPS = 4
-        before = dict(dispatch.launch_counts())
+        before = dict(default_registry().launch_counts())
         got = relational.execute_grouped(q, table)
     finally:
         relational.DENSE_MAX_GROUPS, gops.DENSE_MAX_GROUPS = saved
     delta = {k: v - before.get(k, 0)
-             for k, v in dispatch.launch_counts().items()}
+             for k, v in default_registry().launch_counts().items()}
     assert delta.get("group_aggregate_fallback", 0) >= 1
     assert delta.get("group_aggregate", 0) == 0
     assert got == want == relational.execute_grouped(q, table)
@@ -211,10 +211,10 @@ def test_rle_pregrouped_is_one_launch_no_scatter(table, encoded):
     plane, no host fallback."""
     q = GroupBy("r", where=Pred("r", "lt", 6))
     execute_grouped_encoded(q, encoded, mode="xla_ref")     # warm
-    before = dict(dispatch.launch_counts())
+    before = dict(default_registry().launch_counts())
     got = execute_grouped_encoded(q, encoded, mode="xla_ref")
     delta = {k: v - before.get(k, 0)
-             for k, v in dispatch.launch_counts().items()
+             for k, v in default_registry().launch_counts().items()
              if v != before.get(k, 0)}
     assert delta == {"group_aggregate_rle": 1}, delta
     assert got == relational.execute_grouped_oracle(q, table)
@@ -226,13 +226,13 @@ def test_encoded_forced_fallback_parity(table, encoded):
     saved = relational.DENSE_MAX_GROUPS, gops.DENSE_MAX_GROUPS
     try:
         relational.DENSE_MAX_GROUPS = gops.DENSE_MAX_GROUPS = 0
-        before = dict(dispatch.launch_counts())
+        before = dict(default_registry().launch_counts())
         got = execute_grouped_encoded(q, encoded, mode="xla_ref")
     finally:
         relational.DENSE_MAX_GROUPS, gops.DENSE_MAX_GROUPS = saved
     assert got == want
     delta = {k: v - before.get(k, 0)
-             for k, v in dispatch.launch_counts().items()}
+             for k, v in default_registry().launch_counts().items()}
     assert delta.get("group_aggregate_fallback", 0) == encoded.n_chunks
 
 

@@ -16,12 +16,13 @@ from repro.kernels.aggregate import ops as agg_ops
 from repro.kernels.scan_compressed import ops as rle_ops
 from repro.kernels.scan_compressed import ref as rle_ref
 from repro.launch.mesh import make_mesh
+from repro.obs.metrics import default_registry
 from repro.query import And, Or, Pred, Query, QueryEngine
 from repro.store import (EncodedTable, Encoding, ShardedEncodedTable,
                          encode_chunk, execute_encoded)
 from repro.store.exec import fixup_base, identity_ints, translate_pred
 
-MODES = ("pallas", "xla_ref", "auto")
+MODES = ("pallas", "xla_ref")
 
 # 6001 rows: not a multiple of any codes-per-word or the chunking, so
 # every column carries tail padding in its last chunk
@@ -510,34 +511,32 @@ class TestValidationMessages:
 
 class TestBatchedLaunches:
     """The tentpole's observable: all same-encoding chunks of a column
-    group execute as ONE kernel launch, counted in kernels.dispatch."""
+    group execute as ONE kernel launch, counted in obs.metrics."""
 
     def test_one_launch_per_group_not_per_chunk(self, encoded):
-        from repro.kernels import dispatch
-
+        default = default_registry()
         plan, aggs = Pred("f", "ge", 42), ("u",)
-        dispatch.reset_launch_counts()
+        default.reset_launches()
         execute_encoded(plan, aggs, encoded, mode="xla_ref", batched=False)
-        per_chunk = dispatch.total_launches()
-        dispatch.reset_launch_counts()
+        per_chunk = default.total_launches()
+        default.reset_launches()
         execute_encoded(plan, aggs, encoded, mode="xla_ref", batched=True)
-        batched = dispatch.total_launches()
+        batched = default.total_launches()
         assert per_chunk >= encoded.n_chunks       # the old loop: >= 1/chunk
         assert batched < encoded.n_chunks          # batched: 1 per group
         # fused single-pred/single-agg over one width group -> exactly 1
-        assert dispatch.launch_counts().get("scan_aggregate") == 1
+        assert default.launch_counts().get("scan_aggregate") == 1
 
     def test_rle_chunks_batch_into_one_launch(self, encoded):
-        from repro.kernels import dispatch
-
-        dispatch.reset_launch_counts()
+        default = default_registry()
+        default.reset_launches()
         execute_encoded(Pred("r", "lt", 3), ("r",), encoded,
                         mode="xla_ref", batched=True)
-        assert dispatch.launch_counts().get("scan_compressed") == 1
-        dispatch.reset_launch_counts()
+        assert default.launch_counts().get("scan_compressed") == 1
+        default.reset_launches()
         execute_encoded(Pred("r", "lt", 3), ("r",), encoded,
                         mode="xla_ref", batched=False)
-        assert dispatch.launch_counts().get("scan_compressed") == \
+        assert default.launch_counts().get("scan_compressed") == \
             encoded.n_chunks
 
     @pytest.mark.parametrize("batched", (True, False))

@@ -23,13 +23,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from repro.core.advisor import advise_tier_split
 from repro.core.systems import DIE_STACKED, TRADITIONAL
 from repro.db import Table
-from repro.kernels import tune
 from repro.query import Pred, Query, QueryEngine
 from repro.serve.sla import VirtualClock, blended_bps
 from repro.tier import (PlacementEngine, Policy, TieredBudget, TraceSpec,
-                        make_trace, measured_fast_gbps, paper_tiers,
-                        table1_bandwidth_ratio, tier_from_system,
-                        zipf_hit_curve, zipf_weights)
+                        make_trace, paper_tiers, table1_bandwidth_ratio,
+                        tier_from_system, zipf_hit_curve, zipf_weights)
 
 N_COLS, N_ROWS = 16, 4096
 FAST_FRACTION = 0.25
@@ -126,21 +124,6 @@ class TestTiers:
     def test_blended_bps_guards_rates(self):
         with pytest.raises(ValueError, match="positive"):
             blended_bps(0.0, 4e9, 0.5)
-
-    def test_measured_fast_gbps_reads_autotune_sweep(self, tmp_path):
-        try:
-            cache = tune.set_cache_path(tmp_path / "tune.json")
-            assert measured_fast_gbps(default=7.5) == 7.5  # empty cache
-            cache.store("scan_filter", "bits=8,rows=1024", {"us": 100.0})
-            want = 1024 * 128 * 4 / 100e-6 / 1e9
-            assert measured_fast_gbps() == pytest.approx(want)
-            # the fused op streams three word planes (pred, agg, valid),
-            # so the same us over the same rows is a 3x higher rate
-            cache.store("scan_aggregate", "bits=8,rows=1024",
-                        {"us": 100.0})
-            assert measured_fast_gbps() == pytest.approx(3 * want)
-        finally:
-            tune.set_cache_path(None)
 
 
 # --------------------------------------------------------------------------
