@@ -1,17 +1,22 @@
-"""Fused BitWeaving scan + masked aggregate Pallas TPU kernel.
+"""Fused BitWeaving scan + masked aggregate Pallas TPU kernels.
 
-"Processing Data Where It Makes Sense" applied inside one chip: the scan's
-packed predicate mask never round-trips through HBM. Per grid step a
-(block_rows, 128) tile of the predicate column is compared against the
-constant with the scan kernel's VPU bit-tricks (GE/EQ primitives, optional
-complement for the composed lt/le/ne forms), ANDed with the validity mask
-(tail/shard padding rows carry zero delimiter bits), and immediately
-reduced against the aggregate column's tile into SMEM scalar accumulators
-(the layout helpers are shared with aggregate/kernel.py).
+"Processing Data Where It Makes Sense" applied inside one chip: predicate
+masks never round-trip through HBM. Per grid step the kernels compare
+(rows, 128) word tiles of the predicate columns against their constants
+with the scan kernel's VPU bit-tricks (GE/EQ primitives, optional
+complement for the composed lt/le/ne forms), AND the results with the
+validity mask in VREGs (tail/shard padding rows carry zero delimiter
+bits), and reduce the aggregate columns' tiles under that mask into SMEM
+scalar accumulators (the layout helpers are shared with
+aggregate/kernel.py).
 
-Streams 3 inputs and writes 4 scalars, vs 4 streamed tiles + a full mask
-write for the scan->aggregate pipeline — at the paper's ~1 B/instr scan
-regime that is a 40% traffic cut for the dominant single-predicate query.
+- `scan_conjunction_packed`: one pass over one plane per column for a
+  conjunction of leaf predicates and any number of aggregate columns at
+  one layout — TPC-H Q6 reads its three columns and one validity plane
+  once, where the mask path writes and re-reads five masks.
+- `scan_aggregate_batched_packed`: one (predicate, aggregate) column pair
+  over many chunks, the predicate per chunk carried as data (the encoded
+  store's batched launch).
 """
 from __future__ import annotations
 
@@ -104,27 +109,112 @@ def scan_aggregate_batched_packed(consts, flags, pred3d, agg3d, valid3d, *,
     )(consts, flags, pred3d, agg3d, valid3d))
 
 
+# most (tile_rows, 128) reduction tiles one grid step of the conjunction
+# kernel reads per plane, and the VMEM its double-buffered blocks may take
+CONJUNCTION_MAX_TILES = 4
+CONJUNCTION_VMEM_BYTES = 12 << 20
+
+
+def _conjunction_kernel(*refs, leaves, aggs, tile_rows: int, delim, low,
+                        code_bits: int, vmax: int):
+    """Grid (steps,): refs are the column blocks, the validity block, one
+    output tile per aggregate, then one SMEM accumulator per aggregate.
+    Each (tile_rows, 128) tile of a step is masked and reduced on its
+    own, so every tile partial stays int32-exact whatever the block."""
+    n_cols = len(refs) - 1 - 2 * len(aggs)
+    cols, v_ref = refs[:n_cols], refs[n_cols]
+    outs = refs[n_cols + 1:n_cols + 1 + len(aggs)]
+    accs = refs[n_cols + 1 + len(aggs):]
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        for acc in accs:
+            init_acc(acc, vmax)
+
+    h = jnp.uint32(delim)
+    for r0 in range(0, v_ref.shape[0], tile_rows):
+        rows = pl.ds(r0, tile_rows)
+        tiles = {}
+
+        def tile(c):
+            if c not in tiles:
+                tiles[c] = cols[c][rows, :]
+            return tiles[c]
+
+        m = v_ref[rows, :]
+        for c, prim, cst, inv in leaves:
+            x = tile(c)
+            if prim == "ge":
+                lm = ((x | h) - jnp.uint32(cst)) & h
+            else:
+                lm = ~(((x ^ jnp.uint32(cst)) | h) - jnp.uint32(low)) & h
+            # m holds delimiter bits only, so m & ~lm is the complement
+            m = m & ~lm if inv else m & lm
+        for acc, c in zip(accs, aggs):
+            fold_acc(acc, *field_reduce(tile(c), m, code_bits=code_bits,
+                                        vmax=vmax))
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        for o_ref, acc in zip(outs, accs):
+            write_row(o_ref, acc)
+
+
+def _tiles_per_step(n_tiles: int, n_planes: int, tile_rows: int) -> int:
+    """Reduction tiles per grid step: the largest count up to
+    CONJUNCTION_MAX_TILES whose double-buffered blocks of `n_planes`
+    planes fit CONJUNCTION_VMEM_BYTES and which divides `n_tiles`, so the
+    grid covers the planes exactly (no ragged last block)."""
+    cap = CONJUNCTION_VMEM_BYTES // (2 * n_planes * tile_rows * LANES * 4)
+    k = max(1, min(CONJUNCTION_MAX_TILES, cap, n_tiles))
+    while n_tiles % k:
+        k -= 1
+    return k
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("constant", "op", "invert", "code_bits",
-                                    "block_rows", "interpret"))
-def scan_aggregate_packed(pred2d, agg2d, valid2d, *, constant: int, op: str,
-                          invert: bool, code_bits: int,
-                          block_rows: int = DEFAULT_BLOCK_ROWS,
-                          interpret: bool = True):
-    """(rows, 128) packed predicate/aggregate/validity words -> int32[1, 5]
-    = [sum_lo, sum_hi, count, min, max] (sum = sum_hi * 65536 + sum_lo):
-    the batched kernel over one chunk. `op` is a kernel primitive
-    (ge | eq); the six public predicates are composed in ops.py via
-    (op, constant, invert)."""
-    if op not in ("ge", "eq"):
-        raise ValueError(op)
-    vmax = (1 << (code_bits - 1)) - 1
-    const_packed = 0
-    for f in range(32 // code_bits):
-        const_packed |= (int(constant) & vmax) << (f * code_bits)
-    flags = (1 if op == "eq" else 0) | (2 if invert else 0)
-    return scan_aggregate_batched_packed(
-        jnp.full((1,), const_packed, jnp.int32),
-        jnp.full((1,), flags, jnp.int32),
-        pred2d[None], agg2d[None], valid2d[None], code_bits=code_bits,
-        block_rows=block_rows, interpret=interpret)
+                   static_argnames=("leaves", "aggs", "code_bits",
+                                    "tile_rows", "interpret"))
+def scan_conjunction_packed(cols2d, valid2d, *, leaves, aggs,
+                            code_bits: int,
+                            tile_rows: int = DEFAULT_BLOCK_ROWS,
+                            interpret: bool = True):
+    """SELECT agg(c) for c in aggs WHERE leaf AND leaf AND ... in ONE
+    pass over the planes.
+
+    cols2d: a tuple of (rows, 128) packed word planes at one code width;
+    valid2d: their (shared) validity plane. leaves: ((column index,
+    prim, constant, invert), ...) with prim a kernel primitive (ge | eq)
+    and constant in [0, vmax]; no leaves selects every valid row.
+    aggs: column indices to aggregate. Returns int32[len(aggs), 5] of
+    [sum_lo, sum_hi, count, min, max] rows. `tile_rows` bounds the
+    reduction tile (ops.py keeps it within the sum bound); a grid step
+    reads up to CONJUNCTION_MAX_TILES tiles of every plane. Rows are
+    zero-padded to a whole tile; padded validity words select nothing."""
+    planes, rows, tile_rows = pad_rows(
+        [p[None] for p in (*cols2d, valid2d)], tile_rows)
+    planes = [p[0] for p in planes]
+    block_rows = tile_rows * _tiles_per_step(
+        rows // tile_rows, len(planes), tile_rows)
+    delim, low, value = field_masks(code_bits)
+    n_fields = 32 // code_bits
+    packed = tuple(
+        (c, prim, sum((cst & int(value)) << (f * code_bits)
+                      for f in range(n_fields)), inv)
+        for c, prim, cst, inv in leaves)
+    kernel = functools.partial(
+        _conjunction_kernel, leaves=packed, aggs=aggs, tile_rows=tile_rows,
+        delim=int(delim), low=int(low), code_bits=code_bits,
+        vmax=int(value))
+    spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
+    outs = pl.pallas_call(
+        kernel,
+        grid=(rows // block_rows,),
+        in_specs=[spec] * len(planes),
+        out_specs=[out_spec(lambda i: (0, 0, 0))] * len(aggs),
+        out_shape=[out_shape(1)] * len(aggs),
+        scratch_shapes=[acc_scratch()] * len(aggs),
+        interpret=interpret,
+    )(*planes)
+    return jnp.concatenate([rows_of(o) for o in outs])

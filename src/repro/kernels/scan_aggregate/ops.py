@@ -2,10 +2,11 @@
 repro.kernels.dispatch.
 
 The full predicate set {lt, le, gt, ge, eq, ne} is composed from the
-kernel's {ge, eq} primitives plus an in-kernel complement, mirroring
-scan_filter's composition rules; the two degenerate compositions (gt at the
-payload max, le at/above it) short-circuit to the empty-selection identity
-and a plain validity-mask aggregate respectively.
+kernel's {ge, eq} primitives plus an in-kernel complement, by
+scan_filter's composition rules (`canonical_pred`); a leaf that selects
+nothing (gt at the payload max) makes the answer the empty-selection
+identity, and one that selects every valid row (le at or above it) drops
+out of the conjunction.
 """
 from __future__ import annotations
 
@@ -20,63 +21,58 @@ from repro.kernels.aggregate import ref as agg_ref
 from repro.kernels.scan_aggregate import kernel as K
 from repro.kernels.scan_aggregate import ref
 from repro.kernels.scan_filter import ops as scan_ops
-from repro.kernels.scan_filter.kernel import DEFAULT_BLOCK_ROWS, LANES
-from repro.kernels.scan_filter.ref import OPS
+from repro.kernels.scan_filter.kernel import DEFAULT_BLOCK_ROWS
 from repro.obs import metrics as obs_metrics
 
 
-def scan_aggregate(pred_words, agg_words, valid_words, constant: int,
-                   op: str, code_bits: int, block_rows: int | None = None,
-                   mode=None) -> dict:
-    """Fused SELECT agg(agg_col) WHERE pred_col <op> constant over packed
-    words of one shared code width ->
-    dict(sum_lo, sum_hi, count, min, max); reassemble the exact sum with
-    repro.kernels.aggregate.ops.finalize.
+def scan_conjunction(words: dict, valid_words, preds, aggregates,
+                     code_bits: int, mode=None) -> dict:
+    """Fused SELECT agg(a) for a in aggregates WHERE p1 AND p2 AND ... in
+    one launch -> {a: dict(sum_lo, sum_hi, count, min, max)}; reassemble
+    each exact sum with repro.kernels.aggregate.ops.finalize.
 
-    valid_words is the packed delimiter-bit validity mask (bits set only
-    for real rows); it cancels tail-of-word and shard padding.
+    words: {column: (n_words,) packed words}, every column at one code
+    width and word count; preds: ((column, op, constant), ...) leaves of
+    the conjunction; valid_words: the packed delimiter-bit validity mask
+    the columns share (bits set only for real rows), which cancels
+    tail-of-word and shard padding.
     """
-    if op not in OPS:
-        raise ValueError(f"unknown predicate op {op!r}; expected one of {OPS}")
+    # canonical_pred refuses an unknown op in either mode
+    leaves = [(col, *scan_ops.canonical_pred(op, c, code_bits))
+              for col, op, c in preds]
     r = dispatch.resolve(mode)
     obs_metrics.count_launch("scan_aggregate")
+    aggregates = tuple(dict.fromkeys(aggregates))
     if not r.use_pallas:
-        return ref.scan_aggregate_ref(pred_words, agg_words, valid_words,
-                                      constant, op, code_bits)
-    if pred_words.size == 0:          # zero-row grid is undefined
-        return agg_ref.identity(code_bits)
+        return ref.scan_conjunction_ref(words, valid_words, preds,
+                                        aggregates, code_bits)
+    # degenerate leaves fold in here: one that selects nothing makes the
+    # answer the empty-selection identity, one that selects every valid
+    # row drops out (and its column is not read)
+    if (any(leaf[1:] == scan_ops.SELECT_NONE for leaf in leaves)
+            or valid_words.size == 0):    # zero-row grid is undefined
+        return {a: agg_ref.identity(code_bits) for a in aggregates}
+    leaves = [leaf for leaf in leaves if leaf[1:] != scan_ops.SELECT_ALL]
+    names = tuple(dict.fromkeys([leaf[0] for leaf in leaves]
+                                + list(aggregates)))
+    idx = {n: i for i, n in enumerate(names)}
+    out = K.scan_conjunction_packed(
+        tuple(_to2d(words[n]) for n in names), _to2d(valid_words),
+        leaves=tuple((idx[c], prim, cst, inv)
+                     for c, prim, cst, inv in leaves),
+        aggs=tuple(idx[a] for a in aggregates), code_bits=code_bits,
+        tile_rows=min(DEFAULT_BLOCK_ROWS,
+                      agg_ops.sum_bound_block_rows(code_bits)),
+        interpret=r.interpret)
+    return {a: {"sum_lo": out[k, 0], "sum_hi": out[k, 1],
+                "count": out[k, 2], "min": out[k, 3], "max": out[k, 4]}
+            for k, a in enumerate(aggregates)}
 
-    vmax = (1 << (code_bits - 1)) - 1
-    c = int(constant)
-    if op in ("ge", "eq"):
-        prim, cc, inv = op, c, False
-    elif op == "lt":
-        prim, cc, inv = "ge", c, True
-    elif op == "ne":
-        prim, cc, inv = "eq", c, True
-    elif op == "gt":
-        if c >= vmax:                 # nothing exceeds the payload max
-            return agg_ref.identity(code_bits)
-        prim, cc, inv = "ge", c + 1, False
-    else:  # le
-        if c >= vmax:                 # everything valid matches
-            return agg_ops.aggregate(agg_words, valid_words, code_bits,
-                                     mode=mode)
-        prim, cc, inv = "ge", c + 1, True
 
-    def to2d(w):
-        w = jnp.asarray(w, jnp.uint32)
-        return jnp.pad(w, (0, (-w.shape[0]) % LANES)).reshape(-1, LANES)
-
-    p2d, a2d, v2d = to2d(pred_words), to2d(agg_words), to2d(valid_words)
-    rows = p2d.shape[0]
-    br = block_rows or min(DEFAULT_BLOCK_ROWS, rows)
-    br = min(br, agg_ops.sum_bound_block_rows(code_bits))
-    out = K.scan_aggregate_packed(p2d, a2d, v2d, constant=cc, op=prim,
-                                  invert=inv, code_bits=code_bits,
-                                  block_rows=br, interpret=r.interpret)
-    return {"sum_lo": out[0, 0], "sum_hi": out[0, 1], "count": out[0, 2],
-            "min": out[0, 3], "max": out[0, 4]}
+def _to2d(words):
+    """(n_words,) packed words -> (rows, 128) kernel planes, the last row
+    zero-filled (zero validity words select nothing)."""
+    return scan_ops._to_2d(jnp.asarray(words, jnp.uint32))[0]
 
 
 def scan_aggregate_batched(pred3, agg3, valid3, triples, code_bits: int,
@@ -88,8 +84,8 @@ def scan_aggregate_batched(pred3, agg3, valid3, triples, code_bits: int,
     canonical (prim, constant, invert) from scan_filter.ops.canonical_pred
     — per-chunk FOR frames translate the constant differently, and the
     batched kernel carries that difference as scalar-prefetched data.
-    Returns int32[n_chunks, 5]; each row is bit-identical to the
-    per-chunk `scan_aggregate` composition for that chunk."""
+    Returns int32[n_chunks, 5]; each row is bit-identical to
+    `scan_conjunction` over that chunk with its one leaf."""
     r = dispatch.resolve(mode)
     obs_metrics.count_launch("scan_aggregate")
     p = jnp.asarray(pred3, jnp.uint32)
@@ -130,13 +126,14 @@ def _fused_batched_ref(p3, a3, v3, consts, flags, code_bits: int):
 def _example(rng):
     from repro.kernels.scan_filter import ref as scan_ref
     n = 5001                                  # exercises the tail validity
-    pw = scan_ref.pack(rng.integers(0, 128, n), 8)
-    aw = scan_ref.pack(rng.integers(0, 128, n), 8)
-    valid = scan_ref.valid_mask(pw.size, n, 8)
-    return (jnp.asarray(pw), jnp.asarray(aw), jnp.asarray(valid),
-            64, "lt", 8), {}
+    words = {c: jnp.asarray(scan_ref.pack(rng.integers(0, 128, n), 8))
+             for c in "abc"}
+    valid = jnp.asarray(scan_ref.valid_mask(words["a"].size, n, 8))
+    preds = (("a", "lt", 64), ("b", "ge", 10), ("a", "ne", 7),
+             ("c", "le", 127))
+    return (words, valid, preds, ("b", "c"), 8), {}
 
 
 dispatch.register(
-    "scan_aggregate", fn=scan_aggregate, ref=ref.scan_aggregate_ref,
+    "scan_aggregate", fn=scan_conjunction, ref=ref.scan_conjunction_ref,
     example=_example)

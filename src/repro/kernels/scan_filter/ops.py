@@ -74,6 +74,10 @@ def scan_filter(words, constant: int, op: str, code_bits: int,
 # predicate as data, not code: canonical (prim, constant, invert) triples
 # packed into per-chunk scalar planes (the SMEM scalar-prefetch idiom).
 
+# the canonical triples of the two tautologies
+SELECT_ALL, SELECT_NONE = ("ge", 0, False), ("ge", 0, True)
+
+
 def canonical_pred(op: str, constant: int, code_bits: int):
     """Reduce any of the six predicates at any integer constant to the
     kernel-primitive triple (prim in {ge, eq}, constant in [0, vmax],
@@ -85,7 +89,7 @@ def canonical_pred(op: str, constant: int, code_bits: int):
                          f"{OPS}")
     vmax = (1 << (code_bits - 1)) - 1
     c = int(constant)
-    all_, none = ("ge", 0, False), ("ge", 0, True)
+    all_, none = SELECT_ALL, SELECT_NONE
     if op == "ge":
         return all_ if c <= 0 else (none if c > vmax else ("ge", c, False))
     if op == "gt":

@@ -14,9 +14,10 @@ A logical Plan tree binds to a table's packed columns as `ColumnSlice`s
   forms its mask in their layout, and the final mask is repacked once per
   other aggregate width;
 - each aggregate column reduces the selection through the dispatch-routed
-  masked aggregate, and the dominant single-predicate/single-aggregate
-  query takes the fused scan+aggregate kernel instead (no mask HBM
-  round-trip);
+  masked aggregate; a plan that is one Pred or an And of Preds, whose
+  columns all share one layout, instead takes the fused conjunctive
+  scan+aggregate kernel: every leaf and every aggregate in one pass, no
+  mask in HBM (counters `scan_fused` / `scan_masked`, once per trace);
 - under `axis=...` (inside a shard_map) the four scalars combine across
   shards with psum/pmin/pmax — the only bytes that cross the interconnect.
 
@@ -36,6 +37,7 @@ from repro.kernels.aggregate import ops as agg_ops
 from repro.kernels.mask_repack import ops as repack_ops
 from repro.kernels.scan_aggregate import ops as fused_ops
 from repro.kernels.scan_filter import ops as scan_ops
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.query.plan import And, Or, Plan, Pred, columns_of
 
@@ -88,8 +90,14 @@ def eval_mask(plan: Plan, slices: dict[str, ColumnSlice], mode=None,
     The mask comes in `layout`, (code_bits, words), or by default in the
     leftmost leaf's. A node combines the children of one layout in that
     layout first, then repacks each other layout's combined mask once to
-    its own. Always validity-masked.
+    its own. Always validity-masked. Counts `scan_masked` once.
     """
+    obs_metrics.count("scan_masked")
+    return _mask(plan, slices, mode, layout)
+
+
+def _mask(plan: Plan, slices: dict[str, ColumnSlice], mode,
+          layout: tuple[int, int] | None):
     combine = jnp.bitwise_or if isinstance(plan, Or) else jnp.bitwise_and
     if isinstance(plan, Pred):
         s = slices[plan.column]
@@ -99,7 +107,7 @@ def eval_mask(plan: Plan, slices: dict[str, ColumnSlice], mode=None,
     elif isinstance(plan, (And, Or)):
         parts = {}
         for c in plan.children:
-            m, b = eval_mask(c, slices, mode)
+            m, b = _mask(c, slices, mode, None)
             key = (b, m.shape[0])
             parts[key] = combine(parts[key], m) if key in parts else m
     else:
@@ -110,6 +118,17 @@ def eval_mask(plan: Plan, slices: dict[str, ColumnSlice], mode=None,
         m = repack_ops.repack_mask(m, b, bits, words, mode=mode)
         out = m if out is None else combine(out, m)
     return out, bits
+
+
+def _conjunction_leaves(plan: Plan) -> tuple[Pred, ...] | None:
+    """The leaves of a plan that is one Pred or an And of Preds; None for
+    any other shape (an Or, or an And over a subtree)."""
+    if isinstance(plan, Pred):
+        return (plan,)
+    if isinstance(plan, And) and all(isinstance(c, Pred)
+                                     for c in plan.children):
+        return plan.children
+    return None
 
 
 def _psum_aggs(d: dict, axis: str) -> dict:
@@ -211,16 +230,18 @@ def execute(plan: Plan, aggregates: tuple, slices: dict[str, ColumnSlice],
     inside shard_map (axis names the mesh axis to combine over).
     """
     out: dict[str, dict] = {}
-    fused = (isinstance(plan, Pred) and len(aggregates) == 1
-             and slices[plan.column].code_bits
-             == slices[aggregates[0]].code_bits
-             and slices[plan.column].words.shape
-             == slices[aggregates[0]].words.shape)
-    if fused:
-        p, a = slices[plan.column], slices[aggregates[0]]
-        out[aggregates[0]] = fused_ops.scan_aggregate(
-            p.words, a.words, p.valid, plan.constant, plan.op, p.code_bits,
-            mode=mode)
+    leaves = _conjunction_leaves(plan)
+    names = tuple(dict.fromkeys((*aggregates, *sorted(columns_of(plan)))))
+    if leaves is not None and aggregates and len(
+            {(slices[n].code_bits, slices[n].words.shape[0])
+             for n in names}) == 1:
+        # one layout: every column is valid on the same rows, so one
+        # validity plane serves the whole conjunction
+        obs_metrics.count("scan_fused")
+        out = fused_ops.scan_conjunction(
+            {n: slices[n].words for n in names}, slices[names[0]].valid,
+            tuple((p.column, p.op, p.constant) for p in leaves),
+            aggregates, slices[names[0]].code_bits, mode=mode)
     else:
         layouts = list(dict.fromkeys(
             (slices[c].code_bits, slices[c].words.shape[0])

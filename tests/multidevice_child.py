@@ -511,8 +511,9 @@ def check_grouped_packed():
     """Grouped queries whose key and value columns share one code width,
     on 8 shards below one kernel tile and on 2 at whole tiles: one packed
     kernel per shard (`grouped_packed` 1, `grouped_slabs` 0, no pad over
-    whole tiles) and numpy's answers; a value column at another width
-    still takes the slab path."""
+    whole tiles, the mask built by `eval_mask`: `scan_masked` 1) and
+    numpy's answers; a value column at another width still takes the
+    slab path."""
     from repro.db.columnar import BitPackedColumn, Table
     from repro.kernels.scan_filter.kernel import TILE_WORDS
     from repro.obs.metrics import MetricsRegistry, scoped
@@ -544,9 +545,64 @@ def check_grouped_packed():
                 (n, q)
             assert reg.counter("grouped_packed").value == packed, (n, q)
             assert reg.counter("grouped_slabs").value == 1 - packed, (n, q)
+            # the packed body builds its mask through eval_mask, not the
+            # one-pass kernel
+            assert reg.counter("scan_masked").value == packed, (n, q)
+            assert reg.counter("scan_fused").value == 0, (n, q)
             if packed and n == 2:
                 assert reg.counter("tile_pads").value == 0, q
     print("OK grouped_packed")
+
+
+def check_conjunction():
+    """Conjunctions at one code width on 8 shards below one kernel tile
+    and on 4 at whole tiles: one fused kernel a shard (`scan_fused` 1,
+    `scan_masked` 0, one `scan_aggregate` launch), its per-shard rows
+    psum-combined to numpy's answers, in modes pallas and xla_ref, for
+    a selection of rows, of none (a leaf past the payload max, and two
+    leaves that exclude each other) and of every valid row."""
+    from repro.db.columnar import BitPackedColumn, Table
+    from repro.kernels.scan_filter.kernel import TILE_WORDS
+    from repro.obs.metrics import MetricsRegistry, scoped
+    from repro.query import And, Pred, ShardedTable
+
+    plans = [
+        And.of(Pred("a", "ge", 731), Pred("a", "lt", 1096),
+               Pred("b", "ge", 5), Pred("b", "le", 7), Pred("c", "lt", 24)),
+        Pred("b", "gt", 32767),
+        And.of(Pred("c", "lt", 10), Pred("c", "ge", 20)),
+        Pred("a", "le", 32767),
+    ]
+    aggs = ("b", "c", "a")
+    rng = np.random.default_rng(29)
+    for n, rows in ((8, 100_001), (4, 4 * TILE_WORDS * 2 * 3 + 555)):
+        table = Table("t")
+        for name, hi in (("a", 2526), ("b", 10), ("c", 50)):
+            table.add(BitPackedColumn.from_values(
+                name, rng.integers(0, hi + 1, rows), 16))
+        c = {k: table.columns[k].decode().astype(np.int64)
+             for k in table.columns}
+        st = ShardedTable.shard(table, make_mesh((n,), ("data",)))
+        for plan in plans:
+            sel = np.ones(rows, bool)
+            for p in (plan.children if isinstance(plan, And) else (plan,)):
+                sel &= {"ge": np.greater_equal, "lt": np.less,
+                        "le": np.less_equal, "gt": np.greater}[p.op](
+                            c[p.column], p.constant)
+            for mode in ("pallas", "xla_ref"):
+                reg = MetricsRegistry("conj")
+                with scoped(reg):
+                    got = st.execute(plan, aggs, mode=mode)
+                for a in aggs:
+                    v = c[a][sel]
+                    want = {"sum": int(v.sum()), "count": int(sel.sum()),
+                            "min": int(v.min(initial=32767)),
+                            "max": int(v.max(initial=0))}
+                    assert got[a] == want, (n, plan, mode, a, got[a], want)
+                assert (reg.counter("scan_fused").value,
+                        reg.counter("scan_masked").value) == (1, 0)
+                assert reg.launch_counts() == {"scan_aggregate": 1}
+    print("OK conjunction")
 
 
 def check_serve_step_sharded():
@@ -580,6 +636,7 @@ if __name__ == "__main__":
         "tiles": check_whole_tile_shards,
         "q6_narrow": check_q6_narrow,
         "grouped_packed": check_grouped_packed,
+        "conjunction": check_conjunction,
     }
     if which == "all":
         for fn in checks.values():

@@ -144,8 +144,11 @@ def _ragged_scan_aggregate(rows, rng):
     pw = scan_ref.pack(rng.integers(0, 128, n), 8)
     aw = scan_ref.pack(rng.integers(0, 128, n), 8)
     valid = scan_ref.valid_mask(pw.size, n, 8)
-    return (ops.scan_aggregate(pw, aw, valid, 64, "lt", 8, mode="pallas"),
-            ref.scan_aggregate_ref(pw, aw, valid, 64, "lt", 8))
+    words = {"p": pw, "a": aw}
+    return (ops.scan_conjunction(words, valid, (("p", "lt", 64),), ("a",),
+                                 8, mode="pallas"),
+            ref.scan_conjunction_ref(words, valid, (("p", "lt", 64),),
+                                     ("a",), 8))
 
 
 def _ragged_scan_compressed(rows, rng):

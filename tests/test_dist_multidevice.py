@@ -27,7 +27,8 @@ def run_child(which: str):
                                    "ef", "train", "serve", "elastic",
                                    "query", "store", "resilience",
                                    "relational", "d2h", "tiles",
-                                   "q6_narrow", "grouped_packed"])
+                                   "q6_narrow", "grouped_packed",
+                                   "conjunction"])
 def test_multidevice(which):
     out = run_child(which)
     assert "OK" in out

@@ -86,11 +86,17 @@ def _kernel_cases(s):
                                      agg_k.aggregate_batched_packed(
                                          w, m, code_bits=16, **off),
                                      (w3, w3)),
-        "scan_aggregate_packed": (lambda p, a, v:
-                                  fused_k.scan_aggregate_packed(
-                                      p, a, v, constant=3, op="eq",
-                                      invert=True, code_bits=8, **off),
-                                  (w2, w2, w2)),
+        # TPC-H Q6's shape: five leaves over three 16-bit columns, two
+        # aggregates, one validity plane
+        "scan_conjunction_packed": (
+            lambda a, b, c, v: fused_k.scan_conjunction_packed(
+                (a, b, c), v, leaves=((0, "ge", 731, False),
+                                      (0, "ge", 1096, True),
+                                      (1, "ge", 5, False),
+                                      (1, "ge", 8, True),
+                                      (2, "ge", 24, True)),
+                aggs=(1, 2), code_bits=16, tile_rows=256, **off),
+            (w2, w2, w2, w2)),
         "scan_aggregate_batched_packed": (
             lambda c, f, p, a, v: fused_k.scan_aggregate_batched_packed(
                 c, f, p, a, v, code_bits=8, **off),
@@ -127,7 +133,7 @@ def _kernel_cases(s):
 
 
 KERNELS = ("scan_packed", "aggregate_packed", "aggregate_batched_packed",
-           "scan_aggregate_packed", "scan_aggregate_batched_packed",
+           "scan_conjunction_packed", "scan_aggregate_batched_packed",
            "rle_scan_aggregate_packed", "rle_scan_aggregate_batched_packed",
            "group_sum_count_batched_planes", "group_sum_count_packed",
            "group_sum_count_packed_20_values",
@@ -147,8 +153,9 @@ def test_kernel_compiles_for_v5e(entry, one_chip, no_persistent_cache):
 
 SERVED = {
     "fused": Query(Pred("a", "lt", 40), ("b",)),
+    # the 16-bit leaf puts the plan over two layouts: the mask path
     "mask_and_aggregate": Query(And.of(Pred("a", "lt", 64),
-                                       Pred("b", "ge", 32)), ("a", "b")),
+                                       Pred("c", "ge", 32)), ("a", "b")),
     "grouped": GroupBy("a", ("b",), where=Pred("c", "lt", 16384)),
 }
 
@@ -202,16 +209,20 @@ Q6 = Query(And.of(Pred("l_shipdate", "ge", 731),
 Q6_SCHEMA = {"l_shipdate": 16, "l_quantity": 16, "l_discount": 16}
 
 
+# the fused program reserves no temporaries (0 B measured on one chip and
+# on four); the mask path it replaced reserved 6.00 and 4.50 GB for the
+# five predicate masks
 @pytest.mark.parametrize("chips,rows,temp_bytes",
-                         ((1, 600_037_902, 6.1e9),
-                          (4, 1_799_989_091, 4.6e9)),
+                         ((1, 600_037_902, 2**20),
+                          (4, 1_799_989_091, 2**20)),
                          ids=("sf100_1chip", "sf300_4chip"))
 def test_q6_program_over_whole_tiles_pads_nothing(chips, rows, temp_bytes,
                                                   topo, no_persistent_cache,
                                                   monkeypatch):
     """Q6's served program over a shard placed at whole kernel tiles
-    (SF 100 on one chip, SF 300 on four): no pad, no plane-sized slice,
-    no `tile_pads`, and the mask temporaries shrink to the masks."""
+    (SF 100 on one chip, SF 300 on four): one fused conjunctive kernel a
+    shard and no mask kernel (`scan_fused` 1), no pad, no plane-sized
+    slice, no `tile_pads`, and no mask temporaries."""
     monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
     rps = shard_rows(Q6_SCHEMA.values(), rows, chips)
     mesh = Mesh(np.asarray(topo.devices[:chips]), ("data",))
@@ -232,6 +243,11 @@ def test_q6_program_over_whole_tiles_pads_nothing(chips, rows, temp_bytes,
               for dims in re.findall(r"\[([\d,]*)\]\S* slice\(", hlo)]
     assert sliced and max(sliced) < scan_k.TILE_WORDS, sliced
     assert reg.counter("tile_pads").value == 0
+    calls = re.findall(r"%(\w+?)(?:\.\d+)? = .*? custom-call\(", hlo)
+    assert calls == ["scan_conjunction_packed"], calls
+    assert {k: reg.counter(k).value for k in ("scan_fused",
+                                              "scan_masked")} == {
+        "scan_fused": 1, "scan_masked": 0}
     assert compiled.memory_analysis().temp_size_in_bytes <= temp_bytes
 
 
@@ -262,10 +278,11 @@ def test_q6_program_at_narrow_widths_fits_one_chip(topo, no_persistent_cache,
     hlo = compiled.as_text()
     assert re.search(r"%repack_mask_packed\S* = \S+ custom-call\(", hlo)
     assert reg.counter("mask_repacks").value == 1
+    assert reg.counter("scan_masked").value == 1
     minor = set(re.findall(r"\b[a-z]+\d*\[[\d,]*,(\d+)\]", hlo))
     assert minor and not minor & {"2", "4"}, minor
-    # q6_power's bound; measured 2,400,287,232: the two 1.2 GB ship-date
-    # masks live at once before their AND, the 8-bit masks reuse them
+    # measured 2,400,287,232: the two 1.2 GB ship-date masks live at once
+    # before their AND, the 8-bit masks reuse them
     assert compiled.memory_analysis().temp_size_in_bytes <= 6.1e9
 
 
